@@ -2,10 +2,11 @@
 
 Traces and compiles the fragment/kernel shapes the test suite hits most —
 scan→agg, partitioned join (skewed and plain), streaming group-by — so a
-CI rerun that points ``JAX_COMPILATION_CACHE_DIR`` at the same directory
-skips those compiles. Run from the repo root:
+later run that uses the same cache directory skips those compiles. The
+directory is the one ``import trino_tpu`` gives every process:
+``JAX_COMPILATION_CACHE_DIR`` where it is set, else ``<checkout>/.jax_cache``.
 
-    JAX_COMPILATION_CACHE_DIR=.jax_cache python scripts/prewarm_cache.py
+    python scripts/prewarm_cache.py
 
 With ``--history-dir DIR`` (a query-history store written by a prior
 serving run, obs/history.py) the corpus is reordered by OBSERVED elapsed
@@ -17,7 +18,7 @@ With ``--results`` the corpus is additionally executed with the semantic
 result cache enabled and a fingerprint → cached-bytes table prints what
 landed in the RESULT tier (see README "Semantic result cache").
 
-The suite's conftest honors the same variable, so tests reuse the warmed
+The suite's processes follow the same rule, so tests reuse the warmed
 entries. Idempotent: re-running only adds missing entries.
 """
 
@@ -60,25 +61,16 @@ def main() -> None:
     args = ap.parse_args()
 
     import jax
+    import numpy as np
 
-    cache_dir = os.path.abspath(
-        os.environ.get("JAX_COMPILATION_CACHE_DIR") or ".jax_cache"
-    )
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    from trino_tpu import types as T  # the import applies the cache rule
+
+    cache_dir = jax.config.jax_compilation_cache_dir
     # write EVERY compile: the suite reads entries regardless of its own
     # write threshold, and CPU-CI compiles are individually fast but
     # collectively the tier-1 tail
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 
-    import numpy as np
-
-    from trino_tpu import types as T  # noqa: F401 — import applies config
-
-    # trino_tpu's import hook re-applies cache config; restore ours after
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-
-    from trino_tpu import types as T
     from trino_tpu.columnar import Batch, Column
     from trino_tpu.config import Session
     from trino_tpu.connectors.api import ColumnSchema, TableSchema

@@ -1,0 +1,212 @@
+"""The main path's kernels, compiled for the v5e at real sizes — no chip.
+
+The TPU compiler is installed here and compiles for a chip that is
+described, not attached (``v5e:2x2``). It refuses what interpret mode and
+the CPU backend let through: a misaligned slice, too much VMEM, a scalar
+store to VMEM, a program that does not fit HBM. Nothing runs, so these
+cases say nothing about results or times; ``chip_smoke.py`` does that on
+the chip. Every ``pallas_call`` in ``trino_tpu/`` has a case here.
+
+All cases stay in THIS file (one xdist worker then holds the TPU library),
+and the topology is described inside a module fixture, never at import.
+"""
+
+import os
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as PS, SingleDeviceSharding
+
+from trino_tpu.exec.streaming import dense_program
+from trino_tpu.ops import dense_groupby as DG
+from trino_tpu.ops import dense_join as DJ
+from trino_tpu.ops import keypack
+from trino_tpu.parallel.exchange import hash_repartition
+from trino_tpu.parallel.mesh import AXIS
+
+LINEITEM_SLAB = 1 << 23  # SF1 lineitem (6,001,215 rows) padded to a slab
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — whatever keeps libtpu from loading
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep these out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield topo
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _shape(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compile(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert compiled.memory_analysis() is not None
+    return compiled
+
+
+# (rows, plan): 2^25 rows x 4096 groups; 8192 groups with a signed
+# 128-bit sum; four value columns of Q1's widths
+_DENSE_CASES = {
+    "2^25x4096": (
+        1 << 25,
+        DG.DensePlan(G=4096, cols=(DG.DenseCol(True, 20),), pair128=(False,)),
+    ),
+    "2^22x8192-signed128": (
+        1 << 22,
+        DG.DensePlan(G=8192, cols=(DG.DenseCol(False, 64),), pair128=(True,)),
+    ),
+    "2^23x128-4cols": (
+        LINEITEM_SLAB,
+        DG.DensePlan(
+            G=128,
+            cols=(DG.DenseCol(True, 13), DG.DenseCol(True, 24),
+                  DG.DenseCol(True, 37), DG.DenseCol(False, 64)),
+            pair128=(False, False, True, True),
+        ),
+    ),
+}
+
+
+@pytest.mark.parametrize("case", list(_DENSE_CASES))
+def test_dense_groupby_kernel(one_chip, case):
+    rows, plan = _DENSE_CASES[case]
+    compiled = _compile(
+        lambda b, vs: DG.dense_groupby_device(plan, b, vs),
+        _shape(one_chip, (rows,), jnp.int32),
+        [_shape(one_chip, (rows,), jnp.int64) for _ in plan.cols],
+    )
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_dense_program_and_reconstruct_at_lineitem_slab(one_chip):
+    """What ``_try_dense`` jits for Q1 at SF1: two dictionary-code keys,
+    the aggregate inputs, then the device reconstruction in its own jit."""
+    n = LINEITEM_SLAB
+    plan = _DENSE_CASES["2^23x128-4cols"][1]
+    nk = 2
+    producer = _compile(
+        dense_program(plan),
+        _shape(one_chip, (n,), jnp.bool_),
+        _shape(one_chip, (nk,), jnp.int64),
+        _shape(one_chip, (nk,), jnp.int32),
+        [_shape(one_chip, (n,), jnp.int32) for _ in range(nk)],
+        [_shape(one_chip, (n,), jnp.int64) for _ in plan.cols],
+    )
+    assert "tpu_custom_call" in producer.as_text()
+    drain = _shape(one_chip, (plan.m, 128), jnp.int32)
+    _compile(
+        partial(DG.reconstruct_device, plan),
+        drain, drain,
+        *[_shape(one_chip, (nk,), jnp.int64) for _ in range(3)],
+    )
+
+
+_JOIN_BUILD, _JOIN_PROBE, _JOIN_CAP = 1 << 21, LINEITEM_SLAB, 1 << 23
+
+
+def test_dense_join_build(one_chip):
+    """The jnp build rounds at orders size: 2^21 build rows into a table
+    at the engineered 4x load."""
+    nb, cap = _JOIN_BUILD, _JOIN_CAP
+
+    def build(bh, valid, sel):
+        return DJ.build_table(DJ.slot_base_hash(bh, cap), valid, sel, cap)
+
+    _compile(
+        build,
+        _shape(one_chip, (nb,), jnp.int64),
+        _shape(one_chip, (nb,), jnp.bool_),
+        _shape(one_chip, (nb,), jnp.bool_),
+    )
+
+
+def test_dense_join_probe(one_chip):
+    """The jnp probe rounds at lineitem size: 2^23 probe rows against the
+    2^21-row build's table."""
+    nb, npr, cap = _JOIN_BUILD, _JOIN_PROBE, _JOIN_CAP
+
+    def probe(table, bh, ph, pvalid, psel):
+        return DJ.probe_table(
+            table, bh, DJ.slot_base_hash(ph, cap), ph, pvalid, psel, npr
+        )
+
+    _compile(
+        probe,
+        _shape(one_chip, (cap,), jnp.int32),
+        _shape(one_chip, (nb,), jnp.int64),
+        _shape(one_chip, (npr,), jnp.int64),
+        _shape(one_chip, (npr,), jnp.bool_),
+        _shape(one_chip, (npr,), jnp.bool_),
+    )
+
+
+# sel bit + key bits + 23 row-index bits, packed into 63-bit int64 lanes.
+# The compile time is the comparator's, not the row count's: one lane takes
+# ~20 s here and three take ~150 s, so the wide case is slow-marked.
+_SORT_CASES = {
+    "1-lane": (jnp.int32,),
+    "3-lanes": (jnp.int64, jnp.int64, jnp.int32),
+}
+
+
+@pytest.mark.parametrize(
+    "case",
+    ["1-lane", pytest.param("3-lanes", marks=pytest.mark.slow)],
+)
+def test_keypack_sort(one_chip, case):
+    """The packed grouping sort at 2^23 rows."""
+    n = LINEITEM_SLAB
+    dtypes = _SORT_CASES[case]
+
+    def sort(sel, *cols):
+        keys = [(c, None) for c in cols]
+        lanes = keypack.KeyPlan(keys, sel_present=True).num_lanes
+        assert lanes == len(dtypes), lanes
+        return keypack.grouping_sort(keys, sel, n)
+
+    _compile(
+        sort,
+        _shape(one_chip, (n,), jnp.bool_),
+        *[_shape(one_chip, (n,), dt) for dt in dtypes],
+    )
+
+
+def test_repartition_is_an_all_to_all_on_four_chips(topo):
+    """The repartition shuffle over a 4-device mesh of the described
+    chips: the compiler must put an all-to-all in, on every device."""
+    mesh = Mesh(np.asarray(topo.devices).reshape(4), (AXIS,))
+    rows = NamedSharding(mesh, PS(AXIS))
+    n, bucket = LINEITEM_SLAB, 1 << 20
+
+    def shuffle(a, b, khash, sel):
+        return hash_repartition(mesh, [a, b], khash, sel, bucket)
+
+    compiled = _compile(
+        shuffle,
+        _shape(rows, (n,), jnp.int64),
+        _shape(rows, (n,), jnp.int64),
+        _shape(rows, (n,), jnp.int64),
+        _shape(rows, (n,), jnp.bool_),
+    )
+    assert "all-to-all" in compiled.as_text()

@@ -3,18 +3,15 @@
 Covers the join-engine-v2 PR: kernel units for the open-addressing
 build/probe pair (graceful overflow re-hash at doubled capacity, null
 keys, duplicate-key tie order, the duplicate-chain pathology capacity
-growth can never fix), the Pallas sequential-insertion build kernel vs
-the jnp round-based scheme (interpret mode on CPU, native on a chip),
-the join-as-matmul count contraction vs its gather lowering,
-dense-vs-sort kernel bit-identity across 3 rng seeds, the `_Caps`
-demotion ladder, end-to-end bit-identity across join_strategy
+growth can never fix), the join-as-matmul count contraction vs its
+gather lowering, dense-vs-sort kernel bit-identity across 3 rng seeds,
+the `_Caps` demotion ladder, end-to-end bit-identity across join_strategy
 auto/sort/dense on TPC-H Q5/Q10 and a TPC-DS star query against the
 single-node interpreter, the multiway star-join fusion win, and the
 PR-15 history loop (warm repeat with zero overflow retries off a
 history-seeded `densejoin@…` site).
 """
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -30,9 +27,6 @@ from trino_tpu.ops.join import (
 )
 from trino_tpu.config import Session
 from trino_tpu.testing import DistributedQueryRunner, LocalQueryRunner
-
-_ON_TPU = jax.devices()[0].platform == "tpu"
-
 
 def _keys(data, valid=None):
     data = jnp.asarray(data, jnp.int64)
@@ -54,21 +48,13 @@ def _sort_pairs(keys_p, keys_b, psel, bsel, out_cap, jt):
     return _live(pp, bp, osel)
 
 
-def _dense_pairs(keys_p, keys_b, psel, bsel, out_cap, jt, capacity,
-                 device_build=False):
+def _dense_pairs(keys_p, keys_b, psel, bsel, out_cap, jt, capacity):
     """The dense tier at a FIXED capacity; asserts no table overflow."""
     ph, pv = hash_keys(keys_p)
     bh, bv = hash_keys(keys_b)
     bbase = DJ.slot_base_hash(bh, capacity)
-    if device_build:
-        table, unplaced = DJ.build_table_device(
-            bbase, bv & jnp.asarray(bsel), capacity,
-            interpret=not _ON_TPU,
-        )
-        assert int(unplaced) == 0
-    else:
-        table, tovf = DJ.build_table(bbase, bv, jnp.asarray(bsel), capacity)
-        assert not bool(tovf)
+    table, tovf = DJ.build_table(bbase, bv, jnp.asarray(bsel), capacity)
+    assert not bool(tovf)
     pbase = DJ.slot_base_hash(ph, capacity)
     pp, bp, osel, total, ovf = DJ.probe_table(
         table, bh, pbase, ph, pv, jnp.asarray(psel), out_cap, jt
@@ -159,30 +145,6 @@ class TestBuildTable:
         for cap in (64, 128, 256, 1024):
             _, ovf = DJ.build_table(DJ.slot_base_hash(h, cap), ones, ones, cap)
             assert bool(ovf), f"dup chain placed at capacity {cap}?"
-
-    def test_pallas_build_joins_identically(self):
-        """build_table_device (sequential first-vacant insertion, chunked
-        DMA) and build_table (round-based scatter-min) may lay the table
-        out differently across colliding DISTINCT keys, but probing
-        either emits the identical join — elementwise, not just as a
-        set."""
-        n = 512
-        rng = np.random.default_rng(11)
-        bk = rng.integers(0, 200, n)  # heavy dup chains, some collisions
-        pk = rng.integers(0, 200, 300)
-        ps = np.ones(300, bool)
-        bs = rng.random(n) < 0.9
-        jnp_pairs = _dense_pairs(
-            _keys(pk), _keys(bk), ps, bs, 4096, "inner", 4096
-        )
-        dev_pairs = _dense_pairs(
-            _keys(pk), _keys(bk), ps, bs, 4096, "inner", 4096,
-            device_build=True,
-        )
-        assert jnp_pairs == dev_pairs
-        assert sorted(jnp_pairs) == sorted(
-            _sort_pairs(_keys(pk), _keys(bk), ps, bs, 4096, "inner")
-        )
 
 
 class TestMatmulTier:

@@ -23,25 +23,22 @@ enable_x64()
 def _enable_compile_cache() -> None:
     """Persistent XLA compile cache: plans are re-traced per query (like the
     reference re-plans per query), but identical fragment programs hit the
-    on-disk XLA cache instead of recompiling."""
+    on-disk XLA cache instead of recompiling.
+
+    The one rule: where ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it
+    itself and nothing here touches the directory; otherwise it is
+    ``<checkout>/.jax_cache``, a fixed path (the path is part of the cache
+    key). Worker subprocesses import this package and so agree."""
     import os
 
-    try:
-        import jax
+    import jax
 
-        # JAX_COMPILATION_CACHE_DIR (the upstream variable; CI points it at
-        # a dir pre-warmed by scripts/prewarm_cache.py) wins over the
-        # package-specific override and the home-dir default
-        cache = (
-            os.environ.get("JAX_COMPILATION_CACHE_DIR")
-            or os.environ.get("TRINO_TPU_COMPILE_CACHE")
-            or os.path.join(os.path.expanduser("~"), ".cache", "trino_tpu_xla")
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        jax.config.update(
+            "jax_compilation_cache_dir", os.path.join(checkout, ".jax_cache")
         )
-        if cache:
-            jax.config.update("jax_compilation_cache_dir", cache)
-            jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.2)
-    except Exception:  # noqa: BLE001 — cache is an optimization only
-        pass
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.2)
 
 
 _enable_compile_cache()

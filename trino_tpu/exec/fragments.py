@@ -164,8 +164,8 @@ def _expr_blocks_fusion(e) -> bool:
 
 # XLA failure signatures that a SMALLER program can fix: scoped-vmem
 # allocation failures at compile time and HBM exhaustion at run time
-# (NOTES_r05 known issue 1: SF1 Q5's 33MB fragment program dies in
-# scoped allocation before any overflow flag can fire)
+# (VERDICT.md weak #3: SF1 Q5's 33MB fragment program died in scoped
+# allocation before any overflow flag could fire)
 _RESOURCE_ERROR_MARKERS = (
     "RESOURCE_EXHAUSTED",
     "Resource exhausted",

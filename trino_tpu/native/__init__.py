@@ -9,41 +9,51 @@ toolchain (``NATIVE_AVAILABLE`` reports which path is active).
 from __future__ import annotations
 
 import ctypes
+import fcntl
 import hashlib
 import os
 import subprocess
-import tempfile
 from typing import Optional, Sequence
 
 import numpy as np
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
-_SRC = os.path.join(_HERE, "..", "..", "native", "columnar.cpp")
+_CHECKOUT = os.path.dirname(os.path.dirname(_HERE))
+_SRC = os.path.join(_CHECKOUT, "native", "columnar.cpp")
+# fixed, git-ignored build directory inside the checkout: the library is
+# built from native/columnar.cpp as committed and from nothing else
+_BUILD_DIR = os.path.join(_CHECKOUT, ".cache", "native")
 _LIB: Optional[ctypes.CDLL] = None
 NATIVE_AVAILABLE = False
 
 
 def _build_and_load() -> Optional[ctypes.CDLL]:
-    src = os.path.abspath(_SRC)
-    if not os.path.exists(src):
+    """None (NumPy fallbacks) when there is no source, no toolchain or the
+    library will not load; ``chip_smoke.py`` and the test header report
+    ``NATIVE_AVAILABLE`` so the fallback is never silent."""
+    if not os.path.exists(_SRC):
         return None
-    with open(src, "rb") as f:
+    with open(_SRC, "rb") as f:
         digest = hashlib.sha256(f.read()).hexdigest()[:16]
-    cache_dir = os.path.join(tempfile.gettempdir(), "trino_tpu_native")
-    os.makedirs(cache_dir, exist_ok=True)
-    lib_path = os.path.join(cache_dir, f"columnar_{digest}.so")
-    if not os.path.exists(lib_path):
-        tmp = lib_path + f".tmp{os.getpid()}"
-        try:
-            subprocess.run(
-                ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-o", tmp, src],
-                check=True,
-                capture_output=True,
-                timeout=120,
-            )
-            os.replace(tmp, lib_path)
-        except Exception:
-            return None
+    os.makedirs(_BUILD_DIR, exist_ok=True)
+    lib_path = os.path.join(_BUILD_DIR, f"columnar_{digest}.so")
+    # concurrent first imports (xdist workers, cluster processes) take
+    # turns on a lock file; the first builds, the rest find the library
+    with open(lib_path + ".lock", "w") as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        if not os.path.exists(lib_path):
+            partial = lib_path + ".partial"
+            try:
+                subprocess.run(
+                    ["g++", "-O3", "-shared", "-fPIC", "-std=c++17",
+                     "-o", partial, _SRC],
+                    check=True,
+                    capture_output=True,
+                    timeout=120,
+                )
+                os.replace(partial, lib_path)
+            except (OSError, subprocess.SubprocessError):
+                return None
     try:
         lib = ctypes.CDLL(lib_path)
     except OSError:

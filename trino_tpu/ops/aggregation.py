@@ -82,14 +82,15 @@ def group_aggregate(
     n = sel.shape[0]
     idx = jnp.arange(n, dtype=jnp.int32)
     # ONE narrow sort: all key columns (plus selection/validity bits) are
-    # bit-packed into 1-3 integer lanes (ops/keypack.py), sorted unstably
-    # — XLA:TPU sort compile time is ~linear in operand count AND doubles
-    # under is_stable, so the old per-column operand list compiled ~20x
-    # slower. Aggregate inputs RIDE the sort as payload lanes: a post-sort
-    # random gather costs ~35ms per column at 2^21 rows on v5e, ~10x the
-    # whole sort; payload moves inside the sort are near-free by
-    # comparison. Group-key outputs are recovered by G-sized bit
-    # extraction from the packed lanes (KeyPlan), not payload lanes.
+    # bit-packed into 1-3 integer lanes (ops/keypack.py), sorted unstably.
+    # The v5e compiler's time for a lax.sort grows with every operand
+    # (tens of seconds each at these sizes, keys and payload alike): with
+    # Q1's 17 aggregate-input lanes riding this sort as payload, its cold
+    # run on the chip had not ended when the call was cut at 1,800 s
+    # (PR 24). So the only payload is the row index, and aggregate inputs
+    # are gathered through that permutation.
+    # Group-key outputs are recovered by G-sized bit extraction from the
+    # packed lanes (KeyPlan).
     from trino_tpu.ops import keypack as KP
 
     plan = KP.KeyPlan(keys, sel_present=True)
@@ -98,37 +99,21 @@ def group_aggregate(
     n_packed = len(packed)
     key_ops = packed + list(native)
     nkey_ops = len(key_ops)
-    payload: list = []
-    payload_pos: dict[tuple, tuple] = {}
-    for pair in agg_inputs:
-        if pair is None:
-            continue
-        pid = (id(pair[0]), id(pair[1]))
-        if pid in payload_pos:
-            continue
-        data, valid = pair
-        base = nkey_ops + len(payload)
-        wide = getattr(data, "ndim", 1) == 2
-        lanes = [data[:, 0], data[:, 1]] if wide else [data]
-        if valid is not None:
-            lanes.append(valid)
-        payload.extend(lanes)
-        payload_pos[pid] = (wide, tuple(range(base, base + len(lanes))), valid is not None)
-    sorted_ops = jax.lax.sort(
-        tuple(key_ops) + tuple(payload), num_keys=nkey_ops, is_stable=False
+    *s_lanes, perm = jax.lax.sort(
+        tuple(key_ops) + (idx,), num_keys=nkey_ops, is_stable=False
     )
-    s_lanes = list(sorted_ops[:nkey_ops])
     s_sel = plan.sel_bit(s_lanes[0])
 
+    gathered: dict[int, jnp.ndarray] = {}  # one gather per distinct lane
+
+    def _sorted(lane):
+        if id(lane) not in gathered:
+            gathered[id(lane)] = lane[perm]
+        return gathered[id(lane)]
+
     def _sorted_pair(pair):
-        wide, pos, has_valid = payload_pos[(id(pair[0]), id(pair[1]))]
-        sv = sorted_ops[pos[-1]] if has_valid else None
-        if wide:
-            return (
-                jnp.stack([sorted_ops[pos[0]], sorted_ops[pos[1]]], axis=1),
-                sv,
-            )
-        return sorted_ops[pos[0]], sv
+        data, valid = pair
+        return _sorted(data), None if valid is None else _sorted(valid)
 
     # boundary: first row, or any sorted key lane changed vs previous row
     changed = idx == 0

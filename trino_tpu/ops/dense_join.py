@@ -39,23 +39,12 @@ The per-probe match-count contraction ``counts = onehot(bins) @ hist``
 is MXU-shaped; ``matmul_join_counts`` computes it as a real chunked
 ``jnp.dot`` for the bench/join-project path, while the traced tier uses
 the gather lowering of the same contraction (no n×C one-hot resident).
-
-Pallas: ``build_table_device`` is the NOTES_r05 gridless single-core
-kernel (in-kernel ``fori_loop`` insertion over double-buffered
-HBM→VMEM chunks, table resident in VMEM).  Per the NOTES constraints
-its outputs must be consumed from a SEPARATE jit (in-graph consumers of
-pallas outputs read corrupted values on this stack), so traced fragment
-programs use the jnp rounds above and the pallas kernel serves the
-standalone/bench path; both produce the same join output (see module
-tests for the equivalence).
 """
 
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from trino_tpu.ops.join import MISSING
 
@@ -253,132 +242,3 @@ def matmul_join_counts(
         0, nch, chunk_body, jnp.zeros(bins_p.shape[0], jnp.float32)
     )
     return out[:n].astype(jnp.int32)
-
-
-# ── gridless pallas build kernel (bench/standalone path) ────────────────
-
-
-def _make_build_kernel(ncap: int, capacity: int, ch: int, window: int):
-    nchunks = ncap // ch
-
-    def kernel(
-        base_hbm, use_hbm, table_out, ovf_out, tbuf, obuf, bbuf, ubuf,
-        sems, outsem,
-    ):
-        tbuf[:] = jnp.full((capacity,), EMPTY, jnp.int32)
-
-        def dma(c, slot):
-            off = c * jnp.int32(ch)
-            dst = pl.ds(slot * jnp.int32(ch), ch)
-            return [
-                pltpu.make_async_copy(
-                    base_hbm.at[pl.ds(off, ch)], bbuf.at[dst],
-                    sems.at[slot, jnp.int32(0)],
-                ),
-                pltpu.make_async_copy(
-                    use_hbm.at[pl.ds(off, ch)], ubuf.at[dst],
-                    sems.at[slot, jnp.int32(1)],
-                ),
-            ]
-
-        for d in dma(jnp.int32(0), jnp.int32(0)):
-            d.start()
-
-        def chunk_body(c, ovf):
-            slot = jax.lax.rem(c, jnp.int32(2))
-
-            @pl.when(c + jnp.int32(1) < jnp.int32(nchunks))
-            def _():
-                for d in dma(c + jnp.int32(1), jnp.int32(1) - slot):
-                    d.start()
-
-            for d in dma(c, slot):
-                d.wait()
-            off = slot * jnp.int32(ch)
-
-            def row_body(rr, ovf):
-                b = bbuf[off + rr]
-                u = ubuf[off + rr]
-                rid = c * jnp.int32(ch) + rr
-
-                def win(d, found):
-                    idx = (b + d) & jnp.int32(capacity - 1)
-                    vac = tbuf[idx] == EMPTY
-                    return jnp.where(
-                        (found < jnp.int32(0)) & vac, idx, found
-                    )
-
-                found = jax.lax.fori_loop(
-                    jnp.int32(0), jnp.int32(window), win, jnp.int32(-1)
-                )
-                # -2: dead row, no placement wanted (and no overflow)
-                found = jnp.where(u > jnp.int32(0), found, jnp.int32(-2))
-
-                @pl.when(found >= jnp.int32(0))
-                def _():
-                    tbuf[found] = rid
-
-                return ovf + jnp.where(
-                    found == jnp.int32(-1), jnp.int32(1), jnp.int32(0)
-                )
-
-            return jax.lax.fori_loop(
-                jnp.int32(0), jnp.int32(ch), row_body, ovf
-            )
-
-        ovf = jax.lax.fori_loop(
-            jnp.int32(0), jnp.int32(nchunks), chunk_body, jnp.int32(0)
-        )
-        obuf[:] = jnp.zeros((8,), jnp.int32)
-        obuf[0] = ovf
-        d1 = pltpu.make_async_copy(tbuf, table_out, outsem.at[jnp.int32(0)])
-        d2 = pltpu.make_async_copy(obuf, ovf_out, outsem.at[jnp.int32(1)])
-        d1.start()
-        d2.start()
-        d1.wait()
-        d2.wait()
-
-    return kernel
-
-
-def build_table_device(
-    slot_base: jnp.ndarray,
-    use: jnp.ndarray,
-    capacity: int,
-    window: int = PROBE_WINDOW,
-    interpret: bool = False,
-):
-    """Pallas build: sequential in-kernel insertion (first-vacant-slot
-    per row, rows in id order — the same per-key ascending placement the
-    jnp rounds produce, so probing either table emits identical joins).
-
-    Returns ``(table int32[capacity], unplaced int32)``; consume from a
-    SEPARATE jit (module doc).
-    """
-    n = slot_base.shape[0]
-    window = min(window, capacity)
-    ch = min(1024, max(256, n))
-    pad = (-n) % ch
-    base_p = jnp.pad(slot_base.astype(jnp.int32), (0, pad))
-    use_p = jnp.pad(use.astype(jnp.int32), (0, pad))
-    ncap = n + pad
-    kernel = _make_build_kernel(ncap, capacity, ch, window)
-    table, ovf = pl.pallas_call(
-        kernel,
-        in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 2,
-        out_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 2,
-        out_shape=[
-            jax.ShapeDtypeStruct((capacity,), jnp.int32),
-            jax.ShapeDtypeStruct((8,), jnp.int32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((capacity,), jnp.int32),
-            pltpu.VMEM((8,), jnp.int32),
-            pltpu.VMEM((2 * ch,), jnp.int32),
-            pltpu.VMEM((2 * ch,), jnp.int32),
-            pltpu.SemaphoreType.DMA((2, 2)),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
-        interpret=interpret,
-    )(base_p, use_p)
-    return table, ovf[0]

@@ -20,15 +20,10 @@ AXIS = "shards"
 
 
 def smap(f, mesh: Mesh, in_specs, out_specs):
-    """Version-compatible shard_map (check_vma/check_rep rename across JAX)."""
-    try:
-        from jax import shard_map as _sm
-
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)
-    except (ImportError, TypeError):
-        from jax.experimental.shard_map import shard_map as _sm
-
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False)
+    """``jax.shard_map`` over ``mesh`` without the replication check."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
+    )
 
 
 def make_mesh(n_devices: Optional[int] = None) -> Mesh:

@@ -23,7 +23,9 @@ def main(argv=None) -> int:
     parser.add_argument(
         "--platform",
         default=None,
-        help="force a JAX platform (e.g. cpu) before engine start",
+        help="JAX platform of this process, set before engine start. For "
+        "workers of CPU test clusters (--platform cpu); a server on the "
+        "chip takes JAX's default and fails at start-up without one",
     )
     parser.add_argument(
         "--spmd-coordinator",
