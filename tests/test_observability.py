@@ -293,6 +293,322 @@ class TestTracingIsInert:
         assert sink.trace_ids()  # and it actually traced something
 
 
+# --- program spans on the profiler's clock (served default path) ---------
+
+
+def _span(sid, parent, name, start, end, **attrs):
+    return {
+        "spanId": sid, "parentId": parent, "name": name,
+        "startNs": start, "endNs": end, "durationMs": (end - start) / 1e6,
+        "attrs": attrs,
+    }
+
+
+class TestSelfTimes:
+    """obs/trace.py::self_times / query_phases on hand-made timelines."""
+
+    def test_overlapping_and_cross_thread_children(self):
+        from trino_tpu.obs.trace import self_times
+
+        ms = 1_000_000
+        spans = [
+            _span("root", None, "query", 0, 100 * ms),
+            # two children that overlap (20..50 and 40..70: union 50 ms),
+            # the second as another thread's span would be
+            _span("a", "root", "plan", 20 * ms, 50 * ms),
+            _span("b", "root", "task", 40 * ms, 70 * ms),
+            # one that outlives its parent: only 90..100 counts
+            _span("c", "root", "pull", 90 * ms, 130 * ms),
+            _span("a1", "a", "inner", 25 * ms, 30 * ms),
+            # no clock stamps (another process's old span): left out
+            {"spanId": "old", "parentId": "root", "name": "x", "durationMs": 5.0},
+        ]
+        own = self_times(spans)
+        assert own == {"root": 40.0, "a": 25.0, "b": 30.0, "c": 40.0, "a1": 5.0}
+
+    def test_elided_spans_leave_their_time_with_the_kept_ancestor(self):
+        from trino_tpu.obs.trace import self_times
+
+        ms = 1_000_000
+        spans = [
+            _span("e", None, "execute_plan", 0, 100 * ms),
+            _span("j", "e", "op:Join", 0, 100 * ms),
+            _span("s", "j", "op:TableScan", 10 * ms, 40 * ms),
+            _span("d", "s", "ingest.decode", 10 * ms, 35 * ms),
+            # an operator below a span that is no operator: the join's child
+            _span("w", "j", "wrapper", 50 * ms, 90 * ms),
+            _span("f", "w", "op:Filter", 60 * ms, 80 * ms),
+        ]
+        own = self_times(spans, keep=lambda n: n.startswith("op:"))
+        assert own == {"j": 50.0, "s": 30.0, "f": 20.0}
+
+    def test_query_phases_names_phases_operators_and_compiles(self):
+        from trino_tpu.obs.trace import query_phases
+
+        ms = 1_000_000
+        spans = [
+            _span("q", None, "query", 0, 200 * ms, xlaCompiles=1, xlaCompileMs=2.5),
+            _span("p", "q", "parse", 1 * ms, 3 * ms),
+            _span("pl", "q", "plan", 3 * ms, 7 * ms),
+            _span("o", "q", "optimize", 7 * ms, 12 * ms),
+            _span("c", "q", "canonicalize", 12 * ms, 13 * ms),
+            _span("e", "q", "execute_plan", 20 * ms, 120 * ms),
+            _span("out", "e", "op:Output", 20 * ms, 120 * ms),
+            _span("agg", "out", "op:Aggregate", 21 * ms, 119 * ms,
+                  xlaCompiles=2, xlaCompileMs=30.0, xlaCacheLoads=1),
+            _span("s1", "agg", "op:TableScan", 21 * ms, 41 * ms),
+            _span("d", "s1", "ingest.decode", 22 * ms, 40 * ms),
+            _span("r", "q", "result.pull", 121 * ms, 130 * ms),
+        ]
+        got = query_phases(spans)
+        assert got["phaseMs"] == {
+            "parse": 2.0, "plan": 4.0, "optimize": 5.0, "canonicalize": 1.0,
+            "execute": 100.0, "resultPull": 9.0,
+        }
+        assert got["operatorMs"] == {
+            "Output": 2.0, "Aggregate": 78.0, "TableScan": 20.0,
+        }
+        assert sum(got["operatorMs"].values()) == got["phaseMs"]["execute"]
+        assert (got["xlaCompiles"], got["xlaCompileMs"], got["xlaCacheLoads"]) \
+            == (3, 32.5, 1)
+
+
+class TestProfilerBridge:
+    def test_no_sink_no_annotation(self, monkeypatch):
+        """Dark: the shared no-op span, and nothing of the profiler's made."""
+        from trino_tpu.obs import trace
+
+        made = []
+        monkeypatch.setattr(
+            trace, "TraceAnnotation", lambda name: made.append(name)
+        )
+        t = trace.Tracer()
+        assert t.start_span("query") is trace.NOOP_SPAN
+        with t.span("plan") as s:
+            s.add("attempts")
+        with t.activate(t.start_span("query")):
+            pass
+        assert made == []
+
+    @staticmethod
+    def _one_session(directory):
+        """One profiler session round two nested spans, one on a second
+        thread and the adoption of a span started elsewhere: the ``trino:``
+        events ``name -> (line, start_ns, duration_ns)`` and the spans."""
+        import glob
+        import threading
+        import time
+
+        import jax
+        from jax.profiler import ProfileData, TraceAnnotation
+
+        from trino_tpu.obs.trace import InMemorySpanSink, Tracer
+
+        t = Tracer()
+        sink = InMemorySpanSink()
+        t.add_sink(sink)
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        options.host_tracer_level = 1
+        jax.profiler.start_trace(str(directory), profiler_options=options)
+        try:
+            root = t.start_span("query", trace_id="qp")  # not bridged itself
+            with t.activate(root):  # ... its adoption is: trino:query
+                with t.span("outer"):
+                    time.sleep(0.002)
+                    with t.span("inner"):
+                        time.sleep(0.003)
+
+                    def worker(ctx=t.context()):
+                        with TraceAnnotation("warm"):
+                            pass  # a thread's first event costs the profiler extra
+                        with t.span("elsewhere", trace_id=ctx[0], parent_id=ctx[1]):
+                            time.sleep(0.002)
+
+                    th = threading.Thread(target=worker)
+                    th.start()
+                    th.join()
+            root.finish()
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = glob.glob(
+            str(directory / "plugins" / "profile" / "*" / "*.xplane.pb")
+        )
+        events = {}
+        for plane in ProfileData.from_file(path).planes:
+            for i, line in enumerate(plane.lines):  # one line per thread
+                for e in line.events:
+                    if e.name.startswith("trino:"):
+                        assert e.name not in events, e.name
+                        events[e.name] = (i, e.start_ns, e.duration_ns)
+        return events, {s["name"]: s for s in sink.spans_for("qp")}
+
+    def test_spans_are_events_on_the_profilers_clock(self, tmp_path):
+        """The spans appear as ``trino:*`` events of a real profiler
+        session; every event sits at the same offset from its span's own
+        ``startNs`` and lasts as long, within 100 us (the best of five
+        sessions: on a loaded host a thread can lose the processor between
+        the two stamps, and only ever to the span's cost)."""
+        worst = []
+        for attempt in range(5):
+            events, spans = self._one_session(tmp_path / str(attempt))
+            assert set(events) == {
+                "trino:query", "trino:outer", "trino:inner", "trino:elsewhere",
+            }
+            # the second thread's span is on another line of the same file
+            assert events["trino:elsewhere"][0] != events["trino:outer"][0]
+            # nesting holds on the profiler's clock as it does on the spans'
+            _, o_start, o_dur = events["trino:outer"]
+            _, i_start, i_dur = events["trino:inner"]
+            assert o_start <= i_start and i_start + i_dur <= o_start + o_dur
+            offsets, gaps = [], []
+            for name in ("outer", "inner", "elsewhere"):
+                _, start, dur = events["trino:" + name]
+                s = spans[name]
+                assert s["durationMs"] == pytest.approx(
+                    (s["endNs"] - s["startNs"]) / 1e6, abs=0.001
+                )
+                gaps.append(abs(s["endNs"] - s["startNs"] - dur))
+                offsets.append(start - s["startNs"])
+            worst.append(max(max(gaps), max(offsets) - min(offsets)))
+            if worst[-1] < 100_000:
+                break
+        assert min(worst) < 100_000, worst
+
+
+_SERVED_Q1 = """
+select l_returnflag, l_linestatus, sum(l_quantity), sum(l_extendedprice),
+       sum(l_extendedprice * (1 - l_discount)), avg(l_discount), count(*)
+from tpch.tiny.lineitem
+where l_shipdate <= date '1998-12-01' - interval '90' day
+group by l_returnflag, l_linestatus order by l_returnflag, l_linestatus
+"""
+_SERVED_Q3 = """
+select l_orderkey, sum(l_extendedprice * (1 - l_discount)) as revenue,
+       o_orderdate, o_shippriority
+from tpch.tiny.customer, tpch.tiny.orders, tpch.tiny.lineitem
+where c_mktsegment = 'BUILDING' and c_custkey = o_custkey
+  and l_orderkey = o_orderkey and o_orderdate < date '1995-03-15'
+  and l_shipdate > date '1995-03-15'
+group by l_orderkey, o_orderdate, o_shippriority
+order by revenue desc, o_orderdate limit 10
+"""
+
+
+@pytest.fixture(scope="module")
+def served():
+    from trino_tpu import client
+    from trino_tpu.server.http import TrinoTpuServer
+
+    server = TrinoTpuServer(port=0).start()
+    try:
+        yield server, client.Connection(server.base_uri, client.ClientSession())
+    finally:
+        server.stop()
+
+
+def _served_query(served, sql):
+    """Run ``sql`` in the default session; its ``/v1/query`` record and spans."""
+    server, conn = served
+    rows, _ = conn.execute(sql)
+    assert rows
+    import time
+
+    deadline = time.monotonic() + 5.0
+    while True:
+        # the root span closes just after the client has its last page
+        info = [q for q in conn.list_queries() if q["query"] == sql][-1]
+        spans = _get_json(
+            server.base_uri, f"/v1/query/{info['queryId']}/timeline"
+        )["spans"]
+        if any(s["name"] == "query" for s in spans) or time.monotonic() > deadline:
+            return info, spans
+        time.sleep(0.01)
+
+
+class TestServedDefaultPathSpans:
+    @pytest.mark.parametrize("sql", [_SERVED_Q1, _SERVED_Q3], ids=["q1", "q3"])
+    def test_every_phase_and_one_span_per_plan_node(self, served, sql):
+        from trino_tpu.planner import plan as P
+        from trino_tpu.sql.parser import parse_statement
+
+        server, _ = served
+        info, spans = _served_query(served, sql)
+        names = [s["name"] for s in spans]
+        for phase in ("query", "execute", "parse", "plan", "optimize",
+                      "canonicalize", "execute_plan", "result.pull"):
+            assert names.count(phase) == 1, (phase, names)
+        by_id = {s["spanId"]: s for s in spans}
+        ops = [s for s in spans if s["name"].startswith("op:")]
+        # one span per executed node: no node number twice
+        numbers = [s["attrs"]["node"] for s in ops]
+        assert len(set(numbers)) == len(numbers)
+        # ... and per node of the plan: the same node types as often (the
+        # probe side of a join may run as a copy that a dynamic filter made,
+        # with one more Filter where it pushed a domain)
+        from trino_tpu.config import Session
+
+        plan = server.engine.plan(parse_statement(sql), Session())
+        planned, stack = [], [plan]
+        while stack:
+            n = stack.pop()
+            planned.append(type(n).__name__)
+            stack.extend(n.sources)
+        assert isinstance(plan, P.Output)
+        kinds = [s["name"][3:] for s in ops]
+        for kind in set(planned) | set(kinds):
+            extra = kinds.count(kind) - planned.count(kind)
+            assert extra == 0 or (kind == "Filter" and 0 < extra <= planned.count("Join")), \
+                (kind, planned, kinds)
+        # operators nest as the plan does, under execute_plan
+        root_ops = [s for s in ops if by_id[s["parentId"]]["name"] == "execute_plan"]
+        assert [s["name"] for s in root_ops] == ["op:Output"]
+        for s in ops:
+            parent = by_id[s["parentId"]]
+            assert parent["name"].startswith("op:") or parent["name"] == "execute_plan"
+            assert parent["startNs"] <= s["startNs"] and s["endNs"] <= parent["endNs"]
+        # the scan's decode is a span below its operator, not a stamp after it
+        decodes = [s for s in spans if s["name"] == "ingest.decode"]
+        assert len(decodes) == kinds.count("TableScan")
+        assert all(by_id[s["parentId"]]["name"] == "op:TableScan" for s in decodes)
+        # the operators that chose something say so
+        for s in ops:
+            if s["name"] == "op:Aggregate":
+                assert s["attrs"]["groupBy"] == "sort" and s["attrs"]["attempts"] >= 1
+            if s["name"] == "op:Join":
+                assert s["attrs"]["joinKind"] == "INNER"
+                assert s["attrs"]["attempts"] == len(s["attrs"]["capacities"])
+
+        stats = info["queryStats"]
+        phases, operators = stats["phaseMs"], stats["operatorMs"]
+        assert set(phases) == {"parse", "plan", "optimize", "canonicalize",
+                               "execute", "resultPull"}
+        assert set(operators) == set(kinds)
+        assert sum(operators.values()) == pytest.approx(phases["execute"], rel=0.01)
+        assert stats["queuedMs"] + sum(phases.values()) \
+            == pytest.approx(stats["elapsedMs"], rel=0.05, abs=2.0)
+        execute_plan = next(s for s in spans if s["name"] == "execute_plan")
+        assert phases["execute"] == pytest.approx(execute_plan["durationMs"], abs=0.01)
+
+    def test_compiles_counted_on_a_shapes_first_execution_only(self, served):
+        # 211 rows: a shape no other test of this process has compiled for
+        values = ", ".join(f"({i}, {i % 7})" for i in range(211))
+        sql = (f"select k, sum(v), count(*) from (values {values}) t(v, k) "
+               "group by k order by k")
+        first, spans = _served_query(served, sql)
+        assert first["queryStats"]["xlaCompiles"] > 0
+        assert first["queryStats"]["xlaCompileMs"] > 0
+        # counted where they happened: under operators, not on the root
+        counted = {s["name"] for s in spans if s["attrs"].get("xlaCompiles")}
+        assert counted and all(n.startswith("op:") for n in counted), counted
+        second, _ = _served_query(served, sql)
+        assert second["queryId"] != first["queryId"]
+        assert second["queryStats"]["xlaCompiles"] == 0
+        assert second["queryStats"]["xlaCompileMs"] == 0
+        # the fragment programs' own counters keep their meaning
+        assert second["traceCount"] == 0 and second["compileMs"] == 0.0
+
+
 # --- distributed span/metrics tests (one shared 2-node cluster) ----------
 
 

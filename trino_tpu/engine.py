@@ -729,7 +729,11 @@ class Engine:
         cached = self.try_cached_result(sql, session)
         if cached is not None:
             return cached
-        stmt = parse_statement(sql)
+        from trino_tpu.obs.trace import get_tracer
+
+        with get_tracer().span("parse") as span:
+            stmt = parse_statement(sql)
+            span.set("statement", type(stmt).__name__)
         if isinstance(stmt, t.Prepare):
             # keep the statement's SQL text: it must survive the stateless
             # HTTP protocol via X-Trino-Added-Prepare
@@ -760,61 +764,72 @@ class Engine:
             # text — keys the program cache, so `x < 24` and `x < 25`
             # land on the same entry with different parameter vectors
             plan = self.plan(stmt, session)
-            # result-cache store context (tables + PRE-execution data
-            # versions); None when the cache is off or the shape refuses
-            rc_ctx = self._result_cache_begin(sql_text, session, plan)
-            exec_plan, params, entry, fp = plan, [], None, None
-            mode = session.get("execution_mode")
-            try:
-                wants_batch = int(session.get("batch_window_ms")) > 0
-            except KeyError:
-                wants_batch = False
-            mesh_n = (
-                int(self.mesh.devices.size) if self.mesh is not None else 1
-            )
-            if (
-                sql_text is not None
-                # cluster queries canonicalize only to join the batch
-                # collector (grouping needs the fingerprint); each
-                # member binds its own literals back before the
-                # scheduler ships fragments (_execute_query_plan)
-                and (
-                    mode == "distributed"
-                    or (mode == "cluster" and wants_batch)
-                )
-                and session.get("fragment_execution")
-                and bool(session.get("program_cache"))
-                and self._sql_cacheable(sql_text)
-            ):
-                from trino_tpu.planner.canonicalize import canonicalize_plan
+            # one span round what happens between the optimizer and the
+            # executor: the result-cache snapshot, the canonical fingerprint
+            # and the program-cache / history probes
+            from trino_tpu.obs.trace import get_tracer
 
-                canonical, params, fp = canonicalize_plan(
-                    plan, session, mesh_n
-                )
-                if fp is not None:
-                    exec_plan = canonical
-                    entry = self._query_cache_entry(fp)
-                else:
-                    params = []  # unserializable shape: run baked, uncached
-            elif (
-                sql_text is not None
-                and mode == "cluster"
-                and self._sql_cacheable(sql_text)
-            ):
-                # record-only fingerprint: cluster queries execute the
-                # baked plan, but the history store still keys their
-                # observed truth (and the admission gate their peak HBM)
-                # by the same canonical fingerprint
+            with get_tracer().span("canonicalize") as cspan:
+                # result-cache store context (tables + PRE-execution data
+                # versions); None when the cache is off or the shape refuses
+                rc_ctx = self._result_cache_begin(sql_text, session, plan)
+                exec_plan, params, entry, fp = plan, [], None, None
+                mode = session.get("execution_mode")
                 try:
-                    from trino_tpu.planner.canonicalize import (
-                        canonicalize_plan,
+                    wants_batch = int(session.get("batch_window_ms")) > 0
+                except KeyError:
+                    wants_batch = False
+                mesh_n = (
+                    int(self.mesh.devices.size) if self.mesh is not None else 1
+                )
+                if (
+                    sql_text is not None
+                    # cluster queries canonicalize only to join the batch
+                    # collector (grouping needs the fingerprint); each
+                    # member binds its own literals back before the
+                    # scheduler ships fragments (_execute_query_plan)
+                    and (
+                        mode == "distributed"
+                        or (mode == "cluster" and wants_batch)
                     )
+                    and session.get("fragment_execution")
+                    and bool(session.get("program_cache"))
+                    and self._sql_cacheable(sql_text)
+                ):
+                    from trino_tpu.planner.canonicalize import canonicalize_plan
 
-                    _, _, fp = canonicalize_plan(plan, session, mesh_n)
-                except Exception:  # noqa: BLE001
-                    fp = None
-            hist = self.history_store(session) if fp is not None else None
-            hist_entry = hist.get(fp) if hist is not None else None
+                    canonical, params, fp = canonicalize_plan(
+                        plan, session, mesh_n
+                    )
+                    if fp is not None:
+                        exec_plan = canonical
+                        entry = self._query_cache_entry(fp)
+                    else:
+                        params = []  # unserializable shape: run baked, uncached
+                elif (
+                    sql_text is not None
+                    and mode == "cluster"
+                    and self._sql_cacheable(sql_text)
+                ):
+                    # record-only fingerprint: cluster queries execute the
+                    # baked plan, but the history store still keys their
+                    # observed truth (and the admission gate their peak HBM)
+                    # by the same canonical fingerprint
+                    try:
+                        from trino_tpu.planner.canonicalize import (
+                            canonicalize_plan,
+                        )
+
+                        _, _, fp = canonicalize_plan(plan, session, mesh_n)
+                    except Exception:  # noqa: BLE001
+                        fp = None
+                hist = self.history_store(session) if fp is not None else None
+                hist_entry = hist.get(fp) if hist is not None else None
+                cspan.set("fingerprint", fp)
+                cspan.set(
+                    "cacheHit",
+                    entry is not None and entry["plan"] is not None,
+                )
             # cross-query batching: when the session opts in, compatible
             # queries (same fingerprint + same session signature) wait in
             # the collector for a short window and share ONE stacked
@@ -988,8 +1003,14 @@ class Engine:
             )
             cs = getattr(executor, "compile_stats", None) or {}
             dsnap = getattr(executor, "device_stats_snapshot", None)
+            from trino_tpu.obs.trace import get_tracer
+
+            # the device->host pull of the answer and the typing of its rows
+            with get_tracer().span("result.pull") as span:
+                rows = batch.to_pylist()
+                span.set("rows", len(rows))
             return StatementResult(
-                batch.to_pylist(),
+                rows,
                 names,
                 [c.type for c in batch.columns],
                 peak_memory_bytes=ctx.peak_bytes,

@@ -29,6 +29,7 @@ from trino_tpu.compiler import ExprCompiler
 from trino_tpu.config import Session
 from trino_tpu.connectors.api import CatalogManager
 from trino_tpu.ir import Call, Constant, InputRef, RowExpr, SpecialForm, Variable, bind_variables
+from trino_tpu.obs.trace import get_tracer
 from trino_tpu.ops import join as J
 from trino_tpu.ops.aggregation import AggSpec, global_aggregate, group_aggregate
 from trino_tpu.ops.sort import SortKey, sort_indices
@@ -105,6 +106,15 @@ class LocalExecutor:
         self.ingest_stats: dict = {}
         # engine-owned DeviceTableCache (None outside the engine)
         self.table_cache = None
+        # id(node) -> (number, node) for the ``op:`` spans: the plan's
+        # pre-order where execute() saw the root, visit order for nodes made
+        # while executing (a probe side rewritten by a dynamic filter)
+        self._node_numbers: dict[int, tuple[int, P.PlanNode]] = {}
+
+    def _node_number(self, node: P.PlanNode) -> int:
+        return self._node_numbers.setdefault(
+            id(node), (len(self._node_numbers), node)
+        )[0]
 
     def ingest_stats_snapshot(self) -> Optional[dict]:
         return dict(self.ingest_stats) if self.ingest_stats else None
@@ -133,15 +143,24 @@ class LocalExecutor:
 
     # === entry ==========================================================
     def execute(self, node: P.PlanNode) -> tuple[Batch, list[str]]:
-        from trino_tpu.obs.trace import get_tracer
-
         with get_tracer().span(
             "execute_plan", attrs={"executor": type(self).__name__}
         ):
+            stack = [node]
+            while stack:  # pre-order, the order EXPLAIN prints
+                n = stack.pop()
+                self._node_number(n)
+                stack.extend(reversed(n.sources))
             if isinstance(node, P.Output):
-                res = self._exec(node.source)
-                cols = [res.column(s) for s in node.symbols]
-                out = Batch(cols, res.batch.num_rows, res.batch.sel).compact()
+                # the root is a plan node too: its span holds the compaction
+                with get_tracer().span(
+                    "op:Output", attrs={"node": self._node_number(node)}
+                ):
+                    res = self._exec(node.source)
+                    cols = [res.column(s) for s in node.symbols]
+                    out = Batch(
+                        cols, res.batch.num_rows, res.batch.sel
+                    ).compact()
                 return out, node.column_names
             res = self._exec(node)
             return res.batch.compact(), [s.name for s in node.output_symbols]
@@ -161,29 +180,37 @@ class LocalExecutor:
         method = getattr(self, f"_exec_{type(node).__name__.lower()}", None)
         if method is None:
             raise ExecutionError(f"no executor for {type(node).__name__}")
-        if self.stats_collector is not None:
-            import time as _time
+        # one span per plan node, nesting as the plan does: host wall in the
+        # operator, waits on the device included. Never opened while JAX
+        # traces a fragment program: _FragmentTracer overrides _exec
+        with get_tracer().span(
+            "op:" + type(node).__name__,
+            attrs={"node": self._node_number(node)},
+        ):
+            if self.stats_collector is not None:
+                import time as _time
 
-            from trino_tpu.memory import batch_nbytes
+                from trino_tpu.memory import batch_nbytes
 
-            t0 = _time.perf_counter()
-            res = method(node)
-            rows = int(res.batch.count_rows())  # device sync: exact timing
-            self.stats_collector.record(
-                node, _time.perf_counter() - t0, rows, batch_nbytes(res.batch)
-            )
-        else:
-            res = method(node)
-        if self.memory_ctx is not None:
-            from trino_tpu.memory import batch_nbytes
+                t0 = _time.perf_counter()
+                res = method(node)
+                rows = int(res.batch.count_rows())  # device sync: exact timing
+                self.stats_collector.record(
+                    node, _time.perf_counter() - t0, rows,
+                    batch_nbytes(res.batch),
+                )
+            else:
+                res = method(node)
+            if self.memory_ctx is not None:
+                from trino_tpu.memory import batch_nbytes
 
-            nbytes = batch_nbytes(res.batch)
-            self.memory_ctx.reserve(nbytes, what=type(node).__name__)
-            self._reservations[id(node)] = nbytes
-            # children's intermediates are dead once this node materialized
-            for s in node.sources:
-                self.memory_ctx.free(self._reservations.pop(id(s), 0))
-        return res
+                nbytes = batch_nbytes(res.batch)
+                self.memory_ctx.reserve(nbytes, what=type(node).__name__)
+                self._reservations[id(node)] = nbytes
+                # children's intermediates are dead once this node materialized
+                for s in node.sources:
+                    self.memory_ctx.free(self._reservations.pop(id(s), 0))
+            return res
 
     # === leaf nodes =====================================================
     def _exec_tablescan(self, node: P.TableScan) -> Result:
@@ -194,27 +221,22 @@ class LocalExecutor:
         )
         if not splits:
             return Result(self._empty_batch(node), {s.name: i for i, s in enumerate(node.symbols)})
-        import time as _time
-
-        from trino_tpu.obs.trace import get_tracer
-
-        t0 = _time.perf_counter()
         batches = []
         rows_read = 0
-        for b in self._read_splits(
-            connector, node.schema, node.table, node.column_names, splits
-        ):
-            batches.append(b)
-            rows_read += b.num_rows
-            # connector applyLimit hint: stop pulling splits once the
-            # pushed row budget is covered (the Limit node still enforces)
-            if node.limit is not None and rows_read >= node.limit:
-                break
-        get_tracer().record(
-            "ingest.decode",
-            (_time.perf_counter() - t0) * 1000.0,
-            attrs={"table": node.table, "splits": len(batches)},
-        )
+        with get_tracer().span(
+            "ingest.decode", attrs={"table": node.table}
+        ) as span:
+            for b in self._read_splits(
+                connector, node.schema, node.table, node.column_names, splits
+            ):
+                batches.append(b)
+                rows_read += b.num_rows
+                # connector applyLimit hint: stop pulling splits once the
+                # pushed row budget is covered (the Limit node still enforces)
+                if node.limit is not None and rows_read >= node.limit:
+                    break
+            span.set("splits", len(batches))
+            span.set("rows", rows_read)
         batch = concat_batches(batches) if len(batches) > 1 else batches[0]
         layout = {s.name: i for i, s in enumerate(node.symbols)}
         return Result(batch, layout)
@@ -562,6 +584,29 @@ class LocalExecutor:
             return self._aggregate_final(node, self._exec(node.source))
         return self._aggregate_result(node, self._exec(node.source))
 
+    def _group_aggregate(self, keys, sel, agg_inputs, specs):
+        """``group_aggregate`` up its capacity ladder: a run whose groups
+        overflow ``max_groups`` is made again with four times the room. The
+        operator's span says how often (``attempts``) and where it ended."""
+        max_groups = 1 << 12
+        attempts = 1
+        while True:
+            keys_out, results, ng, overflow = group_aggregate(
+                keys, sel, agg_inputs, specs, max_groups
+            )
+            if not bool(overflow):
+                break
+            max_groups <<= 2
+            attempts += 1
+            if max_groups > (1 << 26):
+                raise ExecutionError("group-by cardinality too large")
+        span = get_tracer().current()
+        if span is not None:
+            span.set("groupBy", "sort")
+            span.add("attempts", attempts)
+            span.set("maxGroups", max_groups)
+        return keys_out, results, int(ng)
+
     def _aggregate_partial(self, node: P.Aggregate, res: Result) -> Result:
         """PARTIAL step: emit accumulator columns (value, count) per agg —
         the wire representation between fragments (reference:
@@ -576,17 +621,9 @@ class LocalExecutor:
             cols, layout = self._acc_columns(node, raw, 1, string_aggs)
             return Result(Batch(cols, 1), layout)
         keys = [res.pair(k) for k in node.group_keys]
-        max_groups = 1 << 12
-        while True:
-            (kd, kv), raw, ng, overflow = group_aggregate(
-                keys, sel, agg_inputs, specs, max_groups
-            )
-            if not bool(overflow):
-                break
-            max_groups <<= 2
-            if max_groups > (1 << 26):
-                raise ExecutionError("group-by cardinality too large")
-        ng = int(ng)
+        (kd, kv), raw, ng = self._group_aggregate(
+            keys, sel, agg_inputs, specs
+        )
         cols: list[Column] = []
         layout: dict[str, int] = {}
         for i, k in enumerate(node.group_keys):
@@ -754,17 +791,9 @@ class LocalExecutor:
             )
         keys = [res.pair(k) for k in node.group_keys]
         key_dicts = [res.column(k).dictionary for k in node.group_keys]
-        max_groups = 1 << 12
-        while True:
-            (kd, kv), raw, ng, overflow = group_aggregate(
-                keys, sel, combine_inputs, combine_specs, max_groups
-            )
-            if not bool(overflow):
-                break
-            max_groups <<= 2
-            if max_groups > (1 << 26):
-                raise ExecutionError("group-by cardinality too large")
-        ng = int(ng)
+        (kd, kv), raw, ng = self._group_aggregate(
+            keys, sel, combine_inputs, combine_specs
+        )
         cols = []
         for i, k in enumerate(node.group_keys):
             valid = np.asarray(kv[i])[:ng]
@@ -987,17 +1016,9 @@ class LocalExecutor:
 
         keys = [res.pair(k) for k in node.group_keys]
         key_dicts = [res.column(k).dictionary for k in node.group_keys]
-        max_groups = 1 << 12
-        while True:
-            (kd, kv), results, ng, overflow = group_aggregate(
-                keys, sel, agg_inputs, specs, max_groups
-            )
-            if not bool(overflow):
-                break
-            max_groups <<= 2
-            if max_groups > (1 << 26):
-                raise ExecutionError("group-by cardinality too large")
-        ng = int(ng)
+        (kd, kv), results, ng = self._group_aggregate(
+            keys, sel, agg_inputs, specs
+        )
         cols = []
         for i, k in enumerate(node.group_keys):
             valid = np.asarray(kv[i])[:ng]
@@ -1318,6 +1339,9 @@ class LocalExecutor:
 
     # === joins ==========================================================
     def _exec_join(self, node: P.Join) -> Result:
+        span = get_tracer().current()
+        if span is not None:  # a RIGHT join re-enters flipped: keep the first
+            span.attrs.setdefault("joinKind", node.join_type)
         if node.join_type == "CROSS":
             return self._exec_cross_join(node)
         if node.join_type in ("SEMI", "ANTI"):
@@ -1350,6 +1374,8 @@ class LocalExecutor:
             and int(left.batch.count_rows()) + int(right.batch.count_rows())
             > int(self.session.get("spill_threshold_rows"))
         ):
+            if span is not None:
+                span.set("spilled", True)
             return self._spill_join(node, left, right)
         return self._join_result(node, left, right)
 
@@ -1433,16 +1459,10 @@ class LocalExecutor:
         sbk, sbi, bcount = J.build_side(bh, bv, right.batch.selection_mask())
         probe_sel = left.batch.selection_mask()
         est = max(1024, left.batch.count_rows() * 2, right.batch.count_rows())
-        out_capacity = bucket_capacity(est)
-        while True:
-            ppos, bpos, osel, total, ovf = J.probe_join(
-                sbk, sbi, bcount, ph, pv, probe_sel,
-                out_capacity,
-                "left" if node.join_type in ("LEFT", "FULL") else "inner",
-            )
-            if not bool(ovf):
-                break
-            out_capacity = bucket_capacity(int(total))
+        ppos, bpos, osel, out_capacity = self._probe_join(
+            sbk, sbi, bcount, ph, pv, probe_sel, bucket_capacity(est),
+            "left" if node.join_type in ("LEFT", "FULL") else "inner",
+        )
         osel = J.verify_equal(lkeys, rkeys, ppos, bpos, osel)
         if node.join_type == "LEFT":
             # verify may drop hash-collision rows; outer padding rows keep
@@ -1575,6 +1595,30 @@ class LocalExecutor:
             )
         return out
 
+    def _probe_join(
+        self, sbk, sbi, bcount, ph, pv, probe_sel, out_capacity: int, kind: str
+    ):
+        """``probe_join`` into ``out_capacity`` rows; where the matches
+        overflow it, once more into the capacity they need. The operator's
+        span says how often (``attempts``) and at which capacities."""
+        capacities = [out_capacity]
+        while True:
+            ppos, bpos, osel, total, ovf = J.probe_join(
+                sbk, sbi, bcount, ph, pv, probe_sel, out_capacity, kind
+            )
+            if not bool(ovf):
+                break
+            out_capacity = bucket_capacity(int(total))
+            capacities.append(out_capacity)
+        span = get_tracer().current()
+        if span is not None:
+            span.set("strategy", "sort-probe")
+            span.add("attempts", len(capacities))
+            span.set(
+                "capacities", span.attrs.get("capacities", []) + capacities
+            )
+        return ppos, bpos, osel, out_capacity
+
     def _join_keys(self, left: Result, right: Result, criteria):
         lkeys, rkeys = [], []
         for ls, rs in criteria:
@@ -1667,16 +1711,11 @@ class LocalExecutor:
         sbk, sbi, bcount = J.build_side(bh, bv, right.batch.selection_mask())
         # exact: expand matches, verify, then scatter-mark probe rows
         probe_sel = left.batch.selection_mask()
-        out_capacity = bucket_capacity(
-            max(1024, left.batch.count_rows() * 2)
+        ppos, bpos, osel, _ = self._probe_join(
+            sbk, sbi, bcount, ph, pv, probe_sel,
+            bucket_capacity(max(1024, left.batch.count_rows() * 2)),
+            "inner",
         )
-        while True:
-            ppos, bpos, osel, total, ovf = J.probe_join(
-                sbk, sbi, bcount, ph, pv, probe_sel, out_capacity, "inner"
-            )
-            if not bool(ovf):
-                break
-            out_capacity = bucket_capacity(int(total))
         osel = J.verify_equal(lkeys, rkeys, ppos, bpos, osel)
         if node.filter is not None:
             # residual correlated condition: evaluate over (probe row,

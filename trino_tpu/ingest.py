@@ -250,31 +250,29 @@ def shard_batch_coalesced(
 
         return shard_batch(mesh, parts)
 
-    t0 = time.perf_counter()
-    arenas = [pack_arena(bufs, use_native=use_native) for bufs in per_part]
-    words = arenas[0].size
-    arena_g = _global(mesh, sharding, arenas)
-    outs = _unpack_program(mesh, tuple(signature))(arena_g)
-
-    # per-column device_put for non-arena dtypes (float64)
-    results: dict[tuple, Any] = dict(zip(slots, outs))
-    fallback_bytes = 0
-    for slot, arrays in fallback:
-        results[slot] = _global(mesh, sharding, arrays)
-        fallback_bytes += sum(a.nbytes for a in arrays)
-
-    total_bytes = n * words * 4 + fallback_bytes
-    h2d_ms = (time.perf_counter() - t0) * 1000.0
     from trino_tpu.obs.metrics import get_registry
     from trino_tpu.obs.trace import get_tracer
 
+    t0 = time.perf_counter()
+    with get_tracer().span("ingest.h2d") as span:
+        arenas = [pack_arena(bufs, use_native=use_native) for bufs in per_part]
+        words = arenas[0].size
+        arena_g = _global(mesh, sharding, arenas)
+        outs = _unpack_program(mesh, tuple(signature))(arena_g)
+
+        # per-column device_put for non-arena dtypes (float64)
+        results: dict[tuple, Any] = dict(zip(slots, outs))
+        fallback_bytes = 0
+        for slot, arrays in fallback:
+            results[slot] = _global(mesh, sharding, arrays)
+            fallback_bytes += sum(a.nbytes for a in arrays)
+
+        total_bytes = n * words * 4 + fallback_bytes
+        span.set("bytes", total_bytes)
+        span.set("transfers", n + len(fallback) * n)
+    h2d_ms = (time.perf_counter() - t0) * 1000.0
     get_registry().counter("trino_tpu_ingest_h2d_bytes_total").inc(
         total_bytes
-    )
-    get_tracer().record(
-        "ingest.h2d",
-        h2d_ms,
-        attrs={"bytes": total_bytes, "transfers": n + len(fallback) * n},
     )
     if stats is not None:
         stats["h2d_bytes"] = stats.get("h2d_bytes", 0) + total_bytes

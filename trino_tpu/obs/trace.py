@@ -12,8 +12,18 @@ Design constraints (per the hot-path rule in the issue):
   allocations, no clock reads, nothing to garbage-collect. Servers
   register an :class:`InMemorySpanSink`; a bare engine run traces
   nothing.
-- **No deps.** Plain dataclass + ``itertools.count`` ids; durations
-  come from ``time.monotonic()`` (epoch kept only for display).
+- **One clock pair.** A span keeps ``time.monotonic_ns()`` stamps
+  (``startNs``/``endNs`` in ``to_json``, unrounded; the epoch is kept only
+  for display), so :func:`self_times` needs the timeline alone.
+- **On the profiler's clock.** A span entered and left on one thread
+  (``with``) is also a ``jax.profiler.TraceAnnotation`` named
+  ``trino:<name>``: a flag test while no profiler session runs, an event
+  beside the device's operations while one does. A span opened with
+  ``start_span()`` and finished elsewhere is not bridged.
+- **Compilations are counted where they happen.** One ``jax.monitoring``
+  listener adds every backend compilation (and persistent-cache load) to
+  the span current on the compiling thread: ``xlaCompiles``,
+  ``xlaCompileMs``, ``xlaCacheLoads``; :func:`compile_counts` sums a trace.
 - **Threads don't inherit context.** The ambient "current span" lives
   in a ``threading.local`` stack, so spans started on the same thread
   nest automatically, but work handed to another thread (query
@@ -31,9 +41,20 @@ import time
 import uuid
 from collections import OrderedDict
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
+
+from jax import monitoring as _monitoring
+from jax.profiler import TraceAnnotation
 
 TRACE_HEADER = "X-Trino-Trace"
+#: prefix of the spans' names in a profiler trace (``benchmark/tracereduce.py``
+#: gathers ``bench:`` names only, so it reads what it read before)
+ANNOTATION_PREFIX = "trino:"
+# jax.monitoring duration events: the first fires once per executable built
+# (eager primitive or jit, compiled or loaded from the persistent cache),
+# the second only for a load; neither fires on a warm call
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
 
 _ids = itertools.count(1)
 # span ids must stay unique across the whole cluster: a timeline is the
@@ -58,20 +79,28 @@ class Span:
     attrs: Dict[str, Any] = field(default_factory=dict)
     duration_ms: Optional[float] = None
     status: str = "OK"
-    _start_mono: float = 0.0
+    start_ns: int = 0  # time.monotonic_ns()
+    end_ns: Optional[int] = None
     _tracer: Optional["Tracer"] = None
     _done: bool = False
+    _annotation: Any = None
 
     def set(self, key: str, value: Any) -> None:
         self.attrs[key] = value
+
+    def add(self, key: str, amount: float = 1) -> None:
+        """Count into an attribute (``attempts``, ``xlaCompiles``)."""
+        self.attrs[key] = self.attrs.get(key, 0) + amount
 
     def finish(self, status: str = "OK", **attrs: Any) -> None:
         """Close the span and hand it to the sinks. Idempotent."""
         if self._done:
             return
         self._done = True
+        if self.end_ns is None:
+            self.end_ns = time.monotonic_ns()
         if self.duration_ms is None:
-            self.duration_ms = (time.monotonic() - self._start_mono) * 1000.0
+            self.duration_ms = (self.end_ns - self.start_ns) / 1e6
         self.status = status
         if attrs:
             self.attrs.update(attrs)
@@ -88,6 +117,8 @@ class Span:
             "parentId": self.parent_id,
             "name": self.name,
             "startMs": round(self.start_epoch * 1000.0, 1),
+            "startNs": self.start_ns,
+            "endNs": self.end_ns,
             "durationMs": round(self.duration_ms, 3)
             if self.duration_ms is not None
             else None,
@@ -99,9 +130,18 @@ class Span:
     def __enter__(self) -> "Span":
         if self._tracer is not None:
             self._tracer._push(self)
+        self._annotation = TraceAnnotation(ANNOTATION_PREFIX + self.name)
+        self._annotation.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
+        if self._annotation is not None:
+            # stamped first: a thread's first event takes the profiler
+            # a while to close, after the event's own end
+            if not self._done:
+                self.end_ns = time.monotonic_ns()
+            self._annotation.__exit__(None, None, None)
+            self._annotation = None
         if self._tracer is not None:
             self._tracer._pop(self)
         if exc is not None and not self._done:
@@ -120,6 +160,9 @@ class _NoopSpan:
     parent_id = None
 
     def set(self, key: str, value: Any) -> None:
+        pass
+
+    def add(self, key: str, amount: float = 1) -> None:
         pass
 
     def finish(self, status: str = "OK", **attrs: Any) -> None:
@@ -215,7 +258,7 @@ class Tracer:
             name=name,
             start_epoch=time.time(),
             attrs=dict(attrs) if attrs else {},
-            _start_mono=time.monotonic(),
+            start_ns=time.monotonic_ns(),
             _tracer=self,
         )
 
@@ -257,6 +300,7 @@ class Tracer:
                     trace_id = cur.trace_id
         if trace_id is None:
             trace_id = _next_id("t")
+        end_ns = time.monotonic_ns()
         span = Span(
             trace_id=trace_id,
             span_id=_next_id("s"),
@@ -265,6 +309,8 @@ class Tracer:
             start_epoch=time.time() - duration_ms / 1000.0,
             attrs=dict(attrs) if attrs else {},
             duration_ms=duration_ms,
+            start_ns=end_ns - int(duration_ms * 1e6),
+            end_ns=end_ns,
             _tracer=self,
         )
         span._done = True
@@ -280,18 +326,27 @@ class Tracer:
 
 
 class _Activation:
-    __slots__ = ("_tracer", "_span")
+    __slots__ = ("_tracer", "_span", "_annotation")
 
     def __init__(self, tracer: Tracer, span) -> None:
         self._tracer = tracer
         self._span = span
+        self._annotation = None
 
     def __enter__(self):
         if isinstance(self._span, Span):
             self._tracer._push(self._span)
+            # the adopting thread's share of the span, on the profiler's clock
+            self._annotation = TraceAnnotation(
+                ANNOTATION_PREFIX + self._span.name
+            )
+            self._annotation.__enter__()
         return self._span
 
     def __exit__(self, exc_type, exc, tb) -> bool:
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+            self._annotation = None
         if isinstance(self._span, Span):
             self._tracer._pop(self._span)
         return False
@@ -347,7 +402,113 @@ def parse_trace_header(value: Optional[str]) -> Optional[Tuple[str, str]]:
     return (trace_id.strip(), span_id.strip())
 
 
+# -- the timeline reduced (choosing-metrics §4: self time) ---------------
+
+def self_times(
+    spans: Iterable[Dict[str, Any]],
+    keep: Optional[Callable[[str], bool]] = None,
+) -> Dict[str, float]:
+    """``spanId`` -> self milliseconds for ``to_json`` spans of one trace:
+    a span's duration less the union of its children's intervals (clipped
+    to its own), whatever thread a child ran on. Spans without both clock
+    stamps are left out.
+
+    With ``keep`` (a predicate on the name) the other spans are elided:
+    their time stays with the nearest kept ancestor, and their kept
+    descendants count as that ancestor's children."""
+    spans = [
+        s for s in spans
+        if s.get("startNs") is not None and s.get("endNs") is not None
+    ]
+    by_id = {s["spanId"]: s for s in spans}
+    kept = [s for s in spans if keep is None or keep(s["name"])]
+    kept_ids = {s["spanId"] for s in kept}
+    children: Dict[str, List[Tuple[int, int]]] = {}
+    for s in kept:
+        parent = s.get("parentId")
+        while parent in by_id and parent not in kept_ids:
+            parent = by_id[parent].get("parentId")
+        if parent in by_id:
+            children.setdefault(parent, []).append((s["startNs"], s["endNs"]))
+    out: Dict[str, float] = {}
+    for s in kept:
+        lo, hi = s["startNs"], s["endNs"]
+        covered, at = 0, lo
+        for a, b in sorted(children.get(s["spanId"], ())):
+            a, b = max(a, at), min(b, hi)
+            if b > a:
+                covered += b - a
+                at = b
+        out[s["spanId"]] = (hi - lo - covered) / 1e6
+    return out
+
+
+#: span name -> the ``phaseMs`` key that sums its durations
+PHASE_OF_SPAN = {
+    "parse": "parse", "plan": "plan", "optimize": "optimize",
+    "canonicalize": "canonicalize", "execute_plan": "execute",
+    "result.pull": "resultPull",
+}
+OPERATOR_PREFIX = "op:"
+
+
+def query_phases(spans: Iterable[Dict[str, Any]]) -> Dict[str, Any]:
+    """One query's timeline as ``queryStats`` serves it: ``phaseMs`` (span
+    durations by phase), ``operatorMs`` (self times of the ``op:`` spans,
+    summed by node type: host wall in the operator, waits on the device and
+    the ingest spans below a scan included) and the compile counts."""
+    spans = list(spans)
+    phases = dict.fromkeys(PHASE_OF_SPAN.values(), 0.0)
+    for s in spans:
+        key = PHASE_OF_SPAN.get(s["name"])
+        if key is not None and s.get("durationMs") is not None:
+            phases[key] += s["durationMs"]
+    operators: Dict[str, float] = {}
+    own = self_times(spans, keep=lambda n: n.startswith(OPERATOR_PREFIX))
+    for s in spans:
+        if s["spanId"] in own:
+            kind = s["name"][len(OPERATOR_PREFIX):]
+            operators[kind] = operators.get(kind, 0.0) + own[s["spanId"]]
+    return {
+        "phaseMs": {k: round(v, 3) for k, v in phases.items()},
+        "operatorMs": {k: round(v, 3) for k, v in operators.items()},
+        **compile_counts(spans),
+    }
+
+
+def compile_counts(spans: Iterable[Dict[str, Any]]) -> Dict[str, Any]:
+    """A trace's XLA compilations: each span carries what was compiled
+    while it was the innermost one on its thread, so the sum is the
+    query's."""
+    n = loads = 0
+    ms = 0.0
+    for s in spans:
+        attrs = s.get("attrs") or {}
+        n += attrs.get("xlaCompiles", 0)
+        ms += attrs.get("xlaCompileMs", 0.0)
+        loads += attrs.get("xlaCacheLoads", 0)
+    return {
+        "xlaCompiles": n, "xlaCompileMs": round(ms, 3), "xlaCacheLoads": loads,
+    }
+
+
 _TRACER = Tracer()
+
+
+def _on_duration_event(event: str, duration_secs: float, **_kw: Any) -> None:
+    if event not in (_COMPILE_EVENT, _CACHE_LOAD_EVENT):
+        return
+    cur = _TRACER.current()
+    if cur is None:
+        return
+    if event == _COMPILE_EVENT:
+        cur.add("xlaCompiles")
+        cur.add("xlaCompileMs", duration_secs * 1000.0)
+    else:
+        cur.add("xlaCacheLoads")
+
+
+_monitoring.register_event_duration_secs_listener(_on_duration_event)
 
 
 def get_tracer() -> Tracer:

@@ -32,6 +32,7 @@ from trino_tpu.columnar import Batch, Column, bucket_capacity
 from trino_tpu.config import Session
 from trino_tpu.connectors.api import CatalogManager
 from trino_tpu.exec.local import ExecutionError, LocalExecutor, Result
+from trino_tpu.obs.trace import get_tracer
 from trino_tpu.ops import join as J
 from trino_tpu.ops.aggregation import AggSpec, group_aggregate
 from trino_tpu.parallel.mesh import AXIS, make_mesh, shard_batch, smap
@@ -213,6 +214,9 @@ class DistributedExecutor(LocalExecutor):
                 self.mesh,
             )
             cached = self.table_cache.lookup(cache_key)
+            span = get_tracer().current()  # this scan's op:TableScan
+            if span is not None:
+                span.set("tableCacheHit", cached is not None)
             if cached is not None:
                 stats["table_cache_hits"] = stats.get("table_cache_hits", 0) + 1
                 return Result(cached, layout)
@@ -220,23 +224,18 @@ class DistributedExecutor(LocalExecutor):
                 stats.get("table_cache_misses", 0) + 1
             )
 
-        import time as _time
-
-        from trino_tpu.obs.trace import get_tracer
-
-        t0 = _time.perf_counter()
         per_shard: list[list[Batch]] = [[] for _ in range(n)]
-        for i, b in enumerate(
-            self._read_splits(
-                connector, node.schema, node.table, node.column_names, splits
-            )
-        ):
-            per_shard[i % n].append(b)
-        get_tracer().record(
+        with get_tracer().span(
             "ingest.decode",
-            (_time.perf_counter() - t0) * 1000.0,
             attrs={"table": node.table, "splits": len(splits)},
-        )
+        ):
+            for i, b in enumerate(
+                self._read_splits(
+                    connector, node.schema, node.table, node.column_names,
+                    splits,
+                )
+            ):
+                per_shard[i % n].append(b)
         parts = []
         empty_proto = None
         for shard_batches in per_shard:

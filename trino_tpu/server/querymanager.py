@@ -27,7 +27,7 @@ from typing import Any, Optional
 from trino_tpu import types as T
 from trino_tpu.config import Session
 from trino_tpu.engine import Engine, StatementResult
-from trino_tpu.obs.trace import get_tracer
+from trino_tpu.obs.trace import get_tracer, query_phases
 from trino_tpu.server.statemachine import (
     QueryState,
     StateMachine,
@@ -97,6 +97,7 @@ class ManagedQuery:
         self._engine = engine
         self._completed_fired = False
         self._completed_lock = threading.Lock()
+        self._phase_stats_done: Optional[dict] = None
         # root span for the whole query (covers queued time); the dispatch
         # thread re-activates it so engine/scheduler spans nest under it
         self.span = get_tracer().start_span(
@@ -269,15 +270,7 @@ class ManagedQuery:
             # classification only — the full stack would bloat the
             # bounded journal without aiding post-mortem triage
             err.pop("failureInfo", None)
-        spans = None
-        try:
-            for sink in getattr(get_tracer(), "_sinks", []):
-                spans_for = getattr(sink, "spans_for", None)
-                if spans_for is not None:
-                    spans = spans_for(self.query_id)
-                    break
-        except Exception:  # noqa: BLE001
-            spans = None
+        spans = self._trace_spans()
         self._flight_event(
             "completed",
             state=st.value,
@@ -295,6 +288,33 @@ class ManagedQuery:
             error=err,
             spans=spans,
         )
+
+    def _trace_spans(self) -> Optional[list]:
+        """This query's finished spans, from the first sink that keeps them."""
+        try:
+            for sink in getattr(get_tracer(), "_sinks", []):
+                spans_for = getattr(sink, "spans_for", None)
+                if spans_for is not None:
+                    return spans_for(self.query_id)
+        except Exception:  # noqa: BLE001
+            pass
+        return None
+
+    def _phase_stats(self) -> dict:
+        """The timeline reduced (obs/trace.py::query_phases): phaseMs,
+        operatorMs and the compile counts; kept once the query has ended
+        (``GET /v1/query`` lists a hundred queries at a time)."""
+        if self._phase_stats_done is not None:
+            return self._phase_stats_done
+        ended = getattr(self.span, "_done", True)  # read before the spans
+        try:
+            spans = self._trace_spans()
+            stats = query_phases(spans) if spans else {}
+        except Exception:  # noqa: BLE001 — observability must not fail queries
+            stats = {}
+        if ended:
+            self._phase_stats_done = stats
+        return stats
 
     def cancel(self, message: str = "Query was canceled") -> None:
         self._cancelled.set()
@@ -461,6 +481,9 @@ class ManagedQuery:
             "recoveredTasks": cluster_stats.get("recovered_tasks", 0),
             "spooledBytes": cluster_stats.get("spooled_bytes", 0),
             "stages": cluster_stats.get("stages", []),
+            # the query's spans reduced: phaseMs, operatorMs (self times by
+            # node type), xlaCompiles / xlaCompileMs / xlaCacheLoads
+            **self._phase_stats(),
         }
 
     def _start_mono(self) -> Optional[float]:
