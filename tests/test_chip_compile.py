@@ -24,6 +24,7 @@ from trino_tpu.exec.streaming import dense_program
 from trino_tpu.ops import dense_groupby as DG
 from trino_tpu.ops import dense_join as DJ
 from trino_tpu.ops import keypack
+from trino_tpu.ops.join import probe_join
 from trino_tpu.parallel.exchange import hash_repartition
 from trino_tpu.parallel.mesh import AXIS
 
@@ -159,6 +160,25 @@ def test_dense_join_probe(one_chip):
         _shape(one_chip, (npr,), jnp.bool_),
         _shape(one_chip, (npr,), jnp.bool_),
     )
+
+
+def test_sort_join_probe_has_no_loop(one_chip):
+    """The sort tier's probe at Q3's lineitem join (6,001,215 probe rows,
+    a 2^21-row build, 2^23 output slots): the chip's compiler must turn
+    neither its sorts, its scans nor its scatter into a ``while``."""
+    nb, npr, cap = _JOIN_BUILD, 6_001_215, _JOIN_CAP
+    compiled = _compile(
+        lambda sbk, sbi, cnt, ph, pv, psel: probe_join(
+            sbk, sbi, cnt, ph, pv, psel, cap, "inner"
+        ),
+        _shape(one_chip, (nb,), jnp.int64),
+        _shape(one_chip, (nb,), jnp.int32),
+        _shape(one_chip, (), jnp.int32),
+        _shape(one_chip, (npr,), jnp.int64),
+        _shape(one_chip, (npr,), jnp.bool_),
+        _shape(one_chip, (npr,), jnp.bool_),
+    )
+    assert "while" not in compiled.as_text()
 
 
 # sel bit + key bits + 23 row-index bits, packed into 63-bit int64 lanes.

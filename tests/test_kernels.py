@@ -1,5 +1,6 @@
 """Golden tests for the kernel substrate vs NumPy reference computations."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -12,7 +13,9 @@ from trino_tpu.ops.aggregation import AggSpec, global_aggregate, group_aggregate
 from trino_tpu.ops.join import (
     build_side,
     hash_keys,
+    merge_rank,
     probe_join,
+    slot_owner,
     verify_equal,
     MISSING,
 )
@@ -234,6 +237,79 @@ class TestGroupAggregate:
         assert int(res[1]) == 3
 
 
+_JOIN_CAP = 8192  # the property test's one out_capacity
+_JOIN_FILL = _JOIN_CAP + 2  # probe rows that top the total up, one match each
+
+
+def _join_case(case, rng):
+    """(build keys, valid, sel, probe keys, valid, sel) of one named case:
+    600 build rows, 1,100 probe rows, then ``_JOIN_FILL`` unselected probe
+    rows that each match build row 0 alone, so a test can choose ``total``
+    by selecting some of them and every case has the same shapes."""
+    nb, npr = 600, 1100
+    bvalid, bsel = np.ones(nb, bool), np.ones(nb, bool)
+    pvalid, psel = np.ones(npr, bool), np.ones(npr, bool)
+    if case == "distinct":
+        bk = rng.permutation(4 * nb)[:nb]
+        pk = rng.permutation(4 * nb)[:npr]
+    elif case == "duplicates":
+        bk = rng.integers(0, 7, nb)
+        pk = rng.integers(0, 9, npr)
+    elif case == "absent":
+        bk = rng.integers(0, 300, nb)
+        pk = rng.integers(300, 600, npr)
+        pk[::50] = bk[:22]
+    elif case == "nulls-unselected":
+        bk = rng.integers(0, 200, nb)
+        pk = rng.integers(0, 250, npr)
+        bvalid, pvalid = rng.random(nb) < 0.7, rng.random(npr) < 0.7
+        bsel, psel = rng.random(nb) < 0.6, rng.random(npr) < 0.6
+    elif case == "short-build":
+        bk = rng.integers(0, 200, nb)
+        pk = rng.integers(0, 200, npr)
+        bsel = np.arange(nb) < 37  # build_count far below the capacity
+    elif case == "emit-gaps":
+        # long stretches of rows that emit nothing between emitting rows
+        bk = rng.integers(0, 40, nb)
+        pk = np.where(np.arange(npr) % 97 == 5, rng.integers(0, 40, npr), -1)
+        psel = rng.random(npr) < 0.9
+    else:
+        raise AssertionError(case)
+    fill_key = 1 << 40
+    bk[0], bvalid[0], bsel[0] = fill_key, True, True
+    fill = np.full(_JOIN_FILL, fill_key)
+    return (
+        bk, bvalid, bsel,
+        np.concatenate([pk, fill]),
+        np.concatenate([pvalid, np.ones(_JOIN_FILL, bool)]),
+        np.concatenate([psel, np.zeros(_JOIN_FILL, bool)]),
+    )
+
+
+def _probe_join_by_search(sbk, sbi, build_count, ph, pv, psel, cap, jt):
+    """The probe as it was before the sort-merge kernel: three binary
+    searches. The reference the kernel has to equal in every live slot."""
+    use = pv & psel
+    maxv = jnp.iinfo(jnp.int64).max
+    keys = jnp.where(use, ph, maxv - 1)
+    lo = jnp.searchsorted(sbk, keys, side="left")
+    hi = jnp.searchsorted(sbk, keys, side="right")
+    hi = jnp.minimum(hi, build_count)
+    lo = jnp.minimum(lo, hi)
+    counts = jnp.where(use, hi - lo, 0)
+    emit = jnp.where(psel, jnp.maximum(counts, 1), 0) if jt == "left" else counts
+    offsets = jnp.cumsum(emit) - emit
+    total = offsets[-1] + emit[-1]
+    t = jnp.arange(cap, dtype=emit.dtype)
+    ppos = jnp.searchsorted(offsets + emit, t, side="right").astype(jnp.int32)
+    ppos = jnp.minimum(ppos, emit.shape[0] - 1)
+    slot = lo[ppos] + (t - offsets[ppos])
+    bpos = jnp.where(
+        counts[ppos] > 0, sbi[jnp.clip(slot, 0, sbi.shape[0] - 1)], MISSING
+    )
+    return (keys, offsets, emit), (ppos, bpos, t < total, total, total > cap)
+
+
 class TestJoin:
     def test_inner_join_with_duplicates(self):
         build_keys = np.array([1, 2, 2, 3, 5], dtype=np.int64)
@@ -298,6 +374,79 @@ class TestJoin:
             sbk, sbi, cnt, ph, pv, jnp.ones(8, bool), out_capacity=16
         )
         assert bool(ovf) and int(total) == 64
+
+    @pytest.mark.parametrize("seed", [0, 1, 2])
+    @pytest.mark.parametrize("jt", ["inner", "left"])
+    @pytest.mark.parametrize(
+        "case",
+        ["distinct", "duplicates", "absent", "nulls-unselected",
+         "short-build", "emit-gaps"],
+    )
+    def test_sort_merge_probe_equals_binary_search(self, case, jt, seed):
+        """``merge_rank`` is ``searchsorted`` left and right, ``slot_owner``
+        the old search for the row that owns a slot, and ``probe_join`` the
+        old probe in every live slot, with ``total`` equal to, one under and
+        one over ``out_capacity``."""
+        cap = _JOIN_CAP
+        rng = np.random.default_rng(seed)
+        bk, bvalid, bsel, pk, pvalid, psel = _join_case(case, rng)
+        bh, bv = hash_keys([(jnp.asarray(bk), jnp.asarray(bvalid))])
+        ph, pv = hash_keys([(jnp.asarray(pk), jnp.asarray(pvalid))])
+        sbk, sbi, cnt = build_side(bh, bv, jnp.asarray(bsel))
+        # keep the longest prefix of the case's rows that leaves room for
+        # a fill row, then choose the total by the fill rows selected
+        (_, _, emit), _ = _probe_join_by_search(
+            sbk, sbi, cnt, ph, pv, jnp.asarray(psel), cap, jt
+        )
+        psel &= np.cumsum(np.asarray(emit)) <= cap - 2
+        base = int(np.asarray(emit)[psel].sum())
+        assert base > 2
+        n_case = pk.shape[0] - _JOIN_FILL
+        for total in (cap, cap - 1, cap + 1):
+            sel = psel.copy()
+            sel[n_case : n_case + total - base] = True
+            sel = jnp.asarray(sel)
+            (keys, offsets, emit), want = _probe_join_by_search(
+                sbk, sbi, cnt, ph, pv, sel, cap, jt
+            )
+            assert int(want[3]) == total
+            lo, hi = merge_rank(sbk, keys)
+            np.testing.assert_array_equal(
+                lo, jnp.searchsorted(sbk, keys, side="left")
+            )
+            np.testing.assert_array_equal(
+                hi, jnp.searchsorted(sbk, keys, side="right")
+            )
+            got = probe_join(sbk, sbi, cnt, ph, pv, sel, cap, jt)
+            live = np.asarray(want[2])
+            assert live.sum() == min(total, cap)
+            np.testing.assert_array_equal(got[2], live)
+            assert int(got[3]) == total
+            assert bool(got[4]) == bool(want[4]) == (total > cap)
+            ppos, bpos = np.asarray(got[0]), np.asarray(got[1])
+            np.testing.assert_array_equal(ppos[live], np.asarray(want[0])[live])
+            np.testing.assert_array_equal(bpos[live], np.asarray(want[1])[live])
+            np.testing.assert_array_equal(
+                np.asarray(slot_owner(offsets, emit, cap))[live], ppos[live]
+            )
+            # dead slots: indices still in range
+            assert ppos.min() >= 0 and ppos.max() < pk.shape[0]
+            assert ((bpos == MISSING) | ((bpos >= 0) & (bpos < bk.shape[0]))).all()
+
+    @pytest.mark.parametrize("jt", ["inner", "left"])
+    def test_probe_join_lowers_without_a_loop(self, jt):
+        """No ``while`` in the probe's program: no binary search, and no
+        scan or scatter that lowers to one."""
+        n_probe, n_build, cap = 6000, 2048, 8192
+        spec = jax.ShapeDtypeStruct
+        text = probe_join.lower(
+            spec((n_build,), jnp.int64), spec((n_build,), jnp.int32),
+            spec((), jnp.int32), spec((n_probe,), jnp.int64),
+            spec((n_probe,), jnp.bool_), spec((n_probe,), jnp.bool_),
+            cap, jt,
+        ).as_text()
+        assert "sort" in text and "scatter" in text
+        assert "while" not in text
 
 
 class TestSort:

@@ -1612,7 +1612,7 @@ class LocalExecutor:
             capacities.append(out_capacity)
         span = get_tracer().current()
         if span is not None:
-            span.set("strategy", "sort-probe")
+            span.set("strategy", "sort-merge-probe")
             span.add("attempts", len(capacities))
             span.set(
                 "capacities", span.attrs.get("capacities", []) + capacities

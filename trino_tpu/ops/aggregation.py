@@ -209,26 +209,39 @@ def group_aggregate(
     return (out_key_data, out_key_valid), results, num_groups, overflow
 
 
-def _prefix_sum(x):
-    """Inclusive prefix sum via a blocked two-level scan.
+def _blocked_scan(x, scan, combine, ident):
+    """Inclusive prefix scan via a blocked two-level scan.
 
-    ``jnp.cumsum`` lowers to one big reduce-window: its scoped-vmem
-    allocation blows up inside TPU while-loops (the streaming chunk
-    loop), and XLA:TPU takes ~1min to COMPILE an int64 reduce-window at
-    odd (non-power-of-two) sizes. Odd sizes are padded to a block
-    multiple so every window stays small and power-of-two shaped."""
+    ``jnp.cumsum`` (and ``lax.cummax``) lower to one big reduce-window:
+    its scoped-vmem allocation blows up inside TPU while-loops (the
+    streaming chunk loop), and XLA:TPU takes ~1min to COMPILE an int64
+    reduce-window at odd (non-power-of-two) sizes. Odd sizes are padded
+    with ``ident`` to a block multiple so every window stays small and
+    power-of-two shaped."""
     n = x.shape[0]
     blk = 512
     if n <= blk:
-        return jnp.cumsum(x)
+        return scan(x)
     pad = (-n) % blk
-    xp = jnp.concatenate([x, jnp.zeros((pad,), x.dtype)]) if pad else x
+    xp = jnp.concatenate([x, jnp.full((pad,), ident, x.dtype)]) if pad else x
     xb = jnp.reshape(xp, ((n + pad) // blk, blk))
-    within = jnp.cumsum(xb, axis=1)
-    offsets = jnp.cumsum(within[:, -1])
-    offsets = jnp.concatenate([jnp.zeros((1,), x.dtype), offsets[:-1]])
-    out = jnp.reshape(within + offsets[:, None], (n + pad,))
+    within = scan(xb, axis=1)
+    offsets = scan(within[:, -1])
+    offsets = jnp.concatenate([jnp.full((1,), ident, x.dtype), offsets[:-1]])
+    out = jnp.reshape(combine(within, offsets[:, None]), (n + pad,))
     return out[:n] if pad else out
+
+
+def _prefix_sum(x):
+    """Inclusive prefix sum (blocked, see ``_blocked_scan``)."""
+    return _blocked_scan(x, jnp.cumsum, jnp.add, 0)
+
+
+def _prefix_max(x):
+    """Inclusive running maximum of an integer array (blocked)."""
+    return _blocked_scan(
+        x, jax.lax.cummax, jnp.maximum, jnp.iinfo(x.dtype).min
+    )
 
 
 def _segmented_scan(flags, x, kind: str):

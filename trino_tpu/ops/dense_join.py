@@ -46,7 +46,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from trino_tpu.ops.join import MISSING
+from trino_tpu.ops.join import MISSING, probe_join, slot_owner
 
 # vacant-slot sentinel: int32 max, deliberately equal to join.MISSING —
 # row ids are always < capacity < 2^31 so no live entry collides with it
@@ -135,8 +135,6 @@ def probe_table(
     if probe_hash.shape[0] == 0 or build_hash.shape[0] == 0:
         # statically empty side: defer to the sort tier's guard logic,
         # which already covers LEFT-over-empty-build row emission
-        from trino_tpu.ops.join import probe_join
-
         empty_keys = jnp.zeros((0,), dtype=jnp.int64)
         empty_idx = jnp.zeros((0,), dtype=jnp.int32)
         return probe_join(
@@ -169,9 +167,7 @@ def probe_table(
     overflow = total > out_capacity
 
     t = jnp.arange(out_capacity, dtype=emit.dtype)
-    ends = offsets + emit
-    probe_pos = jnp.searchsorted(ends, t, side="right").astype(jnp.int32)
-    probe_pos = jnp.minimum(probe_pos, emit.shape[0] - 1)
+    probe_pos = slot_owner(offsets, emit, out_capacity)
     j = t - offsets[probe_pos]
 
     # second W-round pass: per output slot, the j-th matching window

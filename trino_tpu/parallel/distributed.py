@@ -901,7 +901,7 @@ def _sharded_probe(
     build side, probe local rows, expand into fixed capacity.
 
     ``strategy`` picks the join kernel: ``sort`` (ops/join.py bitonic
-    build + binary-search probe), ``dense`` (ops/dense_join.py
+    build + sort-merge probe), ``dense`` (ops/dense_join.py
     open-addressing table of ``table_cap`` slots), or ``matmul`` (same
     table addressed by identity binning of the single key column).
     Non-sort strategies return a FOURTH element — the table-overflow
