@@ -4,7 +4,9 @@ One process, no children. Boots ``TrinoTpuServer`` in-process, talks to it
 only through ``trino_tpu.client.Connection`` (POST /v1/statement ->
 nextUri), runs TPC-H Q6, Q1 and Q3 at scale factor 1 (cold, then warm)
 under the default session and under ``execution_mode=distributed``, and
-compares every row with the TPC-H SF1 answers kept below as literals.
+compares every row with the TPC-H SF1 answers kept below as literals. Each
+query of the compiled session then runs with other literals, and the two
+sessions have to agree on that answer too.
 
     python chip_smoke.py            # one chip (what the driver runs)
     python chip_smoke.py --chips 4  # the 4-device mesh phase only
@@ -71,6 +73,15 @@ DISTRIBUTED = {"execution_mode": "distributed"}
 # ROADMAP.md S2/S7), so it stays in the default session and in --chips 4.
 DISTRIBUTED_QUERIES = (6, 1)
 LOCAL_QUERIES = (6, 1, 3)
+# A second literal for each compiled query: the same plan fingerprint, so
+# the stored programs answer it, and another answer. With one literal a
+# query, PR 24's smoke passed while the compiled tier answered every variant
+# with its first execution's rows (PERF.md section 6, PR 28).
+VARIANTS = {
+    6: (("date '1994-01-01'", "date '1995-01-01'"),
+        ("l_quantity < 24", "l_quantity < 25")),
+    1: (("interval '90' day", "interval '75' day"),),
+}
 
 
 def require(ok, why) -> None:
@@ -104,8 +115,13 @@ def run_query(conn: Connection, sql: str) -> tuple[list[tuple], dict, float]:
     rows, _ = conn.execute(sql)
     seconds = time.perf_counter() - t0
     (qid,) = {q["queryId"] for q in conn.list_queries()} - seen
-    with urllib.request.urlopen(f"{conn.base_uri}/v1/query/{qid}", timeout=30) as r:
-        info = json.loads(r.read().decode())
+    deadline = time.monotonic() + 5.0
+    while True:  # the record turns FINISHED just after the last page is out
+        with urllib.request.urlopen(f"{conn.base_uri}/v1/query/{qid}", timeout=30) as r:
+            info = json.loads(r.read().decode())
+        if info["state"] != "FINISHING" or time.monotonic() > deadline:
+            break
+        time.sleep(0.01)
     require(info["state"] == "FINISHED", info)
     return normalise(rows), info, seconds
 
@@ -176,6 +192,27 @@ def run_pair(server, conn, qn: int, session: str, sql: str, peak) -> list[tuple]
     return results[0]
 
 
+def variant(qn: int, sql: str) -> str:
+    for old, new in VARIANTS[qn]:
+        require(old in sql, f"Q{qn}: no {old!r} to vary")
+        sql = sql.replace(old, new)
+    return sql
+
+
+def run_variant(conn, qn: int, session: str, sql: str, base) -> list[tuple]:
+    """The query with its other literals: an answer of its own, and in the
+    compiled session out of the programs the first literals left."""
+    rows, info, seconds = run_query(conn, variant(qn, sql))
+    require(rows != base, f"Q{qn} [{session}]: the variant got the first answer")
+    if session == "distributed":
+        assert_compiled(qn, info)
+        require(info["traceCount"] == 0, (qn, "the variant traced", info["traceCount"]))
+        require(info["programCacheHits"] >= 1, (qn, info["programCacheHits"]))
+    say(query=f"Q{qn}", session=session, run="variant", smoke_seconds=seconds,
+        traceCount=info["traceCount"], programCacheHits=info["programCacheHits"])
+    return rows
+
+
 def one_chip(server: TrinoTpuServer, device) -> None:
     def peak():
         return (device.memory_stats() or {}).get("peak_bytes_in_use")
@@ -183,16 +220,21 @@ def one_chip(server: TrinoTpuServer, device) -> None:
     text = queries("tpch.sf1")
     dist = Connection(server.base_uri, ClientSession(properties=dict(DISTRIBUTED)))
     local = Connection(server.base_uri, ClientSession())
-    answers = {
-        qn: run_pair(server, dist, qn, "distributed", text[qn], peak)
-        for qn in DISTRIBUTED_QUERIES
-    }
+    answers, variants = {}, {}
+    for qn in DISTRIBUTED_QUERIES:
+        answers[qn] = run_pair(server, dist, qn, "distributed", text[qn], peak)
+        variants[qn] = run_variant(dist, qn, "distributed", text[qn], answers[qn])
     for qn in LOCAL_QUERIES:
         rows = run_pair(server, local, qn, "local", text[qn], peak)
         require(
             qn not in answers or rows == answers[qn],
             f"Q{qn}: the two sessions disagree",
         )
+        if qn in variants:
+            require(
+                run_variant(local, qn, "local", text[qn], rows) == variants[qn],
+                f"Q{qn}: the two sessions disagree on the variant",
+            )
 
 
 def four_chips(server: TrinoTpuServer, devices) -> None:

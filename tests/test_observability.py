@@ -363,7 +363,7 @@ class TestSelfTimes:
         got = query_phases(spans)
         assert got["phaseMs"] == {
             "parse": 2.0, "plan": 4.0, "optimize": 5.0, "canonicalize": 1.0,
-            "execute": 100.0, "resultPull": 9.0,
+            "execute": 100.0, "resultPull": 9.0, "slab": 0.0,
         }
         assert got["operatorMs"] == {
             "Output": 2.0, "Aggregate": 78.0, "TableScan": 20.0,
@@ -582,7 +582,8 @@ class TestServedDefaultPathSpans:
         stats = info["queryStats"]
         phases, operators = stats["phaseMs"], stats["operatorMs"]
         assert set(phases) == {"parse", "plan", "optimize", "canonicalize",
-                               "execute", "resultPull"}
+                               "execute", "resultPull", "slab"}
+        assert phases["slab"] == 0  # the compiled tier's, inside execute
         assert set(operators) == set(kinds)
         assert sum(operators.values()) == pytest.approx(phases["execute"], rel=0.01)
         assert stats["queuedMs"] + sum(phases.values()) \
@@ -607,6 +608,94 @@ class TestServedDefaultPathSpans:
         assert second["queryStats"]["xlaCompileMs"] == 0
         # the fragment programs' own counters keep their meaning
         assert second["traceCount"] == 0 and second["compileMs"] == 0.0
+
+
+class TestServedCompiledPathSpans:
+    """``execution_mode=distributed`` on one device, lineitem streamed
+    through the slab program: the same phases as the default session, the
+    slab inside ``execute``, and one ``execute_plan`` whatever falls back."""
+
+    @pytest.fixture(scope="class")
+    def compiled(self):
+        from trino_tpu import client
+        from trino_tpu.engine import Engine
+        from trino_tpu.parallel.mesh import make_mesh
+        from trino_tpu.server.http import TrinoTpuServer
+
+        engine = Engine()
+        engine.mesh = make_mesh(1)  # the slab path is the one-device path
+        server = TrinoTpuServer(engine=engine, port=0).start()
+        session = client.ClientSession(properties={
+            "execution_mode": "distributed", "stream_scan_threshold_rows": 1,
+        })
+        try:
+            yield server, client.Connection(server.base_uri, session)
+        finally:
+            server.stop()
+
+    def test_phases_and_the_slab_span(self, compiled):
+        first, cold = _served_query(compiled, _SERVED_Q1)
+        sql = _SERVED_Q1.replace("'90'", "'75'")  # a literal variant: a hit
+        info, spans = _served_query(compiled, sql)
+        names = [s["name"] for s in spans]
+        for phase in ("query", "parse", "plan", "optimize", "canonicalize",
+                      "execute_plan", "result.pull", "stream.slab"):
+            assert names.count(phase) == 1, (phase, names)
+        by_id = {s["spanId"]: s for s in spans}
+
+        def ancestors(s):
+            while s["parentId"] in by_id:
+                s = by_id[s["parentId"]]
+                yield s["name"]
+
+        execute_plan = next(s for s in spans if s["name"] == "execute_plan")
+        assert execute_plan["attrs"]["executor"] == "FragmentedExecutor"
+        assert "fallback" not in execute_plan["attrs"]
+        slab = next(s for s in spans if s["name"] == "stream.slab")
+        assert "execute_plan" in ancestors(slab)
+        assert slab["attrs"]["cacheHit"] is True
+        assert slab["attrs"]["params"] >= 1 and slab["attrs"]["attempt"] == 1
+        assert slab["attrs"]["steps"] >= 1 and slab["attrs"]["cap"] >= 1
+        assert slab["attrs"]["groups"] >= 4
+        cold_slab = next(s for s in cold if s["name"] == "stream.slab")
+        assert cold_slab["attrs"]["cacheHit"] is False
+        # spans round the work, not stamps after it: each lies inside its
+        # parent on the same clock
+        pulls = [s for s in spans if s["name"] == "device_pull"]
+        compiles = [s for s in cold if s["name"] == "program_compile"]
+        assert len(pulls) == 1 and pulls[0]["attrs"]["attempt"] == 1
+        assert compiles and all(s["attrs"]["key"] for s in compiles)
+        assert not [s for s in spans if s["name"] == "program_compile"]
+        for s, among in [(p, spans) for p in pulls] + [(c, cold) for c in compiles]:
+            parent = {x["spanId"]: x for x in among}[s["parentId"]]
+            assert parent["startNs"] <= s["startNs"] < s["endNs"] <= parent["endNs"]
+        assert "execute_plan" in ancestors(pulls[0])
+
+        stats = info["queryStats"]
+        phases = stats["phaseMs"]
+        assert phases["execute"] > 0 and 0 < phases["slab"] <= phases["execute"]
+        assert phases["execute"] == pytest.approx(execute_plan["durationMs"], abs=0.01)
+        assert phases["slab"] == pytest.approx(slab["durationMs"], abs=0.01)
+        sequential = sum(v for k, v in phases.items() if k != "slab")
+        assert stats["queuedMs"] + sequential \
+            == pytest.approx(stats["elapsedMs"], rel=0.05, abs=2.0)
+        assert info["traceCount"] == 0 and info["programCacheHits"] >= 1
+        assert first["traceCount"] >= 1
+
+    def test_one_execute_plan_when_the_fused_path_falls_back(self, compiled):
+        # a window is not traced into a fragment program: interpreted
+        sql = ("select o_orderkey, row_number() over (order by o_orderkey) "
+               "from tpch.tiny.orders where o_orderkey < 40")
+        info, spans = _served_query(compiled, sql)
+        plans = [s for s in spans if s["name"] == "execute_plan"]
+        assert len(plans) == 1
+        assert plans[0]["attrs"] == {
+            "executor": "FragmentedExecutor", "fallback": "interpreter"}
+        ops = [s for s in spans if s["name"].startswith("op:")]
+        assert ops and not [s for s in spans if s["name"] == "stream.slab"]
+        phases = info["queryStats"]["phaseMs"]
+        assert phases["slab"] == 0
+        assert phases["execute"] == pytest.approx(plans[0]["durationMs"], abs=0.01)
 
 
 # --- distributed span/metrics tests (one shared 2-node cluster) ----------

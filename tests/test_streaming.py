@@ -324,3 +324,154 @@ class TestDeviceSlabStreaming:
         second, _ = slab_runner.execute(sql)  # cached program + slab
         want, _ = slab_local.execute(sql)
         assert first == second == want
+
+
+# --- literals reach the stored slab program as arguments ---------------------
+
+Q1 = """select l_returnflag, l_linestatus, sum(l_quantity),
+               sum(l_extendedprice), sum(l_extendedprice * (1 - l_discount)),
+               sum(l_extendedprice * (1 - l_discount) * (1 + l_tax)),
+               avg(l_quantity), avg(l_extendedprice), avg(l_discount), count(*)
+        from lineitem
+        where l_shipdate <= date '1998-12-01' - interval '{0}' day
+        group by l_returnflag, l_linestatus
+        order by l_returnflag, l_linestatus"""
+Q6 = """select sum(l_extendedprice * l_discount) from lineitem
+        where l_shipdate >= date '{0}-01-01' and l_shipdate < date '{1}-01-01'
+          and l_discount between {2} - 0.01 and {2} + 0.01
+          and l_quantity < {3}"""
+# orders is the build side of the streamed lineitem probe: a broadcast
+# fragment of its own, whose filter takes its literal through __params__
+# as fragment programs always did; the probe's literal is the step's
+JOIN = """select o_orderpriority, sum(l_quantity), count(*)
+          from lineitem, orders
+          where l_orderkey = o_orderkey and o_totalprice < {0}
+            and l_quantity < {1}
+          group by o_orderpriority order by o_orderpriority"""
+VARIANTS = {
+    "q1": (Q1, [(90,), (120,), (60,), (97,)]),
+    "q6": (Q6, [(1994, 1995, "0.06", 24), (1995, 1996, "0.04", 25),
+                (1993, 1994, "0.08", 24)]),
+    # same digits, so one fingerprint; the second varies the build side alone
+    "join": (JOIN, [("150000.00", 30), ("190000.00", 30), ("250000.00", 11)]),
+}
+
+
+class TestLiteralVariantsThroughTheSlab:
+    """One engine, the program cache on, lineitem streamed through
+    ``_run_device_slab`` (one device, as on the chip): every execution after
+    a shape's first is a literal variant of a cached plan and has to answer
+    for ITS literals out of the stored program, tracing nothing."""
+
+    @pytest.fixture(scope="class")
+    def compiled(self):
+        from trino_tpu.exec import streaming as S
+
+        r = DistributedQueryRunner(n_devices=1)
+        r.session.set("stream_scan_threshold_rows", 1)
+        baked = DistributedQueryRunner(n_devices=1)
+        baked.engine = r.engine
+        baked.session.set("stream_scan_threshold_rows", 1)
+        baked.session.set("program_cache", False)
+        calls = {"slab": 0, "joins": 0}
+        orig = S.StreamingAggregator._slab_attempt
+
+        def counting(self, *args):
+            calls["slab"] += 1
+            calls["joins"] += bool(self.build_roots)
+            return orig(self, *args)
+
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(S.StreamingAggregator, "_slab_attempt", counting)
+            yield {"cached": r, "baked": baked, "calls": calls, "seen": {},
+                   "local": LocalQueryRunner(engine=r.engine)}
+
+    @pytest.mark.parametrize(
+        "shape,variant",
+        [(s, i) for s, (_, vs) in VARIANTS.items() for i in range(len(vs))],
+    )
+    def test_answer_is_this_literals(self, compiled, shape, variant):
+        template, variants = VARIANTS[shape]
+        sql = template.format(*variants[variant])
+        r = compiled["cached"]
+        before = dict(compiled["calls"])
+        res = r.engine.execute_statement(sql, r.session)
+        assert compiled["calls"]["slab"] == before["slab"] + 1, "never streamed"
+        want, _ = compiled["local"].execute(sql)
+        baked, _ = compiled["baked"].execute(sql)
+        assert want and res.rows == want, (shape, variants[variant])
+        assert baked == want
+        nth = compiled["seen"].get(shape, 0)
+        compiled["seen"][shape] = nth + 1
+        if shape == "join":
+            # the step closes over this query's build sides, so it is not
+            # stored and every execution traces it again: ROADMAP S2 (d)
+            assert compiled["calls"]["joins"] == before["joins"] + 2
+            assert res.trace_count >= 1
+        elif nth:
+            assert res.trace_count == 0 and res.program_cache_misses == 0
+            assert res.program_cache_hits >= 1
+
+    def test_another_plan_gets_its_own_program(self, compiled):
+        """Same fragment id, same ordinal, another aggregate: the store is
+        the fingerprint's, so the content key cannot meet a stranger."""
+        r = compiled["cached"]
+        sql = ("select l_returnflag, l_linestatus, max(l_quantity), count(*)"
+               " from lineitem where l_shipdate <= date '1998-09-02'"
+               " group by l_returnflag, l_linestatus order by 1, 2")
+        res = r.engine.execute_statement(sql, r.session)
+        assert res.trace_count >= 1
+        assert res.rows == compiled["local"].execute(sql)[0]
+
+
+def test_equal_plans_at_other_addresses_share_the_slab_program():
+    """D4: what ``StreamingAggregator`` stores is keyed by content (fragment
+    id, the aggregate's ordinal), not by ``id(node)``: an equal plan planned
+    again finds the program, and its literals still ride as arguments."""
+    from trino_tpu.exec import streaming as S
+    from trino_tpu.exec.fragments import FragmentedExecutor, _Caps
+    from trino_tpu.planner.canonicalize import canonicalize_plan
+    from trino_tpu.planner.fragmenter import fragment_plan
+    from trino_tpu.sql.parser import parse_statement
+
+    r = DistributedQueryRunner(n_devices=1)
+    r.session.set("stream_scan_threshold_rows", 1)
+    sql = ("select l_linestatus, sum(l_quantity), count(*) from lineitem"
+           " where l_quantity < {} group by l_linestatus")
+    programs: dict = {}
+
+    def aggregator(literal):
+        plan = r.engine.plan(parse_statement(sql.format(literal)), r.session)
+        plan, params, fp = canonicalize_plan(plan, r.session, 1)
+        assert fp is not None and [v for v, _ in params] == [literal]
+        ex = FragmentedExecutor(
+            r.engine.catalogs, r.session, r.engine.mesh,
+            programs=programs, params=params,
+        )
+        frag = next(
+            f for f in fragment_plan(plan).all_fragments()
+            if S.streamable_chain(f.root) is not None
+        )
+        agg, scan, builds = S.streamable_chain(frag.root)
+        caps = programs.setdefault(("caps", "stream", frag.id), _Caps())
+        return S.StreamingAggregator(ex, frag, agg, scan, caps), ex
+
+    def rows(result):
+        return sorted(result.batch.compact().to_pylist())
+
+    first, ex1 = aggregator(10)
+    second, ex2 = aggregator(30)
+    assert first.agg is not second.agg and first.site == second.site
+    got1, got2 = rows(first.run()), rows(second.run())
+    assert ex1.compile_stats["trace_count"] == 1
+    assert ex2.compile_stats["trace_count"] == 0
+    assert ex2.compile_stats["program_cache_hits"] == 1
+    assert len([k for k in programs if k[0] == "slab"]) == 1
+    assert not [k for k in programs if "id(" in repr(k)]
+    local = LocalQueryRunner(engine=r.engine)
+    for literal, got in ((10, got1), (30, got2)):
+        want, _ = local.execute(
+            "select l_linestatus, sum(l_quantity), count(*) from lineitem"
+            f" where l_quantity < {literal} group by l_linestatus")
+        # partial accumulators: key, sum, count of the sum, count(*)
+        assert [(g[0], g[1], g[-1]) for g in got] == sorted(want)

@@ -266,11 +266,15 @@ def slab_bytes_estimate(types: Sequence, rows: int, cap: int) -> int:
     return nbytes
 
 
-def stage_device_slab(host_batches: Sequence[Batch], cap: int):
+def stage_device_slab(
+    host_batches: Sequence[Batch], cap: int, stats: Optional[dict] = None
+):
     """Stage host batches into device HBM as ONE slab padded to a
     multiple of ``cap`` rows (so a compiled streaming step can
     ``dynamic_slice`` any chunk without clamping). Per-part dictionaries
-    are unified during the concat. Returns (slab_batch, num_rows).
+    are unified during the concat. Returns (slab_batch, num_rows). The
+    bytes put on the device count into ``stats["h2d_bytes"]`` (the
+    query's ``ingestStats``), as a scan's do.
 
     Shared by connectors whose data can live device-resident (memory
     pages, generated tpch splits): HBM plays the role the reference's
@@ -286,6 +290,7 @@ def stage_device_slab(host_batches: Sequence[Batch], cap: int):
     padded_rows = ((total_rows + quantum - 1) // quantum) * quantum
     pad = padded_rows - total_rows
     cols = []
+    nbytes = 0
     for c in host.columns:
         data, valid = np.asarray(c.data), c.valid
         if pad:
@@ -298,7 +303,13 @@ def stage_device_slab(host_batches: Sequence[Batch], cap: int):
                 )
         dev = jax.device_put(data)
         dvalid = None if valid is None else jax.device_put(valid)
+        nbytes += data.nbytes + (0 if valid is None else valid.nbytes)
         cols.append(Column(c.type, dev, dvalid, c.dictionary))
+    from trino_tpu.obs.metrics import get_registry
+
+    get_registry().counter("trino_tpu_ingest_h2d_bytes_total").inc(nbytes)
+    if stats is not None:
+        stats["h2d_bytes"] = stats.get("h2d_bytes", 0) + nbytes
     return Batch(cols, padded_rows), total_rows
 
 

@@ -8,7 +8,7 @@ through the step program with zero host->device traffic."""
 
 from __future__ import annotations
 
-from typing import Sequence
+from typing import Optional, Sequence
 
 from trino_tpu.columnar import Batch, Column, concat_batches
 from trino_tpu.connectors.api import Connector, Split, TableSchema
@@ -80,7 +80,7 @@ class MemoryConnector(Connector):
         self._device.clear()
 
     def device_slab(self, schema, table, columns: Sequence[str], cap: int,
-                    max_bytes: int):
+                    max_bytes: int, stats: Optional[dict] = None):
         """Stage the table's requested columns into device HBM as ONE slab
         padded to a multiple of ``cap`` rows (so a compiled step can
         ``dynamic_slice`` any chunk without clamping). Returns
@@ -125,6 +125,7 @@ class MemoryConnector(Connector):
                 for b in parts
             ],
             cap,
+            stats,
         )
         self._device[key] = staged
         return staged

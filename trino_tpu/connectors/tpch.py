@@ -14,6 +14,8 @@ Schemas: tiny (SF 0.01), sf1, sf10, sf100 (and sf<k> parsed generically).
 
 from __future__ import annotations
 
+from typing import Optional
+
 import numpy as np
 
 from trino_tpu import types as T
@@ -293,7 +295,10 @@ class TpchConnector(Connector):
         return {key: (lo + 1, hi, False)}
 
     # --- data generation -------------------------------------------------
-    def device_slab(self, schema, table, columns, cap: int, max_bytes: int):
+    def device_slab(
+        self, schema, table, columns, cap: int, max_bytes: int,
+        stats: Optional[dict] = None,
+    ):
         """Stage a generated table's columns into device HBM once (the
         reference's tpch connector generates into worker pages; HBM is
         our page store). Bounded by ``max_bytes``; falls back to host
@@ -329,7 +334,7 @@ class TpchConnector(Connector):
             cols = gen(sf, i, n_splits, columns=set(columns))
             out = [cols[c] for c in columns]
             parts.append(Batch(out, out[0].data.shape[0] if out else 0))
-        staged = stage_device_slab(parts, cap)
+        staged = stage_device_slab(parts, cap, stats)
         self._device_slabs[key] = staged
         return staged
 

@@ -34,6 +34,7 @@ Execution model:
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from typing import Any, Optional, Sequence
 
@@ -501,6 +502,19 @@ def program_label(program_key) -> str:
     return repr(program_key)
 
 
+def agg_site(frag: PlanFragment, agg: P.Aggregate) -> str:
+    """An aggregate by content, ``agg@<fragment id>#<ordinal>`` (its place
+    among the fragment's aggregates in walk order): the same for equal
+    plans at any address, which ``id(node)`` is not."""
+    k = 0
+    for node in P.walk_plan(frag.root):
+        if node is agg:
+            break
+        if isinstance(node, P.Aggregate):
+            k += 1
+    return f"agg@{frag.id}#{k}"
+
+
 class FragmentedExecutor(DistributedExecutor):
     """Distributed executor that compiles each fragment into one program.
 
@@ -559,7 +573,9 @@ class FragmentedExecutor(DistributedExecutor):
 
         self.fault_injector = FaultInjector.from_session(self.session)
 
-    def execute(self, node: P.PlanNode) -> tuple[Batch, list[str]]:
+    def _execute_plan(self, node: P.PlanNode) -> tuple[Batch, list[str]]:
+        # inside LocalExecutor.execute's ``execute_plan`` span, the fall-
+        # backs to the interpreter included.
         # reuse the fragmented plan across executions of a cached plan:
         # program-cache keys and traced closures reference fragment node
         # identities, so the fragmentation must be stable too
@@ -568,16 +584,41 @@ class FragmentedExecutor(DistributedExecutor):
             with get_tracer().span("fragment"):
                 sub = fragment_plan(node)
             self.programs["__subplan__"] = sub
-        if not query_fusable(sub):
-            return super().execute(node)
-        try:
-            return self._execute_fragments(sub)
-        except FusedUnsupported:
-            return super().execute(node)
-        except jax.errors.TracerArrayConversionError:
-            # an operator needed host values mid-trace (e.g. datetime
-            # formatting over unique values) — interpret instead
-            return super().execute(node)
+        if query_fusable(sub):
+            try:
+                return self._execute_fragments(sub)
+            except (FusedUnsupported, jax.errors.TracerArrayConversionError):
+                # not traceable, or an operator needed host values
+                # mid-trace (e.g. datetime formatting over unique
+                # values) — interpret instead
+                pass
+        span = get_tracer().current()
+        if span is not None:
+            span.set("fallback", "interpreter")
+        return super()._execute_plan(node)
+
+    def count_program(
+        self, hit: bool, compile_ms: float = 0.0, stored: bool = True
+    ) -> None:
+        """Count one program of ``exec/streaming.py`` into this query's
+        ``compile_stats`` and the store's ``__stats__``, as
+        ``_retry_traced`` counts its own: a hit, or a trace (a miss too
+        where the program went into the store)."""
+        store = self.programs.setdefault(
+            "__stats__",
+            {"hits": 0, "misses": 0, "trace_count": 0, "compile_ms": 0.0},
+        )
+        if hit:
+            self.compile_stats["program_cache_hits"] += 1
+            store["hits"] += 1
+            return
+        if stored:
+            self.compile_stats["program_cache_misses"] += 1
+            store["misses"] += 1
+        self.compile_stats["trace_count"] += 1
+        self.compile_stats["compile_ms"] += compile_ms
+        store["trace_count"] += 1
+        store["compile_ms"] = round(store["compile_ms"] + compile_ms, 3)
 
     def _param_arrays(self) -> Optional[tuple]:
         """Hoisted literals as typed device scalars — the ``__params__``
@@ -674,7 +715,9 @@ class FragmentedExecutor(DistributedExecutor):
             self.programs["__skewroles__"] = roles
         return roles
 
-    def _history_sites(self, frag: PlanFragment) -> dict[str, str]:
+    def _history_sites(
+        self, frag: PlanFragment, streamed: Optional[P.Aggregate] = None
+    ) -> dict[str, str]:
         """Runtime capacity-site names → restart-stable names. The tracer
         mints sites as ``agg{id(node)}`` / ``join{id(node)}`` /
         ``semi{id(node)}`` — node ids churn across processes AND across
@@ -706,9 +749,19 @@ class FragmentedExecutor(DistributedExecutor):
             elif isinstance(node, P.Filter):
                 sites[f"opfilter{id(node)}"] = f"filter@{frag.id}#{filter_k}"
                 filter_k += 1
+        if streamed is not None:
+            # exec/streaming.py names its aggregate's budget by content
+            # at run time too
+            stable = sites.pop(f"agg{id(streamed)}")
+            sites[stable] = stable
         return sites
 
-    def _seed_history(self, frag: PlanFragment, caps: "_Caps") -> None:
+    def _seed_history(
+        self,
+        frag: PlanFragment,
+        caps: "_Caps",
+        streamed: Optional[P.Aggregate] = None,
+    ) -> None:
         """History-seeded capacities: final observed shapes from earlier
         runs of this fingerprint floor the static estimates. Runs BEFORE
         ``_seed_caps`` — floors are first-wins, so observed truth beats a
@@ -719,7 +772,7 @@ class FragmentedExecutor(DistributedExecutor):
         Always registers the runtime→stable site map so the snapshot can
         persist capacities under restart-stable keys."""
         try:
-            sites = self._history_sites(frag)
+            sites = self._history_sites(frag, streamed)
             caps.sites.update(sites)
             hcaps = (self.history or {}).get("capacities") or {}
             if not hcaps:
@@ -985,13 +1038,13 @@ class FragmentedExecutor(DistributedExecutor):
             extras = [
                 jnp.ravel(f.astype(jnp.int32)) for _, _, f, _ in deferred
             ] + [jnp.ravel(c) for _, c, _ in dcounters if c is not None]
-            t_pull = _time.perf_counter()
-            host_root, extra_vals = root.batch.to_host(extras=extras)
-            pull_ms = (_time.perf_counter() - t_pull) * 1000.0
-            get_tracer().record(
-                "device_pull", pull_ms,
+            with get_tracer().span(
+                "device_pull",
                 attrs={"extras": len(extras), "attempt": attempts},
-            )
+            ):
+                t_pull = _time.perf_counter()
+                host_root, extra_vals = root.batch.to_host(extras=extras)
+                pull_ms = (_time.perf_counter() - t_pull) * 1000.0
             get_registry().histogram("trino_tpu_device_pull_ms").observe(
                 pull_ms
             )
@@ -1442,7 +1495,7 @@ class FragmentedExecutor(DistributedExecutor):
                     build_inputs[f"remote{n.fragment_id}"] = upstream.batch
                     build_layouts[f"remote{n.fragment_id}"] = upstream.layout
         caps = self.programs.setdefault(("caps", "stream", frag.id), _Caps())
-        self._seed_history(frag, caps)
+        self._seed_history(frag, caps, streamed=agg)
         attempts = 0
         while True:
             attempts += 1
@@ -1565,54 +1618,68 @@ class FragmentedExecutor(DistributedExecutor):
                     store_stats["misses"] += 1
             t0 = _time.perf_counter()
             outs = None
-            if self._device_profiling:
-                # AOT-compile the SAME jitted function and execute through
-                # the resulting executable: identical program (bit-identical
-                # results, no double compile), but the Compiled object
-                # additionally exposes XLA's cost/memory analysis
-                if traced_now:
-                    try:
-                        compiled = jf.lower(*args).compile()
-                        meta.aot = compiled
-                        from trino_tpu.obs.profiler import (
-                            capture_device_stats,
-                        )
+            # a program's first call traces, lowers and compiles (or loads
+            # from the disk cache) before it dispatches: the span holds it
+            compile_span = (
+                get_tracer().span(
+                    "program_compile",
+                    attrs={
+                        "key": repr(program_key) if program_key else None,
+                        "attempt": attempts,
+                    },
+                )
+                if traced_now
+                else contextlib.nullcontext()
+            )
+            with compile_span:
+                if self._device_profiling:
+                    # AOT-compile the SAME jitted function and execute through
+                    # the resulting executable: identical program (bit-identical
+                    # results, no double compile), but the Compiled object
+                    # additionally exposes XLA's cost/memory analysis
+                    if traced_now:
+                        try:
+                            compiled = jf.lower(*args).compile()
+                            meta.aot = compiled
+                            from trino_tpu.obs.profiler import (
+                                capture_device_stats,
+                            )
 
-                        meta.device_stats = capture_device_stats(compiled)
-                    except Exception:  # noqa: BLE001 — degrade to plain jit
-                        meta.aot = None
-                if meta.aot is not None:
+                            meta.device_stats = capture_device_stats(compiled)
+                        except Exception:  # noqa: BLE001 — degrade to plain jit
+                            meta.aot = None
+                    if meta.aot is not None:
+                        try:
+                            outs = meta.aot(*args)
+                        except Exception:  # noqa: BLE001 — e.g. new input
+                            # shapes on a warm hit: jf(*args) below retraces
+                            # transparently, exactly as the unprofiled path does
+                            meta.aot = None
+                            outs = None
+                if outs is None:
                     try:
-                        outs = meta.aot(*args)
-                    except Exception:  # noqa: BLE001 — e.g. new input
-                        # shapes on a warm hit: jf(*args) below retraces
-                        # transparently, exactly as the unprofiled path does
-                        meta.aot = None
-                        outs = None
-            if outs is None:
-                try:
-                    outs = jf(*args)
-                except Exception as e:  # noqa: BLE001 — inspect and rethrow
-                    if not _is_resource_exhausted(e) or not caps.shrink_all():
-                        raise
-                    # the program failed to COMPILE (scoped-vmem / HBM
-                    # exhaustion) before any overflow flag could run:
-                    # enter the same retry ladder as row overflow,
-                    # inverted — halve every capacity and retrace smaller
-                    self.exchange_stats["compile_halvings"] = (
-                        self.exchange_stats.get("compile_halvings", 0) + 1
-                    )
-                    get_registry().counter(
-                        "trino_tpu_compile_halvings_total"
-                    ).inc()
-                    get_tracer().record(
-                        "compile_halving", 0.0,
-                        attrs={
-                            "key": repr(program_key) if program_key else None,
-                            "attempt": attempts,
-                        },
-                    )
-                    continue
+                        outs = jf(*args)
+                    except Exception as e:  # noqa: BLE001 — inspect and rethrow
+                        if not _is_resource_exhausted(e) or not caps.shrink_all():
+                            raise
+                        # the program failed to COMPILE (scoped-vmem / HBM
+                        # exhaustion) before any overflow flag could run:
+                        # enter the same retry ladder as row overflow,
+                        # inverted — halve every capacity and retrace smaller
+                        self.exchange_stats["compile_halvings"] = (
+                            self.exchange_stats.get("compile_halvings", 0) + 1
+                        )
+                        get_registry().counter(
+                            "trino_tpu_compile_halvings_total"
+                        ).inc()
+                        get_tracer().record(
+                            "compile_halving", 0.0,
+                            attrs={
+                                "key": repr(program_key) if program_key else None,
+                                "attempt": attempts,
+                            },
+                        )
+                        continue
             data, sel, flags, counters, aux = outs
             compile_ms = 0.0
             if traced_now:
@@ -1627,13 +1694,6 @@ class FragmentedExecutor(DistributedExecutor):
                     store_stats["compile_ms"] = round(
                         store_stats["compile_ms"] + compile_ms, 3
                     )
-                get_tracer().record(
-                    "program_compile", compile_ms,
-                    attrs={
-                        "key": repr(program_key) if program_key else None,
-                        "attempt": attempts,
-                    },
-                )
                 get_registry().histogram(
                     "trino_tpu_program_compile_ms"
                 ).observe(compile_ms)
@@ -2036,19 +2096,19 @@ class FragmentedExecutor(DistributedExecutor):
             extras = [
                 jnp.ravel(f.astype(jnp.int32)) for _, _, f, _ in deferred
             ] + [jnp.ravel(c) for _, c, _ in dcounters if c is not None]
-            t_pull = _time.perf_counter()
-            host_batches, extra_vals = self._demux_batch_to_host(
-                roots, extras
-            )
-            pull_ms = (_time.perf_counter() - t_pull) * 1000.0
-            get_tracer().record(
-                "device_pull", pull_ms,
+            with get_tracer().span(
+                "device_pull",
                 attrs={
                     "extras": len(extras),
                     "attempt": attempts,
                     "batch": K,
                 },
-            )
+            ):
+                t_pull = _time.perf_counter()
+                host_batches, extra_vals = self._demux_batch_to_host(
+                    roots, extras
+                )
+                pull_ms = (_time.perf_counter() - t_pull) * 1000.0
             get_registry().histogram("trino_tpu_device_pull_ms").observe(
                 pull_ms
             )

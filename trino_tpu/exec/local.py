@@ -143,27 +143,33 @@ class LocalExecutor:
 
     # === entry ==========================================================
     def execute(self, node: P.PlanNode) -> tuple[Batch, list[str]]:
+        # ONE span a query, whichever executor: a subclass overrides
+        # _execute_plan, never this, so its fall-back to the interpreter
+        # stays inside the same span (``executor`` says whose it is)
         with get_tracer().span(
             "execute_plan", attrs={"executor": type(self).__name__}
         ):
-            stack = [node]
-            while stack:  # pre-order, the order EXPLAIN prints
-                n = stack.pop()
-                self._node_number(n)
-                stack.extend(reversed(n.sources))
-            if isinstance(node, P.Output):
-                # the root is a plan node too: its span holds the compaction
-                with get_tracer().span(
-                    "op:Output", attrs={"node": self._node_number(node)}
-                ):
-                    res = self._exec(node.source)
-                    cols = [res.column(s) for s in node.symbols]
-                    out = Batch(
-                        cols, res.batch.num_rows, res.batch.sel
-                    ).compact()
-                return out, node.column_names
-            res = self._exec(node)
-            return res.batch.compact(), [s.name for s in node.output_symbols]
+            return self._execute_plan(node)
+
+    def _execute_plan(self, node: P.PlanNode) -> tuple[Batch, list[str]]:
+        stack = [node]
+        while stack:  # pre-order, the order EXPLAIN prints
+            n = stack.pop()
+            self._node_number(n)
+            stack.extend(reversed(n.sources))
+        if isinstance(node, P.Output):
+            # the root is a plan node too: its span holds the compaction
+            with get_tracer().span(
+                "op:Output", attrs={"node": self._node_number(node)}
+            ):
+                res = self._exec(node.source)
+                cols = [res.column(s) for s in node.symbols]
+                out = Batch(
+                    cols, res.batch.num_rows, res.batch.sel
+                ).compact()
+            return out, node.column_names
+        res = self._exec(node)
+        return res.batch.compact(), [s.name for s in node.output_symbols]
 
     @staticmethod
     def _nonempty(res: Result) -> Result:

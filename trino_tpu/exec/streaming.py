@@ -20,10 +20,19 @@ overflow the caller grows capacities and restarts the stream.
 Wide-DECIMAL sums stream too: chunk partials produce per-group limb sums
 (ops/decimal128), and limb lanes are independent int64 accumulators, so
 the cross-chunk merge just sums each lane (carry resolution happens once,
-at finalize)."""
+at finalize).
+
+One rule for literals: every program built here takes the plan's hoisted
+literals (``planner/canonicalize.py``) as its ``params`` ARGUMENT, the
+``__params__`` of a fragment program. The engine executes one cached plan
+object for every query of a fingerprint, so a program that closed over a
+literal's value would answer each later variant with the first one's rows.
+What goes into the executor's program store is keyed by content (the
+aggregate's fragment id and ordinal), never by ``id(node)``."""
 
 from __future__ import annotations
 
+import time
 from functools import partial
 from typing import Optional
 
@@ -35,6 +44,7 @@ from jax.sharding import NamedSharding, PartitionSpec as PS
 from trino_tpu import types as T
 from trino_tpu.columnar import Batch, Column, bucket_capacity
 from trino_tpu.exec.local import Result
+from trino_tpu.obs.trace import get_tracer
 from trino_tpu.ops.aggregation import AggSpec, global_aggregate, group_aggregate
 from trino_tpu.parallel.mesh import AXIS, shard_batch, smap
 from trino_tpu.planner import plan as P
@@ -133,10 +143,19 @@ class StreamingAggregator:
         self.build_layouts = build_layouts or {}
         self._prememo: Optional[dict] = None
         self.nkeys = len(agg_node.group_keys)
+        from trino_tpu.exec.fragments import agg_site
+
+        # the aggregate by content (``agg@<fragment>#<ordinal>``): names the
+        # group budget, its overflow flag and every stored program, so an
+        # equal plan at another address finds them and a stranger does not
+        self.site = agg_site(frag, agg_node)
         self.G = caps.get(
-            f"agg{id(agg_node)}",
-            int(executor.session.get("stream_group_budget")),
+            self.site, int(executor.session.get("stream_group_budget"))
         )
+        # this query's hoisted literals: an ARGUMENT of every program built
+        # here (``__params__``), never a constant of one. None when nothing
+        # was hoisted (program_cache / constant_hoisting off)
+        self.params = executor._param_arrays()
         # running per-column dictionaries for the chunk stream; ids of
         # dictionaries whose growth would invalidate the traced step
         self._running_dicts: Optional[list] = None
@@ -152,7 +171,10 @@ class StreamingAggregator:
         from trino_tpu.exec.fragments import _FragmentTracer
 
         tracer = _FragmentTracer(
-            self.executor, self.build_inputs, self.build_layouts, self.caps
+            self.executor,
+            self._with_params(self.build_inputs, self.params),
+            self.build_layouts,
+            self.caps,
         )
         self._prememo = {}
         for root in self.build_roots:
@@ -293,13 +315,13 @@ class StreamingAggregator:
 
         prev_log = Dictionary.begin_trace_log()
         try:
-            state = step(state, chunk, counts)
+            state = step(state, chunk, counts, self.params)
         finally:
             log = Dictionary.end_trace_log(prev_log)
         self._sensitive_dicts |= set(log.get("growth_sensitive", ()))
         for parts, cap in it:
             chunk, counts = _pad_batch(self.mesh, parts, cap)
-            state = step(state, chunk, counts)
+            state = step(state, chunk, counts, self.params)
         self._check_overflow(state, None, meta)
         return self._finish(state, meta)
 
@@ -340,9 +362,13 @@ class StreamingAggregator:
         stage = getattr(connector, "device_slab", None)
         if stage is not None:
             limit = int(self.executor.session.get("stream_device_cache_bytes"))
+            # what this query stages counts as its ingest: the whole
+            # table on its first stream, 0 while the slab stays resident
+            stats = self.executor.ingest_stats
+            stats.setdefault("h2d_bytes", 0)
             staged = stage(
                 self.scan.schema, self.scan.table, self.scan.column_names,
-                cap, limit,
+                cap, limit, stats,
             )
             if staged is not None:
                 slab, num_rows = staged
@@ -372,71 +398,105 @@ class StreamingAggregator:
         # two) and REMEMBER the working cap so warm queries never repeat
         # the failing compile
         if programs is not None:
-            cap = min(cap, programs.get(("slabcap", id(self.agg)), cap))
+            cap = min(cap, programs.get(("slabcap", self.site), cap))
+        attempt = 0
         while True:
-            n_steps = (num_rows + cap - 1) // cap
-            prog_key = ("slab", id(self.agg), self.G, cap, slab is None)
-            hit = programs.get(prog_key) if programs is not None else None
-            if hit is not None:
-                program, meta = hit
-                state = self._init_state(meta)
-                state = program(
-                    state, slab, np.int32(n_steps), np.int64(num_rows)
+            attempt += 1
+            with get_tracer().span(
+                "stream.slab",
+                attrs={
+                    "steps": (num_rows + cap - 1) // cap,
+                    "cap": cap,
+                    "groups": self.G,
+                    "params": len(self.params or ()),
+                    "attempt": attempt,
+                },
+            ) as span:
+                res = self._slab_attempt(
+                    programs, slab, chunk_cols, num_rows, cap, span
                 )
-                self._check_overflow(state, prog_key, meta)
-                return self._finish(state, meta)
-            if slab is not None:
-                probe_cols = [
-                    Column(
-                        c.type,
-                        jax.ShapeDtypeStruct((cap,) + c.data.shape[1:], c.data.dtype),
-                        None
-                        if c.valid is None
-                        else jax.ShapeDtypeStruct((cap,), jnp.bool_),
-                        c.dictionary,
-                    )
-                    for c in slab.columns
-                ]
-            else:
-                probe_cols = [
-                    Column(
-                        c.type,
-                        jax.ShapeDtypeStruct((cap,) + c.data.shape[1:], c.data.dtype),
-                        None,
-                        c.dictionary,
-                    )
-                    for c in jax.eval_shape(
-                        lambda: chunk_cols(jnp.zeros((), jnp.int32), cap)
-                    )
-                ]
-            probe_chunk = Batch(
-                probe_cols, cap, jax.ShapeDtypeStruct((cap,), jnp.bool_)
-            )
-            meta = self._collect_meta(probe_chunk)
+            if res is not None:
+                return res
+            cap //= 2
+
+    def _slab_attempt(
+        self, programs, slab, chunk_cols, num_rows: int, cap: int, span
+    ) -> Optional[Result]:
+        """One run of the slab program at chunk size ``cap``: the stored
+        program if there is one, else trace, compile and store it. None
+        when the compiler refused the size and a halved ``cap`` may fit."""
+        n_steps = (num_rows + cap - 1) // cap
+        prog_key = ("slab", self.site, self.G, cap, slab is None)
+        hit = programs.get(prog_key) if programs is not None else None
+        span.set("cacheHit", hit is not None)
+        if hit is not None:
+            program, meta = hit
             state = self._init_state(meta)
-            program = jax.jit(
-                self._make_slab_program(meta, cap, chunk_cols),
-                donate_argnums=(0,),
+            state = program(
+                state, slab, np.int32(n_steps), np.int64(num_rows), self.params
             )
-            try:
-                state = program(
-                    state, slab, np.int32(n_steps), np.int64(num_rows)
-                )
-            except jax.errors.JaxRuntimeError as e:
-                msg = str(e).lower()
-                compile_failure = any(
-                    tok in msg
-                    for tok in ("compile", "vmem", "resource_exhausted")
-                )
-                if not compile_failure or cap <= 1 << 18:
-                    raise
-                cap //= 2
-                continue
-            if programs is not None:
-                programs[prog_key] = (program, meta)
-                programs[("slabcap", id(self.agg))] = cap
+            self.executor.count_program(hit=True)
             self._check_overflow(state, prog_key, meta)
             return self._finish(state, meta)
+        if slab is not None:
+            probe_cols = [
+                Column(
+                    c.type,
+                    jax.ShapeDtypeStruct((cap,) + c.data.shape[1:], c.data.dtype),
+                    None
+                    if c.valid is None
+                    else jax.ShapeDtypeStruct((cap,), jnp.bool_),
+                    c.dictionary,
+                )
+                for c in slab.columns
+            ]
+        else:
+            probe_cols = [
+                Column(
+                    c.type,
+                    jax.ShapeDtypeStruct((cap,) + c.data.shape[1:], c.data.dtype),
+                    None,
+                    c.dictionary,
+                )
+                for c in jax.eval_shape(
+                    lambda: chunk_cols(jnp.zeros((), jnp.int32), cap)
+                )
+            ]
+        probe_chunk = Batch(
+            probe_cols, cap, jax.ShapeDtypeStruct((cap,), jnp.bool_)
+        )
+        meta = self._collect_meta(probe_chunk)
+        state = self._init_state(meta)
+        program = jax.jit(
+            self._make_slab_program(meta, cap, chunk_cols),
+            donate_argnums=(0,),
+        )
+        t0 = time.perf_counter()
+        try:
+            state = program(
+                state, slab, np.int32(n_steps), np.int64(num_rows), self.params
+            )
+        except jax.errors.JaxRuntimeError as e:
+            msg = str(e).lower()
+            compile_failure = any(
+                tok in msg
+                for tok in ("compile", "vmem", "resource_exhausted")
+            )
+            if not compile_failure or cap <= 1 << 18:
+                raise
+            return None
+        # trace + lower + compile are synchronous in the first call and
+        # the loop itself is dispatched, so this wall is theirs
+        self.executor.count_program(
+            hit=False,
+            compile_ms=(time.perf_counter() - t0) * 1000.0,
+            stored=programs is not None,
+        )
+        if programs is not None:
+            programs[prog_key] = (program, meta)
+            programs[("slabcap", self.site)] = cap
+        self._check_overflow(state, prog_key, meta)
+        return self._finish(state, meta)
 
     def _try_dense(self, slab: Batch, num_rows: int) -> Optional[Result]:
         """Dense-domain fast path: when the group keys span a small
@@ -466,7 +526,7 @@ class StreamingAggregator:
         live0 = jnp.arange(cap, dtype=jnp.int32) < num_rows
         batch = Batch(slab.columns, cap, live0)
         try:
-            tracer = self._tracer_for(batch)
+            tracer = self._tracer_for(batch, self.params)
             agg_inputs, specs, string_dicts, keys, key_dicts, sel = (
                 self._chunk_prep(tracer)
             )
@@ -490,10 +550,23 @@ class StreamingAggregator:
                 return None
             if not np.issubdtype(np.dtype(kd.dtype), np.integer):
                 return None
-        # key domain from data min/max (ONE device round-trip, cached on
-        # the executor's program cache per resident slab)
+        # key domain from data min/max: ONE device round-trip, kept in the
+        # executor's program store by content (the aggregate, the table's
+        # columns and data version) and by the literals' host values, since
+        # the selection the stats are taken under depends on them: a pull
+        # per variant, never a compile (the programs below take
+        # mins/strides as arguments)
         programs = getattr(self.executor, "programs", None)
-        stats_key = ("dense_stats", id(slab), num_rows, id(self.agg))
+        scan = self.scan
+        connector = self.executor.catalogs.get(scan.catalog)
+        stats_key = (
+            "dense_stats", self.site,
+            (scan.catalog, scan.schema, scan.table),
+            tuple(scan.column_names),
+            connector.data_version(scan.schema, scan.table),
+            num_rows,
+            getattr(self.executor, "_params", None),
+        )
         stats = programs.get(stats_key) if programs is not None else None
         distinct_vals: list = []
         for pair in agg_inputs:
@@ -649,7 +722,7 @@ class StreamingAggregator:
         count."""
         inner = self._make_step(meta)
 
-        def body_for(slab, num_rows):
+        def body_for(slab, num_rows, params):
             def body(i, state):
                 # int64 offset: i*cap wraps int32 past 2^31 rows (the
                 # generator path has no table-size bound)
@@ -668,25 +741,35 @@ class StreamingAggregator:
                 else:
                     cols = chunk_cols(off, cap)
                 live = jnp.arange(cap, dtype=jnp.int32) < cnt
-                return inner(state, Batch(cols, cap, live), None)
+                return inner(state, Batch(cols, cap, live), None, params)
 
             return body
 
-        def program(state, slab, n_steps, num_rows):
+        def program(state, slab, n_steps, num_rows, params):
             return jax.lax.fori_loop(
-                0, n_steps, body_for(slab, num_rows), state
+                0, n_steps, body_for(slab, num_rows, params), state
             )
 
         return program
 
     # === metadata (eager pass over the first chunk) ======================
 
-    def _tracer_for(self, chunk: Batch):
+    @staticmethod
+    def _with_params(inputs: dict, params) -> dict:
+        """``inputs`` with the hoisted literals under ``__params__``, where
+        ``_FragmentTracer`` (and with it every ``ExprCompiler``) reads them."""
+        return inputs if params is None else {**inputs, "__params__": params}
+
+    def _tracer_for(self, chunk: Batch, params):
+        """The chunk's tracer. ``params`` is the caller's OWN argument: the
+        traced tuple inside a compiled step, this query's device scalars in
+        an eager pass; never ``self.params`` from inside a stored program,
+        which would bake the first query's literals in."""
         from trino_tpu.exec.fragments import _FragmentTracer
 
         tracer = _FragmentTracer(
             self.executor,
-            {f"scan{id(self.scan)}": chunk},
+            self._with_params({f"scan{id(self.scan)}": chunk}, params),
             {
                 f"scan{id(self.scan)}": {
                     s.name: i for i, s in enumerate(self.scan.symbols)
@@ -719,8 +802,8 @@ class StreamingAggregator:
 
         box = {}
 
-        def probe(ch):
-            tracer = self._tracer_for(ch)
+        def probe(ch, params):
+            tracer = self._tracer_for(ch, params)
             agg_inputs, specs, string_dicts, keys, key_dicts, sel = (
                 self._chunk_prep(tracer)
             )
@@ -736,7 +819,7 @@ class StreamingAggregator:
 
         prev_log = Dictionary.begin_trace_log()
         try:
-            jax.eval_shape(probe, chunk)
+            jax.eval_shape(probe, chunk, self.params)
         finally:
             log = Dictionary.end_trace_log(prev_log)
         self._sensitive_dicts = set(log.get("growth_sensitive", ()))
@@ -764,7 +847,7 @@ class StreamingAggregator:
             "string_dicts": string_dicts,
             "key_dicts": key_dicts,
             "key_dtypes": box["key_dtypes"],
-            "ovf_names": [f"agg{id(self.agg)}"] + box["ovf_names"],
+            "ovf_names": [self.site] + box["ovf_names"],
         }
 
     def _init_state(self, meta: dict) -> dict:
@@ -802,7 +885,7 @@ class StreamingAggregator:
         nspec = len(specs)
         sagg = self
 
-        def step(state, chunk: Batch, counts):
+        def step(state, chunk: Batch, counts, params):
             if counts is not None:
                 # per-shard valid-row counts (dynamic) instead of a host
                 # mask: tail chunks keep the same pytree structure, so the
@@ -811,7 +894,7 @@ class StreamingAggregator:
                 pos = jnp.arange(chunk.capacity, dtype=jnp.int32)
                 live = pos % cap < counts[pos // cap]
                 chunk = Batch(chunk.columns, chunk.num_rows, live)
-            tracer = sagg._tracer_for(chunk)
+            tracer = sagg._tracer_for(chunk, params)
             agg_inputs, _specs, _sd, keys, _kd, sel = sagg._chunk_prep(tracer)
             prev_ovf = state["overflow"]
             if nkeys == 0:

@@ -443,11 +443,13 @@ def self_times(
     return out
 
 
-#: span name -> the ``phaseMs`` key that sums its durations
+#: span name -> the ``phaseMs`` key that sums its durations. ``slab`` (the
+#: compiled tier's streamed slab program, lookup to result) lies inside
+#: ``execute``; the others follow one another
 PHASE_OF_SPAN = {
     "parse": "parse", "plan": "plan", "optimize": "optimize",
     "canonicalize": "canonicalize", "execute_plan": "execute",
-    "result.pull": "resultPull",
+    "result.pull": "resultPull", "stream.slab": "slab",
 }
 OPERATOR_PREFIX = "op:"
 
