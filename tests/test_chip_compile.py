@@ -181,6 +181,42 @@ def test_sort_join_probe_has_no_loop(one_chip):
     assert "while" not in compiled.as_text()
 
 
+@pytest.mark.parametrize("masks", [False, True], ids=["slab-step", "whole-batch"])
+def test_domain_group_by_keeps_no_slot_by_row_array(one_chip, masks):
+    """``group_aggregate``'s domain path at Q1's lanes: a slab step's
+    2,097,152 rows and 12 slots, and the default session's whole batch of
+    2^23 rows with validity masks on both keys, 20 slots. Compare, select
+    and reduce have to fuse: a (slots, rows) int64 array is 200 MB to 1.3 GB
+    a lane. And nothing is sorted."""
+    from trino_tpu.ops.aggregation import AggSpec, domain_slots, group_aggregate
+
+    n = LINEITEM_SLAB if masks else 1 << 21
+    specs = [AggSpec(k) for k in ("sum128", "sum128", "sum128w", "sum128w",
+                                  "sum128", "sum128", "sum128", "count_star")]
+
+    def q1(flag, status, fvalid, svalid, sel, qty, price, disc_price, charge, disc):
+        keys = [(flag, fvalid if masks else None),
+                (status, svalid if masks else None)]
+        lanes = [(qty, None), (price, None), (disc_price, None), (charge, None),
+                 (qty, None), (price, None), (disc, None), None]
+        assert domain_slots(keys, lanes, specs, 4096, [3, 2]) == (20 if masks else 12)
+        return group_aggregate(keys, sel, lanes, specs, 4096, key_domains=[3, 2])
+
+    compiled = _compile(
+        q1,
+        _shape(one_chip, (n,), jnp.int32), _shape(one_chip, (n,), jnp.int32),
+        _shape(one_chip, (n,), jnp.bool_), _shape(one_chip, (n,), jnp.bool_),
+        _shape(one_chip, (n,), jnp.bool_),
+        _shape(one_chip, (n,), jnp.int64), _shape(one_chip, (n,), jnp.int64),
+        _shape(one_chip, (n, 2), jnp.int64), _shape(one_chip, (n, 2), jnp.int64),
+        _shape(one_chip, (n,), jnp.int64),
+    )
+    # the limb lanes may be kept (4 B a row each); a slot-by-row array may not
+    assert compiled.memory_analysis().temp_size_in_bytes < 12 * n * 4
+    text = compiled.as_text()
+    assert " sort(" not in text and " while(" not in text
+
+
 # sel bit + key bits + 23 row-index bits, packed into 63-bit int64 lanes.
 # The compile time is the comparator's, not the row count's: one lane takes
 # ~20 s here and three take ~150 s, so the wide case is slow-marked.

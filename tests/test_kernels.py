@@ -9,7 +9,13 @@ from trino_tpu import types as T
 from trino_tpu.columnar import Batch, Column, Dictionary
 from trino_tpu.compiler import ExprCompiler, days_from_civil
 from trino_tpu.ir import call, const, input_ref, special
-from trino_tpu.ops.aggregation import AggSpec, global_aggregate, group_aggregate
+from trino_tpu.ops.aggregation import (
+    DOMAIN_MAX_SLOTS,
+    AggSpec,
+    domain_slots,
+    global_aggregate,
+    group_aggregate,
+)
 from trino_tpu.ops.join import (
     build_side,
     hash_keys,
@@ -235,6 +241,144 @@ class TestGroupAggregate:
         s, cnt = res[0]
         assert float(s) == 3.0 and int(cnt) == 2
         assert int(res[1]) == 3
+
+
+# --- the domain path against the sort path ----------------------------------
+# name -> (key kinds, masks, n, aggregates, max_groups, which path it takes).
+# A key kind is ("dict", domain[, codes it draws from]) or ("bool",); None as
+# the domain is a key whose caller knows none.
+_ALL_INT = ["sum", "count", "count_star", "min", "max", "avg"]
+_DOMAIN_CASES = {
+    "one-dict-key": ([("dict", 5)], [False], 1000, _ALL_INT, 16, "domain"),
+    "q1-shape-odd-n": ([("dict", 3), ("dict", 2)], [False, False], 1537,
+                       ["sum128", "sum128w", "avg", "count_star"], 4096, "domain"),
+    "masks-nulls-misses": ([("dict", 3), ("dict", 2)], [True, True], 2000,
+                           _ALL_INT + ["sum128", "sum128w"], 64, "domain"),
+    "one-mask-of-two": ([("dict", 4), ("dict", 3)], [False, True], 900,
+                        ["sum", "min", "count"], 32, "domain"),
+    "bool-keys": ([("bool",), ("dict", 3)], [True, False], 1200,
+                  ["sum", "count_star", "max"], 16, "domain"),
+    "bool-key-alone": ([("bool",)], [False], 700, ["sum", "min"], 2, "domain"),
+    "empty-selection": ([("dict", 3), ("dict", 2)], [True, False], 512,
+                        _ALL_INT + ["sum128"], 32, "domain"),
+    "slots-no-row-hits": ([("dict", 20, (0, 7, 19))], [True], 1000,
+                          _ALL_INT, 32, "domain"),
+    "negative-and-extreme": ([("dict", 3)], [True], 800,
+                             ["sum", "sum128", "sum128w", "min", "max"], 8, "domain"),
+    "wide-minmax": ([("dict", 3), ("bool",)], [True, True], 1100,
+                    ["minw", "maxw", "sum128w"], 16, "domain"),
+    "int32-and-bool-inputs": ([("dict", 4)], [False], 600,
+                              ["sum32", "min32", "maxbool", "minf", "maxf"], 8, "domain"),
+    # one key without a mask spans domain + 1 slots
+    "slots-under-constant": ([("dict", DOMAIN_MAX_SLOTS - 2)], [False], 3000,
+                             ["sum", "count"], 2 * DOMAIN_MAX_SLOTS, "domain"),
+    "slots-at-constant": ([("dict", DOMAIN_MAX_SLOTS - 1)], [False], 3000,
+                          ["sum", "count"], 2 * DOMAIN_MAX_SLOTS, "domain"),
+    "slots-over-constant": ([("dict", DOMAIN_MAX_SLOTS)], [False], 3000,
+                            ["sum", "count"], 2 * DOMAIN_MAX_SLOTS, "sort"),
+    "slots-over-max-groups": ([("dict", 3, (0, 2)), ("dict", 2, (0, 1))], [False, False], 500,
+                              ["sum"], 8, "sort"),
+    "float-sum": ([("dict", 3)], [False], 500, ["sumf", "count"], 8, "sort"),
+    "float-avg": ([("dict", 3)], [False], 500, ["avgf"], 8, "sort"),
+    "none-domain": ([("dict", 3), ("dict", None)], [False, False], 500,
+                    ["sum", "count_star"], 16, "sort"),
+    "no-domains-at-all": ([("dict", 3)], [True], 500, ["sum"], 8, "sort"),
+}
+
+
+def _domain_case(name, rng):
+    kinds, masks, n, aggs, max_groups, path = _DOMAIN_CASES[name]
+    keys, domains = [], []
+    for kind, mask in zip(kinds, masks):
+        if kind[0] == "bool":
+            data = rng.random(n) < 0.4
+            domains.append(2)
+        else:
+            dom = kind[1]
+            pool = kind[2] if len(kind) > 2 else range(-1, dom or 3)
+            data = rng.choice(np.asarray(list(pool), np.int32), n)
+            domains.append(dom)
+        keys.append((jnp.asarray(data),
+                     jnp.asarray(rng.random(n) < 0.85) if mask else None))
+    sel = rng.random(n) < (0.0 if name == "empty-selection" else 0.8)
+    big = name == "negative-and-extreme"
+    lo, hi = (-(2**63), 2**63 - 1) if big else (-1000, 1000)
+
+    def ints(dtype=np.int64):
+        return jnp.asarray(rng.integers(lo, hi, n, dtype=np.int64).astype(dtype))
+
+    def valid():
+        return jnp.asarray(rng.random(n) < 0.9) if rng.random() < 0.7 else None
+
+    inputs, specs = [], []
+    for a in aggs:
+        if a == "count_star":
+            inputs.append(None)
+        elif a in ("sum128w", "minw", "maxw"):
+            # few distinct hi lanes, so that the lo lane decides extremes
+            hi_lane = rng.integers(-2, 2, n) if not big else rng.integers(lo, hi, n)
+            inputs.append((jnp.stack([jnp.asarray(hi_lane, jnp.int64), ints()], axis=1),
+                           valid()))
+        elif a in ("sum32", "min32"):
+            inputs.append((ints(np.int32), valid()))
+        elif a == "maxbool":
+            inputs.append((jnp.asarray(rng.random(n) < 0.5), valid()))
+        elif a.endswith("f"):
+            inputs.append((jnp.asarray(rng.normal(size=n)), valid()))
+        else:
+            inputs.append((ints(), valid()))
+        specs.append(AggSpec(
+            {"minw": "min", "maxw": "max", "sum32": "sum", "min32": "min",
+             "maxbool": "max", "minf": "min", "maxf": "max", "sumf": "sum",
+             "avgf": "avg"}.get(a, a)))
+    if name == "no-domains-at-all":
+        domains = None
+    return keys, jnp.asarray(sel), inputs, specs, max_groups, domains, path
+
+
+@pytest.mark.parametrize("name", list(_DOMAIN_CASES))
+def test_domain_path_equals_sort_path(name):
+    """``group_aggregate`` with ``key_domains`` against itself without: the
+    same keys in the same order, every result lane, ``num_groups`` and
+    ``overflow``, whichever path the gate takes; and the gate takes the one
+    the case names."""
+    keys, sel, inputs, specs, max_groups, domains, path = _domain_case(
+        name, np.random.default_rng(sorted(_DOMAIN_CASES).index(name))
+    )
+    slots = domain_slots(keys, inputs, specs, max_groups, domains)
+    assert (slots is not None) == (path == "domain")
+
+    def run(key_domains):
+        return group_aggregate(keys, sel, inputs, specs, max_groups, key_domains)
+
+    traced = str(jax.make_jaxpr(lambda: run(domains))())
+    assert (" sort[" in traced) == (path == "sort")
+    if path == "sort":
+        # the gate's refusal leaves the program a call without domains gets
+        assert traced == str(jax.make_jaxpr(lambda: run(None))())
+
+    (kd, kv), got, ng, ovf = run(domains)
+    (wkd, wkv), want, wng, wovf = run(None)
+    assert int(ng) == int(wng) and ng.dtype == wng.dtype
+    assert bool(ovf) == bool(wovf) is False and ovf.dtype == wovf.dtype
+    g = int(ng)
+    if name == "empty-selection":
+        assert g == 0
+    if name == "slots-no-row-hits":
+        assert g < slots
+    for a, b in zip(kd + kv, wkd + wkv):
+        assert a.dtype == b.dtype and a.shape == b.shape == (max_groups,)
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    for spec, a, b in zip(specs, got, want):
+        if spec.kind in ("count", "count_star"):
+            lanes = [(a, b, max_groups)]
+        else:
+            # a dead row's extreme is whatever the sort path's scan left there
+            rows = g if spec.kind in ("min", "max") else max_groups
+            lanes = [(a[0], b[0], rows), (a[1], b[1], max_groups)]
+        for x, y, rows in lanes:
+            assert x.dtype == y.dtype and x.shape == y.shape, (spec, x.dtype, y.dtype)
+            np.testing.assert_array_equal(np.asarray(x)[:rows], np.asarray(y)[:rows])
 
 
 _JOIN_CAP = 8192  # the property test's one out_capacity

@@ -574,7 +574,15 @@ class TestServedDefaultPathSpans:
         # the operators that chose something say so
         for s in ops:
             if s["name"] == "op:Aggregate":
-                assert s["attrs"]["groupBy"] == "sort" and s["attrs"]["attempts"] >= 1
+                # Q1's keys are two dictionary-coded columns (3 and 2 strings,
+                # each with a validity mask here: (3+2) * (2+2) slots); Q3's
+                # are integers and a date, which have no dictionary
+                want = {"groupBy": "domain", "slots": 20} if sql is _SERVED_Q1 \
+                    else {"groupBy": "sort"}
+                got = {k: s["attrs"].get(k) for k in ("groupBy", "slots")}
+                assert got == {"slots": None, **want}, s["attrs"]
+                assert s["attrs"]["attempts"] >= 1
+                assert s["attrs"]["maxGroups"] >= (got["slots"] or 0)
             if s["name"] == "op:Join":
                 assert s["attrs"]["joinKind"] == "INNER"
                 assert s["attrs"]["attempts"] == len(s["attrs"]["capacities"])
@@ -657,7 +665,11 @@ class TestServedCompiledPathSpans:
         assert slab["attrs"]["params"] >= 1 and slab["attrs"]["attempt"] == 1
         assert slab["attrs"]["steps"] >= 1 and slab["attrs"]["cap"] >= 1
         assert slab["attrs"]["groups"] >= 4
+        # both group keys are dictionary-coded and the slab's columns carry
+        # no validity mask: (3+1) * (2+1) slots, hit or miss
         cold_slab = next(s for s in cold if s["name"] == "stream.slab")
+        for s in (slab, cold_slab):
+            assert (s["attrs"]["groupBy"], s["attrs"]["slots"]) == ("domain", 12)
         assert cold_slab["attrs"]["cacheHit"] is False
         # spans round the work, not stamps after it: each lies inside its
         # parent on the same clock
@@ -761,10 +773,18 @@ class TestDistributedSpans:
         rows, _ = obs_cluster.execute(queries("tpch.tiny")[5])
         assert rows
         qid = _query_id_for(obs_cluster.coordinator_uri, Q5_MARKER)
-        spans = _cluster_timeline(obs_cluster, qid)
+        import time
+
+        deadline = time.monotonic() + 5.0
+        while True:
+            # the root span closes just after the client has its last page
+            spans = _cluster_timeline(obs_cluster, qid)
+            roots = [s for s in spans if s["parentId"] is None]
+            if roots or time.monotonic() > deadline:
+                break
+            time.sleep(0.01)
         assert all(s["traceId"] == qid for s in spans)
         by_id = {s["spanId"]: s for s in spans}
-        roots = [s for s in spans if s["parentId"] is None]
         assert len(roots) == 1 and roots[0]["name"] == "query"
 
         def depth(s, seen=50):

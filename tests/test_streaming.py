@@ -424,6 +424,141 @@ class TestLiteralVariantsThroughTheSlab:
         assert res.rows == compiled["local"].execute(sql)[0]
 
 
+# --- the group-by that does not sort, where the slab's dictionaries say so ----
+
+
+def _sorted_rows(jaxpr):
+    """Leading dimension of the first operand of every ``sort`` in a jaxpr,
+    sub-jaxprs (the loop body, nested jits, shard_map) included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "sort":
+            yield eqn.invars[0].aval.shape[0]
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else (v,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _sorted_rows(sub)
+
+
+class _SlabSpans:
+    """A span sink that keeps the ``stream.slab`` spans."""
+
+    def __init__(self):
+        self.attrs = []
+
+    def record(self, span):
+        if span.name == "stream.slab":
+            self.attrs.append(dict(span.attrs))
+
+
+@pytest.fixture()
+def slab_spans():
+    from trino_tpu.obs.trace import get_tracer
+
+    sink = _SlabSpans()
+    get_tracer().add_sink(sink)
+    try:
+        yield sink.attrs
+    finally:
+        get_tracer().remove_sink(sink)
+
+
+class TestDomainGroupByThroughTheSlab:
+    @pytest.fixture(scope="class")
+    def runner(self):
+        r = DistributedQueryRunner(n_devices=1)
+        r.session.set("stream_scan_threshold_rows", 1)
+        r.session.set("stream_device_chunk_rows", 32768)  # two steps of tiny
+        return r
+
+    def test_q1_takes_the_domain_path_and_its_program_sorts_no_chunk(
+        self, runner, slab_spans, monkeypatch
+    ):
+        """Q1 through the slab path: three DELTAs equal the interpreter, the
+        span says which way the rows were grouped, and the stored program
+        sorts neither a chunk's rows nor the merge's."""
+        import jax
+        import numpy as np
+
+        from trino_tpu.exec import streaming as S
+
+        stored = []
+        orig = S.StreamingAggregator._slab_attempt
+
+        def keeping(self, programs, slab, chunk_cols, num_rows, cap, span):
+            res = orig(self, programs, slab, chunk_cols, num_rows, cap, span)
+            program, meta = programs[("slab", self.site, self.G, cap, False)]
+            steps = np.int32((num_rows + cap - 1) // cap)
+            stored.append((cap, self.G, jax.make_jaxpr(program)(
+                self._init_state(meta), slab, steps, np.int64(num_rows),
+                self.params)))
+            return res
+
+        monkeypatch.setattr(S.StreamingAggregator, "_slab_attempt", keeping)
+        local = LocalQueryRunner(engine=runner.engine)
+        for delta in (90, 60, 120):
+            sql = Q1.format(delta)
+            res = runner.engine.execute_statement(sql, runner.session)
+            assert res.rows and res.rows == local.execute(sql)[0], delta
+        assert [a["cacheHit"] for a in slab_spans] == [False, True, True]
+        for a in slab_spans:
+            # l_returnflag has 3 strings, l_linestatus 2; no validity masks
+            assert (a["groupBy"], a["slots"]) == ("domain", 12), a
+            assert a["steps"] == 2 and a["cap"] == 32768
+        for cap, groups, jaxpr in stored:
+            rows = set(_sorted_rows(jaxpr.jaxpr))
+            assert cap not in rows and 2 * groups not in rows, rows
+
+    def test_a_table_written_to_gets_a_program_with_its_new_dictionary(
+        self, runner, slab_spans
+    ):
+        """The domain is a static of the stored program and the program's
+        key does not carry it: the store it lives in is the one of the
+        table's data version (``Engine._query_cache_entry``), so a write
+        that grows the dictionary reaches a store with no program in it."""
+        from trino_tpu import types as T
+        from trino_tpu.columnar import Batch, Column
+        from trino_tpu.connectors.api import ColumnSchema, TableSchema
+
+        mem = runner.catalogs.get("memory")
+        mem.create_table(
+            "default", "flags",
+            TableSchema("flags", (ColumnSchema("f", T.VARCHAR),
+                                  ColumnSchema("v", T.BIGINT))),
+        )
+
+        def insert(flags, values):
+            mem.insert("default", "flags", Batch(
+                [Column.from_values(T.VARCHAR, flags),
+                 Column.from_values(T.BIGINT, values)], len(flags)))
+
+        sql = ("select f, sum(v), count(*), min(v) from memory.default.flags"
+               " where v < 100 group by f order by f")
+        insert(["a", "b", "a", None, "b"], [1, 2, 3, 4, 5])
+        first = runner.engine.execute_statement(sql, runner.session)
+        again = runner.engine.execute_statement(sql, runner.session)
+        assert first.rows == again.rows == [
+            ("a", 4, 2, 1), ("b", 7, 2, 2), (None, 4, 1, 4)]
+        assert again.trace_count == 0
+        insert(["c", "a", "d"], [6, 7, 8])
+        grown = runner.engine.execute_statement(sql, runner.session)
+        assert grown.trace_count >= 1
+        assert grown.rows == [("a", 11, 3, 1), ("b", 7, 2, 2), ("c", 6, 1, 6),
+                              ("d", 8, 1, 8), (None, 4, 1, 4)]
+        # the dictionary's strings ("a", "b" and the "" that from_values
+        # keeps for a null), the -1 code and null; then two strings more
+        assert [(a["groupBy"], a["slots"], a["cacheHit"]) for a in slab_spans] \
+            == [("domain", 5, False), ("domain", 5, True), ("domain", 7, False)]
+
+    def test_integer_keys_keep_the_sort_path(self, runner, slab_spans):
+        sql = ("select l_linenumber, count(*) from lineitem"
+               " group by l_linenumber order by 1")
+        got = runner.engine.execute_statement(sql, runner.session).rows
+        assert got == LocalQueryRunner(engine=runner.engine).execute(sql)[0]
+        assert slab_spans[-1]["groupBy"] == "sort"
+        assert "slots" not in slab_spans[-1]
+
+
 def test_equal_plans_at_other_addresses_share_the_slab_program():
     """D4: what ``StreamingAggregator`` stores is keyed by content (fragment
     id, the aggregate's ordinal), not by ``id(node)``: an equal plan planned

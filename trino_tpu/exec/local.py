@@ -31,7 +31,13 @@ from trino_tpu.connectors.api import CatalogManager
 from trino_tpu.ir import Call, Constant, InputRef, RowExpr, SpecialForm, Variable, bind_variables
 from trino_tpu.obs.trace import get_tracer
 from trino_tpu.ops import join as J
-from trino_tpu.ops.aggregation import AggSpec, global_aggregate, group_aggregate
+from trino_tpu.ops.aggregation import (
+    AggSpec,
+    domain_slots,
+    global_aggregate,
+    group_aggregate,
+    key_domains_from,
+)
 from trino_tpu.ops.sort import SortKey, sort_indices
 from trino_tpu.planner import plan as P
 
@@ -590,15 +596,19 @@ class LocalExecutor:
             return self._aggregate_final(node, self._exec(node.source))
         return self._aggregate_result(node, self._exec(node.source))
 
-    def _group_aggregate(self, keys, sel, agg_inputs, specs):
+    def _group_aggregate(self, keys, sel, agg_inputs, specs, key_dicts):
         """``group_aggregate`` up its capacity ladder: a run whose groups
         overflow ``max_groups`` is made again with four times the room. The
-        operator's span says how often (``attempts``) and where it ended."""
+        operator's span says how often (``attempts``), where it ended and
+        which way the rows were grouped: the batch is whole here, so its
+        dictionaries are final and their lengths are the keys' domains."""
         max_groups = 1 << 12
         attempts = 1
+        key_domains = key_domains_from(keys, key_dicts)
+        slots = domain_slots(keys, agg_inputs, specs, max_groups, key_domains)
         while True:
             keys_out, results, ng, overflow = group_aggregate(
-                keys, sel, agg_inputs, specs, max_groups
+                keys, sel, agg_inputs, specs, max_groups, key_domains
             )
             if not bool(overflow):
                 break
@@ -608,9 +618,11 @@ class LocalExecutor:
                 raise ExecutionError("group-by cardinality too large")
         span = get_tracer().current()
         if span is not None:
-            span.set("groupBy", "sort")
+            span.set("groupBy", "domain" if slots else "sort")
             span.add("attempts", attempts)
             span.set("maxGroups", max_groups)
+            if slots:
+                span.set("slots", slots)
         return keys_out, results, int(ng)
 
     def _aggregate_partial(self, node: P.Aggregate, res: Result) -> Result:
@@ -628,7 +640,7 @@ class LocalExecutor:
             return Result(Batch(cols, 1), layout)
         keys = [res.pair(k) for k in node.group_keys]
         (kd, kv), raw, ng = self._group_aggregate(
-            keys, sel, agg_inputs, specs
+            keys, sel, agg_inputs, specs, key_dicts
         )
         cols: list[Column] = []
         layout: dict[str, int] = {}
@@ -798,7 +810,7 @@ class LocalExecutor:
         keys = [res.pair(k) for k in node.group_keys]
         key_dicts = [res.column(k).dictionary for k in node.group_keys]
         (kd, kv), raw, ng = self._group_aggregate(
-            keys, sel, combine_inputs, combine_specs
+            keys, sel, combine_inputs, combine_specs, key_dicts
         )
         cols = []
         for i, k in enumerate(node.group_keys):
@@ -1023,7 +1035,7 @@ class LocalExecutor:
         keys = [res.pair(k) for k in node.group_keys]
         key_dicts = [res.column(k).dictionary for k in node.group_keys]
         (kd, kv), results, ng = self._group_aggregate(
-            keys, sel, agg_inputs, specs
+            keys, sel, agg_inputs, specs, key_dicts
         )
         cols = []
         for i, k in enumerate(node.group_keys):

@@ -45,7 +45,13 @@ from trino_tpu import types as T
 from trino_tpu.columnar import Batch, Column, bucket_capacity
 from trino_tpu.exec.local import Result
 from trino_tpu.obs.trace import get_tracer
-from trino_tpu.ops.aggregation import AggSpec, global_aggregate, group_aggregate
+from trino_tpu.ops.aggregation import (
+    AggSpec,
+    domain_slots,
+    global_aggregate,
+    group_aggregate,
+    key_domains_from,
+)
 from trino_tpu.parallel.mesh import AXIS, shard_batch, smap
 from trino_tpu.planner import plan as P
 
@@ -431,6 +437,7 @@ class StreamingAggregator:
         span.set("cacheHit", hit is not None)
         if hit is not None:
             program, meta = hit
+            self._note_group_by(span, meta)
             state = self._init_state(meta)
             state = program(
                 state, slab, np.int32(n_steps), np.int64(num_rows), self.params
@@ -465,7 +472,11 @@ class StreamingAggregator:
         probe_chunk = Batch(
             probe_cols, cap, jax.ShapeDtypeStruct((cap,), jnp.bool_)
         )
-        meta = self._collect_meta(probe_chunk)
+        # a resident slab's dictionaries are final at staging, so their
+        # lengths are the group keys' domains; a program stored with them
+        # lives in a store that the table's data version names (engine.py)
+        meta = self._collect_meta(probe_chunk, resident=slab is not None)
+        self._note_group_by(span, meta)
         state = self._init_state(meta)
         program = jax.jit(
             self._make_slab_program(meta, cap, chunk_cols),
@@ -497,6 +508,13 @@ class StreamingAggregator:
             programs[("slabcap", self.site)] = cap
         self._check_overflow(state, prog_key, meta)
         return self._finish(state, meta)
+
+    def _note_group_by(self, span, meta: dict) -> None:
+        """Which way the step's chunk partial groups its rows, on the span."""
+        if self.nkeys:
+            span.set("groupBy", "domain" if meta["slots"] else "sort")
+            if meta["slots"]:
+                span.set("slots", meta["slots"])
 
     def _try_dense(self, slab: Batch, num_rows: int) -> Optional[Result]:
         """Dense-domain fast path: when the group keys span a small
@@ -792,12 +810,20 @@ class StreamingAggregator:
         key_dicts = [res.column(k).dictionary for k in self.agg.group_keys]
         return agg_inputs, specs, string_dicts, keys, key_dicts, sel
 
-    def _collect_meta(self, chunk: Batch) -> dict:
+    def _collect_meta(self, chunk: Batch, resident: bool = False) -> dict:
         """Static metadata (specs/widths/dicts) via abstract evaluation —
         no device compute; the first chunk is only executed by the step.
         Dictionary accesses that embed growth-sensitive constants (rank
         tables, missed encodes) are recorded so later chunks know whether
-        growing a dictionary invalidates the step."""
+        growing a dictionary invalidates the step.
+
+        ``resident``: the chunk is a slice of a staged slab, whose
+        dictionaries no later chunk grows, so the step may take their
+        lengths as the group keys' domains (``key_domains``; ``slots`` is
+        the chunk partial's slot count where that takes the domain path).
+        The host-chunk stream grows dictionaries chunk by chunk
+        (``_canonicalize_dicts``): a length baked into its step would go
+        stale, so it passes none and keeps the sort path."""
         from trino_tpu.columnar import Dictionary
 
         box = {}
@@ -811,6 +837,12 @@ class StreamingAggregator:
             box["string_dicts"] = string_dicts
             box["key_dicts"] = key_dicts
             box["key_dtypes"] = [kd.dtype for kd, _ in keys]
+            box["key_domains"] = (
+                key_domains_from(keys, key_dicts) if resident else None
+            )
+            box["slots"] = domain_slots(
+                keys, agg_inputs, specs, self.G, box["key_domains"]
+            )
             # per-chunk overflow sources (probe-spine join capacities);
             # execution order is deterministic, so the step trace will
             # produce flags in this same order
@@ -847,6 +879,8 @@ class StreamingAggregator:
             "string_dicts": string_dicts,
             "key_dicts": key_dicts,
             "key_dtypes": box["key_dtypes"],
+            "key_domains": box["key_domains"],
+            "slots": box["slots"],
             "ovf_names": [self.site] + box["ovf_names"],
         }
 
@@ -903,7 +937,8 @@ class StreamingAggregator:
                 )
             else:
                 out = sagg._step_grouped(
-                    state, keys, sel, agg_inputs, specs, combine, widths
+                    state, keys, sel, agg_inputs, specs, combine, widths,
+                    meta["key_domains"],
                 )
             # overflow lanes: [agg] + per-chunk join capacities, max'd
             # with the carried vector
@@ -916,7 +951,8 @@ class StreamingAggregator:
 
         return step
 
-    def _step_grouped(self, state, keys, sel, agg_inputs, specs, combine, widths):
+    def _step_grouped(self, state, keys, sel, agg_inputs, specs, combine,
+                      widths, key_domains):
         nkeys, G, n = self.nkeys, self.G, self.n
         nspec = len(specs)
         Gc = G  # chunk groups bounded by the same budget
@@ -940,7 +976,7 @@ class StreamingAggregator:
 
             # 1) chunk partial: raw rows -> chunk groups
             (ckd, ckv), craw, cng, covf = group_aggregate(
-                lkeys, lsel, linputs, specs, Gc
+                lkeys, lsel, linputs, specs, Gc, key_domains
             )
             clive = jnp.arange(Gc) < cng
             cvals, ccnts = [], []
@@ -986,7 +1022,7 @@ class StreamingAggregator:
                 mspecs.append(AggSpec("sum"))
                 mplan.append(("c", j, 0))
             (nkd, nkv), nraw, nng, novf = group_aggregate(
-                mkeys, msel, minputs, mspecs, G
+                mkeys, msel, minputs, mspecs, G, key_domains
             )
             nlive = jnp.arange(G) < nng
             nvals = [None] * nspec

@@ -1,4 +1,14 @@
-"""Group-by aggregation via sort + sorted-segment reductions.
+"""Group-by aggregation: a sort path and, for small static key domains, a
+domain path that bins rows by slot and reduces.
+
+Which path runs is decided per ``group_aggregate`` call, from what the call
+can observe (``domain_slots``): the **domain path** when the caller hands a
+static domain for every key (``key_domains``: a dictionary's length, 2 for a
+boolean), every key is one integer or boolean lane, the slot count is at most
+``DOMAIN_MAX_SLOTS`` and ``max_groups``, and no ``sum``/``avg`` input is
+floating point; the **sort path**, described below, in every other case, and
+whenever ``key_domains`` is None (the default). Both give the same groups in
+the same order, bit for bit.
 
 Reference semantics: ``operator/HashAggregationOperator.java:49`` +
 ``operator/MultiChannelGroupByHash.java:55`` (open-addressing hash group-by)
@@ -25,6 +35,12 @@ expressible without scatter:
 - group keys gather the first row of each segment.
 Float sums keep ``segment_sum`` (a global cumsum would change rounding).
 
+Domain path: a row's group is arithmetic on its key codes (slot id = sum of
+``(code + 1) * stride``, a null key in a slot of its own), and each integer
+reduction is one masked reduction over ``slot == d`` for the D slots, compare,
+select and reduce fused so that no (D, n) array exists. No sort, no
+permutation, no gather, no prefix scan; the whole call is one jitted program.
+
 Partial/final split: the same kernel serves both; COUNT partials re-aggregate
 with SUM, AVG decomposes into SUM+COUNT (exactly Trino's
 input/combine/output contract for distributed aggregation).
@@ -33,10 +49,13 @@ input/combine/output contract for distributed aggregation).
 from __future__ import annotations
 
 import dataclasses
+import functools
+import math
 from typing import Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from trino_tpu import types as T
 
@@ -61,8 +80,9 @@ def group_aggregate(
     agg_inputs: Sequence[tuple[jnp.ndarray, jnp.ndarray] | None],
     agg_specs: Sequence[AggSpec],
     max_groups: int,
+    key_domains: Sequence[int | None] | None = None,
 ):
-    """Sort-based grouped aggregation.
+    """Grouped aggregation (sort path, or domain path: module docstring).
 
     Args:
       keys: per key column (data, valid), each shape (n,).
@@ -71,6 +91,10 @@ def group_aggregate(
       agg_specs: kinds aligned with agg_inputs.
       max_groups: static output capacity (groups beyond are dropped —
         caller must size from stats; overflow is reported).
+      key_domains: per key the static number of codes it can take (the
+        dictionary's length for codes in [-1, len); 2 for a boolean), None
+        where unknown. The caller vouches for it; None (the default) keeps
+        the sort path.
 
     Returns:
       (group_key_data, group_key_valid): lists of (max_groups,) arrays
@@ -79,6 +103,11 @@ def group_aggregate(
       num_groups: int32 scalar
       overflow: bool scalar (true if groups were dropped)
     """
+    if domain_slots(keys, agg_inputs, agg_specs, max_groups, key_domains):
+        return _domain_aggregate(
+            tuple(keys), sel, tuple(agg_inputs), tuple(agg_specs),
+            tuple(key_domains), max_groups,
+        )
     n = sel.shape[0]
     idx = jnp.arange(n, dtype=jnp.int32)
     # ONE narrow sort: all key columns (plus selection/validity bits) are
@@ -144,12 +173,21 @@ def group_aggregate(
             out_key_data.append(jnp.where(kv, g, zero).astype(data.dtype))
         out_key_valid.append(kv)
 
+    results = _reduce_segments(seg, agg_specs, agg_inputs, _sorted_pair)
+    return (out_key_data, out_key_valid), results, num_groups, overflow
+
+
+def _reduce_segments(seg, agg_specs, agg_inputs, in_segment_order):
+    """Every aggregate of a call over its segments: the one loop both paths
+    run. ``seg`` reduces rows that lie in its own order (``_SortedSegments``:
+    sorted by group; ``_SlotSegments``: as they came) and
+    ``in_segment_order`` brings a ``(data, valid)`` input into that order."""
     results = []
     for spec, pair in zip(agg_specs, agg_inputs):
         if spec.kind == "count_star":
             results.append(seg.sizes.astype(jnp.int64))
             continue
-        s_data, s_valid = _sorted_pair(pair)
+        s_data, s_valid = in_segment_order(pair)
 
         def vcount():
             if s_valid is None:
@@ -206,7 +244,148 @@ def group_aggregate(
                 results.append((seg.extreme(masked, spec.kind), cnt))
         else:
             raise NotImplementedError(spec.kind)
-    return (out_key_data, out_key_valid), results, num_groups, overflow
+    return results
+
+
+# Most slots the domain path takes. Its work grows with slots x rows x lanes
+# and the sort path's does not. On the chip at Q1's 27 lanes and 2,097,152
+# rows the sort path takes 259 ms, the domain path 4.5 ms at 12 slots, 26 at
+# 256, 98 at 1,024 and 384 at 4,096 (PERF.md section 6, PR 29): 256 is where
+# it still wins tenfold, which leaves room for calls with few lanes, where
+# the sort path's fixed part is the larger share.
+DOMAIN_MAX_SLOTS = 256
+
+
+def _key_slots(data, valid, domain: int) -> int:
+    """Slots one key spans: its codes, -1 (a dictionary miss; a boolean has
+    none) and, where the key carries a validity mask, null."""
+    return domain + _code_base(data) + (valid is not None)
+
+
+def _code_base(data) -> int:
+    """What turns a key's lowest code into slot 0: 1 for dictionary codes,
+    which start at -1, 0 for a boolean."""
+    return 0 if data.dtype == jnp.bool_ else 1
+
+
+def key_domains_from(keys, dictionaries) -> list[int | None]:
+    """``key_domains`` as a caller that holds the key columns knows them: a
+    dictionary-coded key takes its dictionary's codes, a boolean two, any
+    other key an unknown number. Only where the dictionaries are final."""
+    return [
+        len(d) if d is not None else 2 if data.dtype == jnp.bool_ else None
+        for (data, _), d in zip(keys, dictionaries)
+    ]
+
+
+def domain_slots(keys, agg_inputs, agg_specs, max_groups, key_domains):
+    """Slot count D when this ``group_aggregate`` call takes the domain
+    path, None when it takes the sort path. Static: reads dtypes, shapes and
+    ``key_domains`` only, so a caller can ask before (or without) the call."""
+    if key_domains is None or not keys or len(key_domains) != len(keys):
+        return None
+    slots = 1
+    for (data, valid), domain in zip(keys, key_domains):
+        if domain is None or getattr(data, "ndim", 1) != 1:
+            return None
+        dt = np.dtype(data.dtype)
+        if dt != np.bool_ and not np.issubdtype(dt, np.integer):
+            return None
+        slots *= _key_slots(data, valid, domain)
+        if slots > min(DOMAIN_MAX_SLOTS, max_groups):
+            return None
+    for spec, pair in zip(agg_specs, agg_inputs):
+        # a float sum in another order rounds differently: the sort path's
+        # segmented scan keeps those answers what they were
+        if spec.kind in ("sum", "avg") and np.issubdtype(
+            np.dtype(pair[0].dtype), np.floating
+        ):
+            return None
+    return slots
+
+
+@functools.partial(
+    jax.jit, static_argnames=("agg_specs", "key_domains", "max_groups")
+)
+def _domain_aggregate(keys, sel, agg_inputs, agg_specs, key_domains, max_groups):
+    """``group_aggregate``'s domain path (contract and outputs as there).
+
+    One program: inside a trace it inlines into the caller's, called eagerly
+    it is one launch."""
+    sizes = [_key_slots(d, v, dom) for (d, v), dom in zip(keys, key_domains)]
+    D = math.prod(sizes)
+    slot = jnp.zeros(sel.shape, jnp.int32)
+    for (data, valid), size in zip(keys, sizes):
+        s = data.astype(jnp.int32) + _code_base(data)
+        if valid is not None:
+            s = jnp.where(valid, s, size - 1)  # null: the key's last slot
+        slot = slot * size + s
+    # slot order IS the sort path's group order: key 0 most significant,
+    # codes ascending from -1, null last (KeyPlan's fields)
+    seg = _SlotSegments(jnp.where(sel, slot, D), D)
+    per_slot = _reduce_segments(seg, agg_specs, agg_inputs, lambda pair: pair)
+
+    # compact the non-empty slots to the front, in slot order: src[g] is the
+    # g-th non-empty slot, by a (max_groups, D) comparison, no sort
+    nonempty = seg.sizes > 0
+    rank = jnp.cumsum(nonempty.astype(jnp.int32)) - 1
+    num_groups = jnp.sum(nonempty.astype(jnp.int32))
+    live = jnp.arange(max_groups, dtype=jnp.int32) < num_groups
+    pick = nonempty[None, :] & (
+        rank[None, :] == jnp.arange(max_groups, dtype=jnp.int32)[:, None]
+    )
+    src = jnp.sum(
+        jnp.where(pick, jnp.arange(D, dtype=jnp.int32)[None, :], 0), axis=1
+    )
+
+    def compact(v):
+        g = v[src]
+        return jnp.where(live if g.ndim == 1 else live[:, None], g, jnp.zeros_like(g))
+
+    out_key_data, out_key_valid = [], []
+    stride = D
+    for (data, valid), size in zip(keys, sizes):
+        stride //= size
+        s = (src // stride) % size
+        kv = live if valid is None else live & (s != size - 1)
+        code = s - _code_base(data)
+        out_key_data.append(jnp.where(kv, code, 0).astype(data.dtype))
+        out_key_valid.append(kv)
+    results = jax.tree_util.tree_map(compact, per_slot)
+    return (
+        (out_key_data, out_key_valid), results, num_groups,
+        jnp.zeros((), jnp.bool_),
+    )
+
+
+class _SlotSegments:
+    """``_SortedSegments``' reductions over rows binned by slot, in any row
+    order: each is one masked reduction over ``slot == d`` for the D slots
+    (a row with slot D is in none). ``_hit`` and every ``(D, n)`` expression
+    built on it is a broadcast that XLA fuses into the reduction consuming
+    it; no such array exists in memory."""
+
+    def __init__(self, slot, D: int):
+        self._hit = slot[None, :] == jnp.arange(D, dtype=jnp.int32)[:, None]
+        self.sizes = jnp.sum(self._hit, axis=1, dtype=jnp.int32)
+
+    def sum(self, x):
+        return jnp.sum(jnp.where(self._hit, x[None, :], 0), axis=1, dtype=x.dtype)
+
+    def _extreme(self, mask, x, kind: str):
+        ident = _max_ident(x.dtype) if kind == "min" else _min_ident(x.dtype)
+        red = jnp.min if kind == "min" else jnp.max
+        return red(jnp.where(mask, x[None, :], ident), axis=1)
+
+    def extreme(self, masked, kind: str):
+        return self._extreme(self._hit, masked, kind)
+
+    def extreme2(self, k1, k2, kind: str):
+        """Lexicographic two-lane min/max: the extreme of ``k1``, then of
+        ``k2`` among the slot's rows tied on it."""
+        b1 = self._extreme(self._hit, k1, kind)
+        tied = self._hit & (k1[None, :] == b1[:, None])
+        return b1, self._extreme(tied, k2, kind)
 
 
 def _blocked_scan(x, scan, combine, ident):
