@@ -126,20 +126,6 @@ def run_query(conn: Connection, sql: str) -> tuple[list[tuple], dict, float]:
     return normalise(rows), info, seconds
 
 
-def dense_programs(server: TrinoTpuServer) -> int:
-    """Dense group-by kernel programs in the engine's program cache (the
-    ``("dense", plan, ...)`` key of exec/streaming.py ``_try_dense``)."""
-    engine = server.engine
-    with engine._query_cache_lock:
-        stores = [e["programs"] for e in engine._query_cache.values()]
-    return sum(
-        1
-        for programs in stores
-        for key in list(programs)
-        if isinstance(key, tuple) and key and key[0] == "dense"
-    )
-
-
 def check(qn: int, session: str, rows: list[tuple]) -> None:
     want = EXPECTED[qn]
     require(len(rows) == len(want), (qn, session, len(rows), len(want)))
@@ -164,9 +150,8 @@ def assert_compiled(qn: int, info: dict) -> dict:
     return ex
 
 
-def run_pair(server, conn, qn: int, session: str, sql: str, peak) -> list[tuple]:
+def run_pair(conn, qn: int, session: str, sql: str, peak) -> list[tuple]:
     distributed = session == "distributed"
-    dense_before = dense_programs(server)
     results = []
     for temperature in ("cold", "warm"):
         rows, info, seconds = run_query(conn, sql)
@@ -185,7 +170,6 @@ def run_pair(server, conn, qn: int, session: str, sql: str, peak) -> list[tuple]
             ex = assert_compiled(qn, info)
             line["dispatchRoundTrips"] = ex["dispatchRoundTrips"]
             line["fusedFragments"] = ex["fusedFragments"]
-            line["dense_groupby_kernel"] = dense_programs(server) > dense_before
         say(**line)
         results.append(rows)
     require(results[0] == results[1], f"Q{qn} [{session}]: cold != warm")
@@ -222,10 +206,10 @@ def one_chip(server: TrinoTpuServer, device) -> None:
     local = Connection(server.base_uri, ClientSession())
     answers, variants = {}, {}
     for qn in DISTRIBUTED_QUERIES:
-        answers[qn] = run_pair(server, dist, qn, "distributed", text[qn], peak)
+        answers[qn] = run_pair(dist, qn, "distributed", text[qn], peak)
         variants[qn] = run_variant(dist, qn, "distributed", text[qn], answers[qn])
     for qn in LOCAL_QUERIES:
-        rows = run_pair(server, local, qn, "local", text[qn], peak)
+        rows = run_pair(local, qn, "local", text[qn], peak)
         require(
             qn not in answers or rows == answers[qn],
             f"Q{qn}: the two sessions disagree",

@@ -66,7 +66,6 @@ _SHARD_WEIGHTS = {
     "test_observability.py": 40,
     "test_memory_spill.py": 35,
     "test_tpcds.py": 30,
-    "test_dense_groupby.py": 30,
     "test_window.py": 30,
     "test_single_device_lane.py": 30,
     "test_speculation.py": 30,

@@ -5,14 +5,14 @@ described, not attached (``v5e:2x2``). It refuses what interpret mode and
 the CPU backend let through: a misaligned slice, too much VMEM, a scalar
 store to VMEM, a program that does not fit HBM. Nothing runs, so these
 cases say nothing about results or times; ``chip_smoke.py`` does that on
-the chip. Every ``pallas_call`` in ``trino_tpu/`` has a case here.
+the chip. A kernel written for the chip by hand gets its case here
+(``tests/test_lint.py`` holds the tree to that).
 
 All cases stay in THIS file (one xdist worker then holds the TPU library),
 and the topology is described inside a module fixture, never at import.
 """
 
 import os
-from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -20,8 +20,6 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as PS, SingleDeviceSharding
 
-from trino_tpu.exec.streaming import dense_program
-from trino_tpu.ops import dense_groupby as DG
 from trino_tpu.ops import dense_join as DJ
 from trino_tpu.ops import keypack
 from trino_tpu.ops.join import probe_join
@@ -64,63 +62,6 @@ def _compile(fn, *args):
     compiled = jax.jit(fn).lower(*args).compile()
     assert compiled.memory_analysis() is not None
     return compiled
-
-
-# (rows, plan): 2^25 rows x 4096 groups; 8192 groups with a signed
-# 128-bit sum; four value columns of Q1's widths
-_DENSE_CASES = {
-    "2^25x4096": (
-        1 << 25,
-        DG.DensePlan(G=4096, cols=(DG.DenseCol(True, 20),), pair128=(False,)),
-    ),
-    "2^22x8192-signed128": (
-        1 << 22,
-        DG.DensePlan(G=8192, cols=(DG.DenseCol(False, 64),), pair128=(True,)),
-    ),
-    "2^23x128-4cols": (
-        LINEITEM_SLAB,
-        DG.DensePlan(
-            G=128,
-            cols=(DG.DenseCol(True, 13), DG.DenseCol(True, 24),
-                  DG.DenseCol(True, 37), DG.DenseCol(False, 64)),
-            pair128=(False, False, True, True),
-        ),
-    ),
-}
-
-
-@pytest.mark.parametrize("case", list(_DENSE_CASES))
-def test_dense_groupby_kernel(one_chip, case):
-    rows, plan = _DENSE_CASES[case]
-    compiled = _compile(
-        lambda b, vs: DG.dense_groupby_device(plan, b, vs),
-        _shape(one_chip, (rows,), jnp.int32),
-        [_shape(one_chip, (rows,), jnp.int64) for _ in plan.cols],
-    )
-    assert "tpu_custom_call" in compiled.as_text()
-
-
-def test_dense_program_and_reconstruct_at_lineitem_slab(one_chip):
-    """What ``_try_dense`` jits for Q1 at SF1: two dictionary-code keys,
-    the aggregate inputs, then the device reconstruction in its own jit."""
-    n = LINEITEM_SLAB
-    plan = _DENSE_CASES["2^23x128-4cols"][1]
-    nk = 2
-    producer = _compile(
-        dense_program(plan),
-        _shape(one_chip, (n,), jnp.bool_),
-        _shape(one_chip, (nk,), jnp.int64),
-        _shape(one_chip, (nk,), jnp.int32),
-        [_shape(one_chip, (n,), jnp.int32) for _ in range(nk)],
-        [_shape(one_chip, (n,), jnp.int64) for _ in plan.cols],
-    )
-    assert "tpu_custom_call" in producer.as_text()
-    drain = _shape(one_chip, (plan.m, 128), jnp.int32)
-    _compile(
-        partial(DG.reconstruct_device, plan),
-        drain, drain,
-        *[_shape(one_chip, (nk,), jnp.int64) for _ in range(3)],
-    )
 
 
 _JOIN_BUILD, _JOIN_PROBE, _JOIN_CAP = 1 << 21, LINEITEM_SLAB, 1 << 23

@@ -271,89 +271,6 @@ STAR_SQL = """
     order by i.i_category, d.d_year
 """
 
-E2E_QUERIES = {"q5": QUERIES[5], "q10": QUERIES[10], "star": STAR_SQL}
-
-
-@pytest.fixture(scope="module")
-def strategy_runners():
-    made = {}
-
-    def get(strategy):
-        if strategy not in made:
-            r = DistributedQueryRunner()
-            r.session.set("join_distribution_type", "PARTITIONED")
-            r.session.set("join_strategy", strategy)
-            made[strategy] = r
-        return made[strategy]
-
-    return get
-
-
-@pytest.fixture(scope="module")
-def interpreter_ref():
-    # lazy per-query: a `-m 'not slow'` run never pays for the q10
-    # interpreter reference it would not compare against
-    r = LocalQueryRunner()
-    cache = {}
-
-    def get(k):
-        if k not in cache:
-            cache[k] = r.execute(E2E_QUERIES[k])[0]
-        return cache[k]
-
-    return get
-
-
-# every strategy on the star query; auto/sort on the TPC-H pair — a
-# cold `auto` resolves to `dense` (no history), so the dense column is
-# already covered and the explicit pin only needs one query's worth of
-# suite time. q10 repeats the q5 evidence on a second join spine, so
-# it rides in the slow lane.
-E2E_CASES = [
-    ("auto", "q5"), ("sort", "q5"),
-    pytest.param("auto", "q10", marks=pytest.mark.slow),
-    pytest.param("sort", "q10", marks=pytest.mark.slow),
-    ("auto", "star"), ("sort", "star"), ("dense", "star"),
-]
-
-
-@pytest.mark.parametrize("strategy,qkey", E2E_CASES)
-def test_strategies_bit_identical(strategy, qkey, strategy_runners,
-                                  interpreter_ref):
-    """Acceptance: TPC-H Q5/Q10 and the TPC-DS star query return
-    bit-identical rows across join_strategy auto/sort/dense, and all
-    match the single-node interpreter."""
-    rows, _ = strategy_runners(strategy).execute(E2E_QUERIES[qkey])
-    assert rows == interpreter_ref(qkey), f"{strategy} diverged on {qkey}"
-
-
-def test_star_query_fuses_multiway():
-    """Acceptance: under the default (broadcast) distribution the
-    dimension builds fuse INTO the fact-probe program — one multiway
-    fused star join in ONE dispatch round-trip, strictly more fragments
-    fused and strictly fewer round-trips than with the dense tier off
-    (broadcast links never fused pairwise), with the chosen strategy
-    surfaced per site in exchangeStats.joinStrategy."""
-    r = DistributedQueryRunner()
-    res = r.engine.execute_statement(STAR_SQL, r.session)
-    ex = res.exchange_stats or {}
-
-    rs = DistributedQueryRunner()
-    rs.session.set("dense_join", False)  # pairwise reference plan
-    res_s = rs.engine.execute_statement(STAR_SQL, rs.session)
-    ex_s = res_s.exchange_stats or {}
-
-    assert res.rows == res_s.rows
-    strategies = ex.get("joinStrategy") or {}
-    assert strategies, "no per-site join strategies surfaced"
-    assert set(strategies.values()) == {"dense"}
-    assert all(s.startswith("densejoin@") for s in strategies)
-    assert ex.get("dispatchRoundTrips", 99) == 1, ex
-    assert ex.get("fusedFragments", 0) > ex_s.get("fusedFragments", 0)
-    assert ex.get("dispatchRoundTrips", 99) < ex_s.get(
-        "dispatchRoundTrips", 0
-    )
-
 
 def _mem_tables(catalogs, n_facts=2000, n_dims=16, seed=7):
     from trino_tpu import types as T
@@ -383,6 +300,104 @@ def _mem_tables(catalogs, n_facts=2000, n_dims=16, seed=7):
 MEM_JOIN_SQL = ("select sum(f.v * d.name) as chk, count(*) as c "
                 "from memory.default.facts f "
                 "join memory.default.dims d on f.k = d.k")
+
+
+E2E_QUERIES = {"q5": QUERIES[5], "q10": QUERIES[10], "star": STAR_SQL,
+               "mem": MEM_JOIN_SQL}
+
+
+@pytest.fixture(scope="module")
+def strategy_runners():
+    made = {}
+
+    def get(strategy):
+        if strategy not in made:
+            r = DistributedQueryRunner()
+            r.session.set("join_distribution_type", "PARTITIONED")
+            r.session.set("join_strategy", strategy)
+            _mem_tables(r.catalogs)
+            made[strategy] = r
+        return made[strategy]
+
+    return get
+
+
+@pytest.fixture(scope="module")
+def interpreter_ref():
+    # lazy per-query: a `-m 'not slow'` run never pays for the q10
+    # interpreter reference it would not compare against
+    r = LocalQueryRunner()
+    _mem_tables(r.catalogs)
+    cache = {}
+
+    def get(k):
+        if k not in cache:
+            cache[k] = r.execute(E2E_QUERIES[k])[0]
+        return cache[k]
+
+    return get
+
+
+# every strategy on the star query; auto/sort on the TPC-H pair — a
+# cold `auto` resolves to `dense` (no history), so the dense column is
+# already covered and the explicit pin only needs one query's worth of
+# suite time. q10 repeats the q5 evidence on a second join spine, so
+# it rides in the slow lane. The small memory join runs under every tier,
+# and there the ladder has to stay at rest as well.
+E2E_CASES = [
+    ("auto", "q5"), ("sort", "q5"),
+    pytest.param("auto", "q10", marks=pytest.mark.slow),
+    pytest.param("sort", "q10", marks=pytest.mark.slow),
+    ("auto", "star"), ("sort", "star"), ("dense", "star"),
+    ("sort", "mem"), ("dense", "mem"), ("matmul", "mem"),
+]
+
+
+@pytest.mark.parametrize("strategy,qkey", E2E_CASES)
+def test_strategies_bit_identical(strategy, qkey, strategy_runners,
+                                  interpreter_ref):
+    """Acceptance: TPC-H Q5/Q10 and the TPC-DS star query return
+    bit-identical rows across join_strategy auto/sort/dense, and all
+    match the single-node interpreter."""
+    r = strategy_runners(strategy)
+    res = r.engine.execute_statement(E2E_QUERIES[qkey], r.session)
+    assert res.rows == interpreter_ref(qkey), f"{strategy} diverged on {qkey}"
+    if qkey == "mem":
+        # 16 build rows at the engineered load: the tier that was asked for
+        # answers in compiled programs at its first capacities, with no
+        # overflow, no re-hash and no demotion on the way
+        ex = res.exchange_stats
+        assert ex["overflow_retries"] == 0, ex
+        assert ex["dispatchRoundTrips"] >= 1
+        assert set(ex["joinStrategy"].values()) == {strategy}, ex["joinStrategy"]
+
+
+def test_star_query_fuses_multiway():
+    """Acceptance: under the default (broadcast) distribution the
+    dimension builds fuse INTO the fact-probe program — one multiway
+    fused star join in ONE dispatch round-trip, strictly more fragments
+    fused and strictly fewer round-trips than with the dense tier off
+    (broadcast links never fused pairwise), with the chosen strategy
+    surfaced per site in exchangeStats.joinStrategy."""
+    r = DistributedQueryRunner()
+    res = r.engine.execute_statement(STAR_SQL, r.session)
+    ex = res.exchange_stats or {}
+
+    rs = DistributedQueryRunner()
+    rs.session.set("dense_join", False)  # pairwise reference plan
+    res_s = rs.engine.execute_statement(STAR_SQL, rs.session)
+    ex_s = res_s.exchange_stats or {}
+
+    assert res.rows == res_s.rows
+    strategies = ex.get("joinStrategy") or {}
+    assert strategies, "no per-site join strategies surfaced"
+    assert set(strategies.values()) == {"dense"}
+    assert all(s.startswith("densejoin@") for s in strategies)
+    assert ex.get("dispatchRoundTrips", 99) == 1, ex
+    assert ex.get("fusedFragments", 0) > ex_s.get("fusedFragments", 0)
+    assert ex.get("dispatchRoundTrips", 99) < ex_s.get(
+        "dispatchRoundTrips", 0
+    )
 
 
 def test_matmul_strategy_pinned_by_session(tmp_path):
@@ -478,35 +493,3 @@ def test_warm_repeat_zero_overflow_retries(tmp_path):
         Session(properties=_props(join_strategy="sort",
                                   query_history=False)))
     assert off.rows == cold.rows
-
-
-# ---------------------------------------------------------------------------
-# bench_suite contract
-# ---------------------------------------------------------------------------
-
-
-class TestBenchJoin:
-    """bench_suite.bench_join publishes a stable schema and the graceful
-    ladder holds while timing (overflow_fallbacks must be 0)."""
-
-    def test_tiny_run_schema_and_zero_fallbacks(self):
-        import bench_suite
-
-        out = bench_suite.bench_join(log2_rows=(10,))
-        assert out["overflow_fallbacks"] == 0
-        entry = out["2^10"]
-        assert entry["build_rows"] == 1024
-        for tier in ("sort", "dense", "matmul"):
-            assert entry[f"{tier}_rows_per_sec_per_chip"] > 0
-        assert entry["join_rows"] > 0
-        assert entry["dense_over_sort"] > 0
-
-    @pytest.mark.slow
-    def test_large_run_zero_fallbacks(self):
-        # the headline 2^22 point from the suite entry; slow-marked so
-        # tier-1 stays within budget — run explicitly or via bench_suite
-        import bench_suite
-
-        out = bench_suite.bench_join(log2_rows=(22,))
-        assert out["overflow_fallbacks"] == 0
-        assert out["2^22"]["join_rows"] > 0

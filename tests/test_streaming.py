@@ -424,6 +424,43 @@ class TestLiteralVariantsThroughTheSlab:
         assert res.rows == compiled["local"].execute(sql)[0]
 
 
+@pytest.mark.parametrize("qn", [6, 1])
+def test_chip_smoke_variant_is_another_literal_of_the_same_plan(qn, slab_spans):
+    """``chip_smoke.VARIANTS`` rewrites a query's text by ``str.replace`` and
+    dies on the chip if the text has drifted; no test read it. Here, at
+    ``tpch.tiny``: the rewritten text differs, has the same plan fingerprint,
+    is answered out of the first literals' programs with nothing traced, and
+    the default session gives the same rows for it."""
+    # importing it needs no chip, and nothing called here does
+    import chip_smoke as smoke
+    from trino_tpu.benchmarks.tpch import queries
+    from trino_tpu.planner.canonicalize import canonicalize_plan
+    from trino_tpu.sql.parser import parse_statement
+
+    assert set(smoke.VARIANTS) == {6, 1} == set(smoke.DISTRIBUTED_QUERIES)
+    base = queries("tpch.tiny")[qn]
+    other = smoke.variant(qn, base)
+    assert other != base
+    r = DistributedQueryRunner(n_devices=1)
+    r.session.set("stream_scan_threshold_rows", 1)
+
+    def fingerprint(sql):
+        plan = r.engine.plan(parse_statement(sql), r.session)
+        _, params, fp = canonicalize_plan(plan, r.session, 1)
+        return fp, [v for v, _ in params]
+
+    (fp, literals), (fp_other, literals_other) = fingerprint(base), fingerprint(other)
+    assert fp is not None and fp == fp_other
+    assert literals != literals_other
+    first = r.engine.execute_statement(base, r.session)
+    varied = r.engine.execute_statement(other, r.session)
+    assert first.trace_count >= 1
+    assert varied.trace_count == 0 and varied.program_cache_hits >= 1
+    assert [a["cacheHit"] for a in slab_spans] == [False, True]
+    assert varied.rows and varied.rows != first.rows
+    assert varied.rows == LocalQueryRunner(engine=r.engine).execute(other)[0]
+
+
 # --- the group-by that does not sort, where the slab's dictionaries say so ----
 
 
@@ -550,13 +587,153 @@ class TestDomainGroupByThroughTheSlab:
         assert [(a["groupBy"], a["slots"], a["cacheHit"]) for a in slab_spans] \
             == [("domain", 5, False), ("domain", 5, True), ("domain", 7, False)]
 
-    def test_integer_keys_keep_the_sort_path(self, runner, slab_spans):
-        sql = ("select l_linenumber, count(*) from lineitem"
-               " group by l_linenumber order by 1")
-        got = runner.engine.execute_statement(sql, runner.session).rows
-        assert got == LocalQueryRunner(engine=runner.engine).execute(sql)[0]
-        assert slab_spans[-1]["groupBy"] == "sort"
-        assert "slots" not in slab_spans[-1]
+    @pytest.fixture(scope="class")
+    def shapes(self, runner):
+        """One 65,536-row memory table holding every key shape the cases
+        below group by, and the columns as NumPy arrays for the oracle."""
+        import numpy as np
+
+        from trino_tpu import types as T
+        from trino_tpu.columnar import Batch, Column
+        from trino_tpu.connectors.api import ColumnSchema, TableSchema
+
+        n = 1 << 16
+        rng = np.random.default_rng(7)
+        data = {
+            "k": rng.integers(0, 97, n).astype(np.int64),
+            "k2": rng.integers(0, 5, n).astype(np.int64),
+            "ki": rng.integers(0, 11, n).astype(np.int32),
+            "kn": rng.integers(0, 7, n).astype(np.int64),
+            "kneg": rng.integers(-50, 51, n).astype(np.int64),
+            # 20 values 1,000 apart: a range of 19,001
+            "kwide": rng.integers(0, 20, n).astype(np.int64) * 1000,
+            "v": rng.integers(-(1 << 30), 1 << 30, n).astype(np.int64),
+            "b": rng.integers(0, 2, n).astype(np.bool_),
+        }
+        kn_valid = rng.integers(0, 4, n) > 0
+        flags = np.asarray(["x", "y", "z"])[rng.integers(0, 3, n)]
+        types = {"ki": T.INTEGER, "b": T.BOOLEAN}
+        cols = [
+            Column(types.get(name, T.BIGINT), arr,
+                   kn_valid if name == "kn" else None)
+            for name, arr in data.items()
+        ] + [Column.from_values(T.VARCHAR, flags.tolist())]
+        names = list(data) + ["s"]
+        mem = runner.catalogs.get("memory")
+        mem.create_table(
+            "default", "shapes",
+            TableSchema("shapes", tuple(
+                ColumnSchema(name, c.type) for name, c in zip(names, cols))),
+        )
+        mem.insert("default", "shapes", Batch(cols, n))
+        return {**data, "kn_valid": kn_valid, "s": flags}
+
+    # What ``group by`` and what beside it; which way the slab step groups.
+    # The sort-path rows are the shapes the Pallas group-by's gate admitted
+    # (integer keys with no mask, integer sums, up to 8,192 bins by the data's
+    # min and max) or refused at its edge (a mask, min/max, a wider range, no
+    # row selected), which ran end to end on a chip only while it lived; the
+    # domain rows are what took its place.
+    _SHAPES = {
+        "bigint-97-values-signed-sums": ("k", "sum(v), count(*)", "", "sort"),
+        "two-integer-keys": ("k, k2", "sum(v), count(*)", "", "sort"),
+        "int32-key": ("ki", "sum(v), count(*)", "", "sort"),
+        "key-with-nulls": ("kn", "sum(v), count(*), count(kn)", "", "sort"),
+        "negative-keys": ("kneg", "sum(v), count(*)", "", "sort"),
+        "range-past-8192": ("kwide", "sum(v), count(*)", "", "sort"),
+        "avg-over-integers": ("k2", "avg(v), avg(ki)", "", "sort"),
+        "min-max-beside-sums": ("k2", "min(v), max(v), sum(v)", "", "sort"),
+        "no-row-selected": (
+            "k", "sum(v), count(*)", "where v > 4611686018427387904", "sort"),
+        "boolean-key": ("b", "sum(v), count(*), min(v)", "", "domain"),
+        "dictionary-key": ("s", "sum(v), count(*), max(v)", "", "domain"),
+        "boolean-and-dictionary-keys": (
+            "b, s", "sum(v), avg(v), count(*)", "", "domain"),
+    }
+
+    @staticmethod
+    def _numpy_rows(shape, t):
+        """The rows NumPy gives for two of the shapes; None for the others,
+        which the default session answers."""
+        import numpy as np
+
+        if shape == "bigint-97-values-signed-sums":
+            sums = np.zeros(97, np.int64)
+            np.add.at(sums, t["k"], t["v"])
+            counts = np.bincount(t["k"], minlength=97)
+            return [(k, int(sums[k]), int(counts[k])) for k in range(97)]
+        if shape == "min-max-beside-sums":
+            by = [t["v"][t["k2"] == k] for k in range(5)]
+            return [(k, int(v.min()), int(v.max()), int(v.sum()))
+                    for k, v in enumerate(by)]
+        return None
+
+    @pytest.mark.parametrize("shape", list(_SHAPES))
+    def test_group_by_shapes_through_the_slab(
+        self, runner, shapes, slab_spans, shape
+    ):
+        """Each shape twice through the slab program (the second answer out
+        of the stored one) equals NumPy's rows for it, or the default
+        session's, and the span says which way the step grouped."""
+        keys, aggs, where, path = self._SHAPES[shape]
+        sql = (f"select {keys}, {aggs} from memory.default.shapes {where}"
+               f" group by {keys} order by {keys}")
+        first = runner.engine.execute_statement(sql, runner.session)
+        again = runner.engine.execute_statement(sql, runner.session)
+        want = self._numpy_rows(shape, shapes)
+        if want is None:
+            want = LocalQueryRunner(engine=runner.engine).execute(sql)[0]
+        assert first.rows == again.rows == want, shape
+        assert bool(want) == (shape != "no-row-selected")
+        assert again.trace_count == 0
+        assert [a["cacheHit"] for a in slab_spans] == [False, True]
+        for a in slab_spans:
+            assert a["groupBy"] == path and ("slots" in a) == (path == "domain"), a
+            assert a["steps"] == 2 and a["cap"] == 32768
+
+    def test_the_store_of_a_warm_streamed_query_holds_programs_and_budgets(self):
+        """What a fingerprint's program store holds after a warm streamed
+        query, by kind: the slab program, its remembered chunk size, the
+        fragment programs, the capacity sites, the fragmented plan and the
+        counters. No key statistics of the data and nothing named by a Python
+        address: ``chip_smoke.py`` used to open the store by hand to look
+        for a group-by kernel's programs; this pins what is there."""
+        import jax
+        import numpy as np
+
+        runner = DistributedQueryRunner(n_devices=1)  # one query, one store
+        runner.session.set("stream_scan_threshold_rows", 1)
+        sql = Q1.format(90)
+        runner.engine.execute_statement(sql, runner.session)
+        warm = runner.engine.execute_statement(sql, runner.session)
+        assert warm.trace_count == 0 and warm.program_cache_hits >= 1
+        engine = runner.engine
+        with engine._query_cache_lock:
+            (store,) = [e["programs"] for e in engine._query_cache.values()]
+
+        def kind(key):
+            # a fragment program's key is (its name, the capacities it holds)
+            while isinstance(key, tuple):
+                key = key[0]
+            return key
+
+        assert {kind(k) for k in store} == {
+            "slab", "slabcap",              # exec/streaming.py
+            "post", "fused", "caps",        # fragment programs, capacity sites
+            "__subplan__", "__fusedunits__", "__fragstats__", "__skewroles__",
+            "__stats__",
+        }
+        slab = [k for k in store if kind(k) in ("slab", "slabcap")]
+        assert sorted(k[0] for k in slab) == ["slab", "slabcap"]
+        assert {k[1] for k in slab} == {"agg@2#0"}
+        program, meta = store[next(k for k in slab if k[0] == "slab")]
+        assert callable(program) and meta["slots"] == 12
+        held = [
+            (k, type(leaf).__name__)
+            for k, v in store.items() for leaf in jax.tree_util.tree_leaves(v)
+            if isinstance(leaf, (jax.Array, np.ndarray))
+        ]
+        assert not held, held
 
 
 def test_equal_plans_at_other_addresses_share_the_slab_program():

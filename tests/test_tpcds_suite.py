@@ -3,8 +3,8 @@
 Mirrors the reference's TPC-DS conformance corpus
 (``testing/trino-benchto-benchmarks/.../tpcds.yaml``). Covers the
 star-join/reporting families plus the BASELINE Q95 shape; the full
-multi-CTE Q64 lives in tests/test_tpcds_oracle.py (shared with
-bench_suite.py via trino_tpu.benchmarks.tpcds).
+multi-CTE Q64 lives in tests/test_tpcds_oracle.py (its text in
+trino_tpu.benchmarks.tpcds).
 """
 
 import pytest

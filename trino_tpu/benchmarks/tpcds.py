@@ -1,10 +1,9 @@
 """TPC-DS benchmark queries (spec text), parameterized by schema.
 
 Reference: ``testing/trino-benchto-benchmarks/src/main/resources/benchmarks/
-presto/tpcds.yaml`` — here the BASELINE config-3 pair (Q64/Q95) is shared
-between the conformance corpus (tests/test_tpcds_oracle.py) and the
-benchmark driver (bench_suite.py). Constants are adapted to the tiny
-generator domains where noted in the test corpus.
+presto/tpcds.yaml`` — here the BASELINE config-3 pair (Q64/Q95), the
+conformance corpus of tests/test_tpcds_oracle.py. Constants are adapted
+to the tiny generator domains where noted in the test corpus.
 """
 
 

@@ -3,7 +3,7 @@
 Reference: ``testing/trino-benchto-benchmarks/src/main/resources/benchmarks/
 presto/tpch.yaml`` — the macro-benchmark suite runs these same 22 queries;
 here the text doubles as the conformance corpus (tests/test_tpch_suite.py)
-and the benchmark driver input (bench_suite.py).
+and as what ``chip_smoke.py`` sends to the chip.
 """
 
 

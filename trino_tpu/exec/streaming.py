@@ -33,7 +33,6 @@ aggregate's fragment id and ordinal), never by ``id(node)``."""
 from __future__ import annotations
 
 import time
-from functools import partial
 from typing import Optional
 
 import jax
@@ -106,24 +105,6 @@ def streamable_chain(frag_root: P.PlanNode):
     if not isinstance(node, P.TableScan):
         return None
     return agg, node, build_roots
-
-
-def dense_program(plan):
-    """The dense group-by producer ``_try_dense`` jits: row-major bin ids
-    from the key columns (dead rows to the overflow bin ``plan.G``), then
-    the binning kernel. ``mins``/``strides`` are traced arguments."""
-    from trino_tpu.ops import dense_groupby as DG
-
-    def prog(sel, mins, strides, key_arrs, val_arrs):
-        bin_ = jnp.zeros(sel.shape[0], jnp.int32)
-        for i, kd in enumerate(key_arrs):
-            bin_ = bin_ + (kd - mins[i]).astype(jnp.int32) * strides[i]
-        bin_ = jnp.where(sel, bin_, jnp.int32(plan.G))
-        return DG.dense_groupby_device(
-            plan, bin_, [v.astype(jnp.int64) for v in val_arrs]
-        )
-
-    return prog
 
 
 class StreamingAggregator:
@@ -389,10 +370,6 @@ class StreamingAggregator:
             chunk_cols, num_rows = spec
             if num_rows <= 0:
                 return None
-        if slab is not None and not self.build_roots:
-            dense = self._try_dense(slab, num_rows)
-            if dense is not None:
-                return dense
         programs = getattr(self.executor, "programs", None)
         if self.build_roots:
             # the step closes over this query's materialized build
@@ -516,220 +493,6 @@ class StreamingAggregator:
             if meta["slots"]:
                 span.set("slots", meta["slots"])
 
-    def _try_dense(self, slab: Batch, num_rows: int) -> Optional[Result]:
-        """Dense-domain fast path: when the group keys span a small
-        integer domain (from data min/max — the ``BigintGroupByHash``
-        precondition) and every aggregate is a null-free sum/count, the
-        whole slab runs through ONE Pallas MXU binning kernel
-        (ops/dense_groupby.py).  Returns None when ineligible; a run that
-        engaged leaves a ``("dense", plan, ...)`` program in the
-        executor's program cache, which is how ``chip_smoke.py`` sees
-        which way a query went."""
-        import numpy as np
-
-        from trino_tpu.ops import dense_groupby as DG
-
-        agg = self.agg
-        if agg.step != "partial" or not self.nkeys:
-            return None
-        cap = slab.capacity
-        if cap < (1 << 15) or cap & (cap - 1):
-            return None
-        if jax.devices()[0].platform not in ("tpu",):
-            return None
-        # trace filters/projections over the WHOLE resident slab (eager
-        # device compute; no host transfer)
-        from trino_tpu.exec.fragments import FusedUnsupported
-
-        live0 = jnp.arange(cap, dtype=jnp.int32) < num_rows
-        batch = Batch(slab.columns, cap, live0)
-        try:
-            tracer = self._tracer_for(batch, self.params)
-            agg_inputs, specs, string_dicts, keys, key_dicts, sel = (
-                self._chunk_prep(tracer)
-            )
-        except FusedUnsupported:
-            return None
-        if tracer.overflows:
-            return None
-        for spec in specs:
-            if spec.kind not in ("sum", "avg", "count", "count_star", "sum128"):
-                return None
-        for pair in agg_inputs:
-            if pair is None:
-                continue
-            data, valid = pair
-            if valid is not None or getattr(data, "ndim", 1) != 1:
-                return None
-            if not np.issubdtype(np.dtype(data.dtype), np.integer):
-                return None
-        for (kd, kv) in keys:
-            if kv is not None or getattr(kd, "ndim", 1) != 1:
-                return None
-            if not np.issubdtype(np.dtype(kd.dtype), np.integer):
-                return None
-        # key domain from data min/max: ONE device round-trip, kept in the
-        # executor's program store by content (the aggregate, the table's
-        # columns and data version) and by the literals' host values, since
-        # the selection the stats are taken under depends on them: a pull
-        # per variant, never a compile (the programs below take
-        # mins/strides as arguments)
-        programs = getattr(self.executor, "programs", None)
-        scan = self.scan
-        connector = self.executor.catalogs.get(scan.catalog)
-        stats_key = (
-            "dense_stats", self.site,
-            (scan.catalog, scan.schema, scan.table),
-            tuple(scan.column_names),
-            connector.data_version(scan.schema, scan.table),
-            num_rows,
-            getattr(self.executor, "_params", None),
-        )
-        stats = programs.get(stats_key) if programs is not None else None
-        distinct_vals: list = []
-        for pair in agg_inputs:
-            if pair is not None and not any(
-                pair[0] is d for d in distinct_vals
-            ):
-                distinct_vals.append(pair[0])
-        if stats is None:
-            mins, maxs = [], []
-            for kd, _ in keys:
-                mins.append(jnp.min(jnp.where(sel, kd, jnp.iinfo(jnp.int64).max)))
-                maxs.append(jnp.max(jnp.where(sel, kd, jnp.iinfo(jnp.int64).min)))
-            vmins, vmaxs = [], []
-            for d in distinct_vals:
-                vmins.append(jnp.min(jnp.where(sel, d, 0)))
-                vmaxs.append(jnp.max(jnp.where(sel, d, 0)))
-            packed = np.asarray(
-                jnp.stack([jnp.stack(mins + vmins), jnp.stack(maxs + vmaxs)])
-            )
-            stats = (packed[0].tolist(), packed[1].tolist())
-            if programs is not None:
-                programs[stats_key] = stats
-        lo_list, hi_list = stats
-        kmins = lo_list[: len(keys)]
-        kmaxs = hi_list[: len(keys)]
-        vmins = lo_list[len(keys):]
-        vmaxs = hi_list[len(keys):]
-        if any(mx < mn for mn, mx in zip(kmins, kmaxs)):
-            return None  # zero selected rows: let the sort path handle
-        ranges = [int(mx - mn) + 1 for mn, mx in zip(kmins, kmaxs)]
-        g_raw = 1
-        for r in ranges:
-            g_raw *= r
-            if g_raw > 8192:
-                return None
-        G = max(128, ((g_raw + 127) // 128) * 128)
-        # lane plan from value ranges; a column consumed by any sum128
-        # spec gets the exact 128-bit pair output REGARDLESS of sign
-        # (downstream dispatches on the spec kind, not the data range)
-        pair_cols: set = set()
-        for spec, pair in zip(specs, agg_inputs):
-            if spec.kind == "sum128" and pair is not None:
-                for ci, d in enumerate(distinct_vals):
-                    if pair[0] is d:
-                        pair_cols.add(ci)
-        cols, pair128 = [], []
-        for ci, (d, mn, mx) in enumerate(zip(distinct_vals, vmins, vmaxs)):
-            nonneg = mn >= 0
-            bits = max(int(mx).bit_length(), 1) if nonneg else 64
-            cols.append(DG.DenseCol(nonneg=nonneg, bits=bits))
-            pair128.append(ci in pair_cols)
-        plan = DG.DensePlan(G=G, cols=tuple(cols), pair128=tuple(pair128))
-        if plan.m > 4096:
-            return None  # accumulator VMEM budget
-        # row-major key offsets; bins computed INSIDE the jitted program
-        # (one fused program is one dispatch, where eager ops are one
-        # each). mins/strides are dynamic args so one compile serves any
-        # key range of this shape.
-        strides = []
-        acc = 1
-        for r in reversed(ranges):
-            strides.append(acc)
-            acc *= r
-        strides.reverse()
-        nk = len(keys)
-        prog_key = ("dense", plan, cap, nk, len(distinct_vals))
-        fn = programs.get(prog_key) if programs is not None else None
-        if fn is None:
-            fn = jax.jit(dense_program(plan))
-            if programs is not None:
-                programs[prog_key] = fn
-        hi, lo = fn(
-            sel,
-            jnp.asarray(np.asarray(kmins, np.int64)),
-            jnp.asarray(np.asarray(strides, np.int32)),
-            [kd for kd, _ in keys],
-            list(distinct_vals),
-        )
-        # reconstruction runs on DEVICE in a SECOND jit, separate from the
-        # pallas producer: no host round-trip between kernel and result
-        recon_key = ("dense_recon", plan, nk)
-        rfn = programs.get(recon_key) if programs is not None else None
-        if rfn is None:
-            rfn = jax.jit(partial(DG.reconstruct_device, plan))
-            if programs is not None:
-                programs[recon_key] = rfn
-        key_vals, col_sums, counts = rfn(
-            hi, lo,
-            jnp.asarray(np.asarray(kmins, np.int64)),
-            jnp.asarray(np.asarray(strides, np.int64)),
-            jnp.asarray(np.asarray(ranges, np.int64)),
-        )
-        return self._dense_finish(
-            plan, keys, key_dicts, specs, string_dicts, agg_inputs,
-            distinct_vals, key_vals, col_sums, counts,
-        )
-
-    def _dense_finish(self, plan, keys, key_dicts, specs, string_dicts,
-                      agg_inputs, distinct_vals, key_vals, col_sums,
-                      counts) -> Result:
-        """Build the partial-accumulator Result (same wire format as
-        ``_finish_partial``) from device-reconstructed sums."""
-        agg = self.agg
-        G = plan.G
-        live = counts > 0
-        cols: list[Column] = []
-        layout: dict[str, int] = {}
-        for i, ksym in enumerate(agg.group_keys):
-            cols.append(
-                Column(
-                    ksym.type,
-                    key_vals[i].astype(ksym.type.storage_dtype),
-                    live,
-                    key_dicts[i],
-                )
-            )
-            layout[ksym.name] = len(cols) - 1
-
-        def col_index(pair):
-            for ci, d in enumerate(distinct_vals):
-                if pair[0] is d:
-                    return ci
-            raise KeyError
-
-        for (vsym, csym), spec, sdict, pair in zip(
-            agg.acc_symbols, specs, string_dicts, agg_inputs
-        ):
-            if spec.kind in ("count", "count_star"):
-                cols.append(Column(T.BIGINT, counts, None))
-                layout[vsym.name] = len(cols) - 1
-                continue
-            ci = col_index(pair)
-            val = col_sums[ci]
-            if spec.kind != "sum128":
-                if getattr(val, "ndim", 1) == 2:
-                    # column shared with a sum128 spec: the pair's lo
-                    # limb IS the modular int64 sum
-                    val = val[:, 1]
-                val = val.astype(vsym.type.storage_dtype)
-            cols.append(Column(vsym.type, val, None, sdict))
-            layout[vsym.name] = len(cols) - 1
-            cols.append(Column(T.BIGINT, counts, None))
-            layout[csym.name] = len(cols) - 1
-        return Result(Batch(cols, G, live), layout)
-
     def _make_slab_program(self, meta: dict, cap: int, chunk_cols=None):
         """The ENTIRE chunk loop as one compiled program: a
         ``lax.fori_loop`` whose body takes chunk i — dynamic-sliced from
@@ -770,7 +533,7 @@ class StreamingAggregator:
 
         return program
 
-    # === metadata (eager pass over the first chunk) ======================
+    # === metadata (abstract pass over the first chunk) ===================
 
     @staticmethod
     def _with_params(inputs: dict, params) -> dict:
@@ -780,9 +543,9 @@ class StreamingAggregator:
 
     def _tracer_for(self, chunk: Batch, params):
         """The chunk's tracer. ``params`` is the caller's OWN argument: the
-        traced tuple inside a compiled step, this query's device scalars in
-        an eager pass; never ``self.params`` from inside a stored program,
-        which would bake the first query's literals in."""
+        traced tuple inside a compiled step, the abstract one under
+        ``_collect_meta``'s ``eval_shape``; never ``self.params`` from inside
+        a stored program, which would bake the first query's literals in."""
         from trino_tpu.exec.fragments import _FragmentTracer
 
         tracer = _FragmentTracer(

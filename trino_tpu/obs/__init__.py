@@ -11,8 +11,8 @@ exchange transfers) instead of per-operator CPU counters:
   HTTP header. Emission is a no-op unless a sink is registered.
 - :mod:`trino_tpu.obs.metrics` — process-global counters, gauges and
   fixed-bucket histograms (no external deps), rendered in Prometheus
-  text format at ``GET /v1/metrics`` and embedded as JSON snapshots by
-  ``bench.py`` / ``scripts/chaos_smoke.py``.
+  text format at ``GET /v1/metrics`` and embedded as a JSON snapshot by
+  ``scripts/chaos_smoke.py``.
 """
 
 from trino_tpu.obs.metrics import get_registry

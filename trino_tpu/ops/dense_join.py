@@ -31,13 +31,13 @@ the r-th smallest unplaced row id, so matches of one probe row emit in
 ascending build-row order — the same set the sorted tier emits, and the
 exactness pass (``verify_equal``) ANDs out hash collisions identically.
 
-The ``matmul`` tier is the join analog of ``dense_groupby``'s binning:
-when the build key domain bins densely, ``slot_base_binned`` addresses
+The ``matmul`` tier bins instead of hashing: when the build key domain
+is small and dense, ``slot_base_binned`` addresses
 the table by ``key - kmin`` directly (identity binning == perfect
 hashing — zero probe collisions when the domain fits the capacity).
 The per-probe match-count contraction ``counts = onehot(bins) @ hist``
 is MXU-shaped; ``matmul_join_counts`` computes it as a real chunked
-``jnp.dot`` for the bench/join-project path, while the traced tier uses
+``jnp.dot`` for join-project shapes, while the traced tier uses
 the gather lowering of the same contraction (no n×C one-hot resident).
 """
 
@@ -208,8 +208,8 @@ def matmul_join_counts(
 
     ``counts[i] = Σ_g 1[probe_bin_i = g] · hist_g`` — the join-as-matmul
     count kernel for join-project shapes, computed as chunked
-    ``onehot @ hist`` dots exactly like ``dense_groupby``'s binning
-    matmul.  Equal to the gather lowering ``hist[probe_bins]`` (asserted
+    ``onehot @ hist`` dots (a chunk of probe rows against the build
+    histogram at a time).  Equal to the gather lowering ``hist[probe_bins]`` (asserted
     by the unit tests); the traced tier uses the gather form to avoid a
     resident n×domain one-hot.
     """
