@@ -27,8 +27,9 @@ began under. One JSON line:
 - ``largest_ops``: the slice's largest device operations, each with the
   ``op:`` span most of its time began under;
 - ``queries``: per profiled query, ``queryStats`` (``phaseMs``,
-  ``operatorMs``, ``xlaCompiles`` ...) and the operators' own attributes
-  (``attempts``, ``capacities``, ``xlaCompiles``) from its timeline.
+  ``operatorMs``, ``xlaCompiles`` ...), ``ingestStats`` (``table_cache_hits``,
+  ``h2d_bytes``) and the operators' own attributes (``attempts``,
+  ``capacities``, ``tableCacheHit``, ``xlaCompiles``) from its timeline.
 
 Needs the chips the cell asks for (``--platform cpu`` rehearses it, reading
 the host's XLA threads as the device). Nothing here is part of the
@@ -193,6 +194,7 @@ def main(argv=None) -> int:
             "sql_tail": q["query"][-60:], "spans": len(spans),
             **{k: stats.get(k) for k in ("elapsedMs", "queuedMs", "phaseMs", "operatorMs",
                                          "xlaCompiles", "xlaCompileMs", "xlaCacheLoads")},
+            "ingestStats": q.get("ingestStats"),
             "operators": operators,
         })
     head = {"workload": cell["name"], "device": devices[0].device_kind,

@@ -471,3 +471,223 @@ def test_ingest_metrics_and_stats_surface(drunner):
     )
     ing = res.ingest_stats
     assert ing is not None and "h2d_bytes" in ing
+
+
+# === the default session: tables live on the device =====================
+# LocalExecutor's scan hands on a device-resident batch and keeps it
+# across queries in the engine's DeviceTableCache (table_cache=false is
+# the scan as it was: host batches, the cache untouched).
+
+_LOCAL_Q1 = (
+    "select l_returnflag, l_linestatus, sum(l_quantity),"
+    " sum(l_extendedprice * (1 - l_discount) * (1 + l_tax)), count(*)"
+    " from tpch.tiny.lineitem where l_shipdate <= date '1998-09-02'"
+    " group by l_returnflag, l_linestatus order by 1, 2"
+)
+_LOCAL_Q3 = (
+    "select l.l_orderkey, sum(l.l_extendedprice * (1 - l.l_discount)) as"
+    " revenue, o.o_orderdate, o.o_shippriority from tpch.tiny.customer c,"
+    " tpch.tiny.orders o, tpch.tiny.lineitem l where c.c_mktsegment ="
+    " 'BUILDING' and c.c_custkey = o.o_custkey"
+    " and l.l_orderkey = o.o_orderkey and o.o_orderdate < date '1995-03-15'"
+    " and l.l_shipdate > date '1995-03-15' group by l.l_orderkey,"
+    " o.o_orderdate, o.o_shippriority order by revenue desc, o.o_orderdate"
+    " limit 10"
+)
+_HOST = Session(properties={"table_cache": False})
+
+
+@pytest.fixture()
+def lrunner():
+    from trino_tpu.testing import LocalQueryRunner
+
+    return LocalQueryRunner()
+
+
+def _scan_nodes(plan):
+    from trino_tpu.planner import plan as P
+
+    out, stack = [], [plan]
+    while stack:
+        n = stack.pop()
+        if isinstance(n, P.TableScan):
+            out.append(n)
+        stack.extend(n.sources)
+    return out
+
+
+def _traced(runner, sql, session=None):
+    """(StatementResult, the query's op:TableScan spans)."""
+    from trino_tpu.obs.trace import InMemorySpanSink, get_tracer
+
+    sink = InMemorySpanSink()
+    get_tracer().add_sink(sink)
+    try:
+        res = runner.engine.execute_statement(sql, session or runner.session)
+    finally:
+        get_tracer().remove_sink(sink)
+    spans = [s for t in sink.trace_ids() for s in sink.spans_for(t)]
+    return res, [s for s in spans if s["name"] == "op:TableScan"]
+
+
+@pytest.mark.parametrize(
+    "sql,scans", [(_LOCAL_Q1, 1), (_LOCAL_Q3, 3)], ids=["q1", "q3"]
+)
+def test_local_scan_resident_second_run_hits(lrunner, sql, scans):
+    host = lrunner.engine.execute_statement(sql, _HOST)
+    want_bytes = 0
+    for node in _scan_nodes(lrunner.plan(sql)):
+        ex = lrunner.engine._executor(_HOST, None)
+        for c in ex._exec(node).batch.columns:
+            want_bytes += np.asarray(c.data).nbytes
+            want_bytes += 0 if c.valid is None else np.asarray(c.valid).nbytes
+    assert lrunner.engine.table_cache.snapshot()["misses"] == 0
+
+    cold, cold_spans = _traced(lrunner, sql)
+    assert cold.rows == host.rows
+    assert cold.ingest_stats["table_cache_misses"] == scans
+    assert "table_cache_hits" not in cold.ingest_stats
+    assert cold.ingest_stats["h2d_bytes"] == want_bytes > 0
+    assert [s["attrs"]["tableCacheHit"] for s in cold_spans] == [False] * scans
+
+    warm, warm_spans = _traced(lrunner, sql)
+    assert warm.rows == host.rows
+    assert warm.ingest_stats == {"h2d_bytes": 0, "table_cache_hits": scans}
+    assert [s["attrs"]["tableCacheHit"] for s in warm_spans] == [True] * scans
+    snap = lrunner.engine.table_cache.snapshot()
+    assert (snap["entries"], snap["hits"], snap["misses"]) == (scans,) * 3
+    assert snap["bytes"] == want_bytes
+
+
+def test_local_scan_columns_are_device_arrays_with_the_hosts_rows(lrunner):
+    import jax
+
+    (node,) = _scan_nodes(lrunner.plan(_LOCAL_Q1))
+    host = lrunner.engine._executor(_HOST, None)._exec(node).batch
+    for _ in range(2):  # a miss, then the resident batch
+        dev = lrunner.engine._executor(lrunner.session, None)._exec(node).batch
+        assert dev.num_rows == host.num_rows and dev.sel is None
+        for h, d in zip(host.columns, dev.columns):
+            assert isinstance(h.data, np.ndarray)
+            assert isinstance(d.data, jax.Array)
+            assert d.data.dtype == h.data.dtype
+            assert np.array_equal(np.asarray(d.data), h.data)
+            assert (d.valid is None) == (h.valid is None)
+            assert d.valid is None or isinstance(d.valid, jax.Array)
+            assert d.dictionary is h.dictionary  # host objects, shared
+            # a host consumer that writes in place has to copy first
+            assert not np.asarray(d.data).flags.writeable
+
+
+def test_local_scan_write_between_runs_misses_and_reads_new_rows(lrunner):
+    lrunner.execute("create table memory.default.res (k bigint, v bigint)")
+    lrunner.execute("insert into memory.default.res values (1, 10), (2, 20)")
+    sql = (
+        "select count(*), sum(a.v), sum(b.v) from memory.default.res a,"
+        " memory.default.res b where a.k = b.k"
+    )
+    r1 = lrunner.engine.execute_statement(sql, lrunner.session)
+    assert r1.rows == [(2, 30, 30)]
+    r2 = lrunner.engine.execute_statement(sql, lrunner.session)
+    assert r2.rows == r1.rows
+    assert r2.ingest_stats == {"h2d_bytes": 0, "table_cache_hits": 2}
+    # INSERT bumps the connector's version: the first scan misses and reads
+    # the new row, the second is served the batch the first one put
+    lrunner.execute("insert into memory.default.res values (3, 30)")
+    r3 = lrunner.engine.execute_statement(sql, lrunner.session)
+    assert r3.rows == [(3, 60, 60)]
+    assert r3.ingest_stats["table_cache_misses"] == 1
+    assert r3.ingest_stats["table_cache_hits"] == 1
+    assert r3.ingest_stats["h2d_bytes"] > 0
+
+
+def test_local_scan_with_pushed_limit_is_not_admitted(lrunner):
+    sql = "select o_orderkey from tpch.tiny.orders limit 5"
+    (node,) = _scan_nodes(lrunner.plan(sql))
+    assert node.limit == 5
+    for _ in range(2):
+        res, spans = _traced(lrunner, sql)
+        assert len(res.rows) == 5
+        # cut short, so neither looked up nor kept; still one upload a column
+        assert "tableCacheHit" not in spans[0]["attrs"]
+        assert res.ingest_stats["h2d_bytes"] > 0
+        assert "table_cache_misses" not in res.ingest_stats
+    snap = lrunner.engine.table_cache.snapshot()
+    assert (snap["entries"], snap["hits"], snap["misses"]) == (0, 0, 0)
+
+
+def test_local_scan_over_the_byte_budget_is_rejected_and_still_right(lrunner):
+    host = lrunner.engine.execute_statement(_LOCAL_Q1, _HOST)
+    small = Session(properties={"table_cache_max_bytes": 1 << 10})
+    for n in (1, 2):
+        res = lrunner.engine.execute_statement(_LOCAL_Q1, small)
+        assert res.rows == host.rows
+        # not resident, so every query pays the upload again: once a column
+        assert res.ingest_stats["table_cache_misses"] == 1
+        assert res.ingest_stats["h2d_bytes"] > 1 << 10
+        snap = lrunner.engine.table_cache.snapshot()
+        assert (snap["entries"], snap["rejections"]) == (0, n)
+
+
+def test_local_scan_table_cache_off_leaves_the_cache_untouched(lrunner):
+    (node,) = _scan_nodes(lrunner.plan(_LOCAL_Q1))
+    ex = lrunner.engine._executor(_HOST, None)
+    batch = ex._exec(node).batch
+    assert all(isinstance(c.data, np.ndarray) for c in batch.columns)
+    res = lrunner.engine.execute_statement(_LOCAL_Q1, _HOST)
+    assert "h2d_bytes" not in (res.ingest_stats or {})
+    assert lrunner.engine.table_cache.snapshot() == {
+        "entries": 0, "bytes": 0, "hits": 0, "misses": 0,
+        "evictions": 0, "rejections": 0,
+    }
+
+
+def test_local_scan_of_live_state_is_read_anew_every_query(lrunner):
+    # the system tables materialize process state at scan time and have no
+    # snapshot token (supports_result_caching false): never looked up or
+    # kept, so the second read lists the first
+    sql = "select query from system.runtime.queries"
+    r1, s1 = _traced(lrunner, sql)
+    r2, s2 = _traced(lrunner, sql)
+    assert len(r2.rows) == len(r1.rows) + 1
+    for res, spans in ((r1, s1), (r2, s2)):
+        assert "tableCacheHit" not in spans[0]["attrs"]
+        assert "table_cache_misses" not in res.ingest_stats
+        assert res.ingest_stats["h2d_bytes"] > 0  # resident all the same
+    snap = lrunner.engine.table_cache.snapshot()
+    assert (snap["entries"], snap["hits"], snap["misses"]) == (0, 0, 0)
+
+
+@pytest.mark.parametrize("first", ["local", "mesh"])
+def test_local_and_one_device_mesh_scans_do_not_serve_each_other(first):
+    # one engine, one cache, one device: a mesh scan's batch is padded to
+    # its shards' capacity under a selection wherever they are uneven, the
+    # local one is the table's rows as they are; the placement kind in the
+    # key keeps them apart
+    from trino_tpu.testing import DistributedQueryRunner
+
+    runner = DistributedQueryRunner(n_devices=1)
+    sessions = {"mesh": runner.session, "local": Session()}
+    order = [first, "mesh" if first == "local" else "local"]
+    (node,) = _scan_nodes(runner.plan(_LOCAL_Q1))
+    rows = []
+    for n, kind in enumerate(order, start=1):
+        res = runner.engine.execute_statement(_LOCAL_Q1, sessions[kind])
+        rows.append(res.rows)
+        assert res.ingest_stats["table_cache_misses"] == 1
+        assert "table_cache_hits" not in res.ingest_stats
+        assert runner.engine.table_cache.snapshot()["entries"] == n
+    assert rows[0] == rows[1]
+    host = runner.engine._executor(_HOST, None)._exec(node).batch
+    served = {}
+    for kind in order:  # each is served the batch its own scan put
+        ex = runner.engine._executor(sessions[kind], None)
+        served[kind] = batch = ex._exec(node).batch
+        assert ex.ingest_stats["table_cache_hits"] == 1
+        live = np.asarray(batch.selection_mask())
+        assert int(live.sum()) == host.num_rows
+        for h, d in zip(host.columns, batch.columns):
+            assert np.array_equal(np.asarray(d.data)[live], h.data)
+    assert served["local"].sel is None
+    assert served["local"].capacity == host.num_rows
+    assert served["local"].columns[0].data is not served["mesh"].columns[0].data

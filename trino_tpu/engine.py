@@ -1121,12 +1121,12 @@ class Engine:
                 ex = DistributedExecutor(
                     self.catalogs, session, self.mesh, memory_ctx=ctx
                 )
-            # share the engine-wide device table cache (warm repeat scans
-            # skip H2D); the local interpreter keeps host batches, so
-            # only the device-mesh executors get it
-            ex.table_cache = self.table_cache
-            return ex
-        return LocalExecutor(self.catalogs, session, memory_ctx=ctx)
+        else:
+            ex = LocalExecutor(self.catalogs, session, memory_ctx=ctx)
+        # every executor shares the engine-wide device table cache: a warm
+        # repeat scan of an unchanged table decodes and uploads nothing
+        ex.table_cache = self.table_cache
+        return ex
 
     def _run_query_rows(self, query: t.Query, session: Session) -> tuple[Batch, list[str]]:
         plan = self.plan(query, session)

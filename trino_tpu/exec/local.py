@@ -231,8 +231,21 @@ class LocalExecutor:
             node.schema, node.table, 64, node.constraint,
             limit=node.limit, topn=node.topn,
         )
+        layout = {s.name: i for i, s in enumerate(node.symbols)}
         if not splits:
-            return Result(self._empty_batch(node), {s.name: i for i, s in enumerate(node.symbols)})
+            return Result(self._empty_batch(node), layout)
+        # tables live on the device: the scan hands on a device-resident
+        # batch and keeps it across queries in the engine's table cache
+        # (table_cache=false is the host scan: NumPy columns, no cache)
+        resident = bool(self.session.get("table_cache"))
+        # a scan a pushed limit / topn hint may cut short is not the table
+        cache_key = None
+        if resident and node.limit is None and node.topn is None:
+            cache_key, cached = self._cached_scan(
+                node, connector, splits, jax.devices()[0]
+            )
+            if cached is not None:
+                return Result(cached, layout)
         batches = []
         rows_read = 0
         with get_tracer().span(
@@ -250,8 +263,62 @@ class LocalExecutor:
             span.set("splits", len(batches))
             span.set("rows", rows_read)
         batch = concat_batches(batches) if len(batches) > 1 else batches[0]
-        layout = {s.name: i for i, s in enumerate(node.symbols)}
+        if resident:
+            from trino_tpu.ingest import put_batch
+
+            # one upload a column a query even where the cache refuses the
+            # table, not one a use by every eager primitive downstream
+            batch, nbytes = put_batch(batch, self.ingest_stats)
+            self._admit_scan(cache_key, batch, nbytes)
         return Result(batch, layout)
+
+    def _cached_scan(self, node: P.TableScan, connector, splits, placement):
+        """(cache key, the resident batch or None) for a scan of the whole
+        table on ``placement`` (see ``table_cache_key``); counts the hit or
+        miss and marks this scan's ``op:TableScan``. The key is None, and
+        nothing is looked up or later admitted, where the session turns
+        the cache off or the connector's rows are live state with no
+        snapshot token to key on (``supports_result_caching`` false: the
+        system tables), which every scan has to read anew."""
+        if (
+            self.table_cache is None
+            or not self.session.get("table_cache")
+            or not getattr(connector, "supports_result_caching", True)
+        ):
+            return None, None
+        from trino_tpu.ingest import table_cache_key
+
+        stats = self.ingest_stats
+        stats.setdefault("h2d_bytes", 0)
+        key = table_cache_key(
+            node.catalog,
+            node.schema,
+            node.table,
+            connector.data_version(node.schema, node.table),
+            node.column_names,
+            splits,
+            placement,
+        )
+        cached = self.table_cache.lookup(key)
+        span = get_tracer().current()  # this scan's op:TableScan
+        if span is not None:
+            span.set("tableCacheHit", cached is not None)
+        counter = "table_cache_misses" if cached is None else "table_cache_hits"
+        stats[counter] = stats.get(counter, 0) + 1
+        return key, cached
+
+    def _admit_scan(self, key, batch: Batch, nbytes: int, peak_hbm_hint: int = 0):
+        """Keep a missed scan's device batch for the next query, if
+        ``_cached_scan`` gave it a key and the byte budget and the HBM
+        headroom allow."""
+        if key is not None:
+            self.table_cache.admit(
+                key,
+                batch,
+                nbytes,
+                max_bytes=int(self.session.get("table_cache_max_bytes")),
+                peak_hbm_hint=peak_hbm_hint,
+            )
 
     def _empty_batch(self, node: P.TableScan) -> Batch:
         cols = [

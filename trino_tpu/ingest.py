@@ -54,7 +54,7 @@ from trino_tpu.parallel.mesh import (
     row_sharding,
     smap,
 )
-from jax.sharding import PartitionSpec as PS
+from jax.sharding import Mesh, PartitionSpec as PS
 
 # === arena layout ===========================================================
 #
@@ -294,6 +294,41 @@ def shard_batch_coalesced(
         cols.append(Column(t, data_g, valid_g, d))
     sel = None if sels is None else results[("sel",)]
     return Batch(cols, cap * n, sel)
+
+
+def put_batch(batch: Batch, stats: Optional[dict] = None) -> tuple[Batch, int]:
+    """A host batch on the default device, one ``jax.device_put`` a column
+    (data, validity, selection); dictionaries stay host objects. Returns
+    (device batch, bytes put), the bytes counted into ``stats["h2d_bytes"]``
+    as a sharded scan's are. The single-device counterpart of
+    :func:`shard_batch_coalesced`: nothing to coalesce, since a resident
+    table pays this once."""
+    from trino_tpu.obs.metrics import get_registry
+    from trino_tpu.obs.trace import get_tracer
+
+    nbytes, bufs = _batch_buffer_bytes([batch])
+    if batch.sel is not None:
+        nbytes += np.asarray(batch.sel).nbytes
+        bufs += 1
+    with get_tracer().span(
+        "ingest.h2d", attrs={"bytes": nbytes, "transfers": bufs}
+    ):
+        cols = [
+            Column(
+                c.type,
+                jax.device_put(c.data),
+                None if c.valid is None else jax.device_put(c.valid),
+                c.dictionary,
+            )
+            for c in batch.columns
+        ]
+        sel = None if batch.sel is None else jax.device_put(batch.sel)
+        jax.block_until_ready((cols, sel))  # the span holds the transfers
+    get_registry().counter("trino_tpu_ingest_h2d_bytes_total").inc(nbytes)
+    if stats is not None:
+        stats["h2d_bytes"] = stats.get("h2d_bytes", 0) + nbytes
+        stats["h2d_transfers"] = stats.get("h2d_transfers", 0) + bufs
+    return Batch(cols, batch.num_rows, sel), nbytes
 
 
 # === double-buffered split decode ===========================================
@@ -538,9 +573,16 @@ def table_cache_key(
     version: Any,
     column_names: Iterable[str],
     splits: Sequence,
-    mesh,
+    placement,
 ) -> tuple:
-    mesh_fp = tuple(str(d) for d in mesh.devices.flat)
+    """``placement``: where the batch lives and in what shape: the mesh a
+    sharded scan spreads it over (capacity-padded, with a selection), or
+    the local executor's one device (the table's rows as they are). The
+    two never serve each other, on a one-device mesh either."""
+    if isinstance(placement, Mesh):
+        mesh_fp = ("mesh", *(str(d) for d in placement.devices.flat))
+    else:
+        mesh_fp = ("local", str(placement))
     return (
         catalog,
         schema,

@@ -200,29 +200,9 @@ class DistributedExecutor(LocalExecutor):
 
         # device table cache: a warm repeat scan of an unchanged table
         # returns the HBM-resident batch — zero decode, zero H2D
-        cache_key = None
-        if self.table_cache is not None and self.session.get("table_cache"):
-            from trino_tpu.ingest import table_cache_key
-
-            cache_key = table_cache_key(
-                node.catalog,
-                node.schema,
-                node.table,
-                connector.data_version(node.schema, node.table),
-                node.column_names,
-                splits,
-                self.mesh,
-            )
-            cached = self.table_cache.lookup(cache_key)
-            span = get_tracer().current()  # this scan's op:TableScan
-            if span is not None:
-                span.set("tableCacheHit", cached is not None)
-            if cached is not None:
-                stats["table_cache_hits"] = stats.get("table_cache_hits", 0) + 1
-                return Result(cached, layout)
-            stats["table_cache_misses"] = (
-                stats.get("table_cache_misses", 0) + 1
-            )
+        cache_key, cached = self._cached_scan(node, connector, splits, self.mesh)
+        if cached is not None:
+            return Result(cached, layout)
 
         per_shard: list[list[Batch]] = [[] for _ in range(n)]
         with get_tracer().span(
@@ -278,13 +258,7 @@ class DistributedExecutor(LocalExecutor):
                 ),
                 default=0,
             )
-            self.table_cache.admit(
-                cache_key,
-                batch,
-                batch_nbytes(batch),
-                max_bytes=int(self.session.get("table_cache_max_bytes")),
-                peak_hbm_hint=peak_hint,
-            )
+            self._admit_scan(cache_key, batch, batch_nbytes(batch), peak_hint)
         return Result(batch, layout)
 
     # === partial/final aggregation ======================================
