@@ -26,9 +26,9 @@ from benchmark import compare, harness, reference  # noqa: E402
 
 
 def control_verdict(root: str, workload: str, seed: int, queries: int, cache: bool = False) -> dict:
-    _, _, _, config, mix = harness.load_cell(root, workload)
-    columns = harness.reference_columns(root, config, mix, cache)
-    refs = {p: reference.Reference(columns, p) for p in ("exact", "float32")}
+    _, data_root, _, config, mix = harness.load_cell(root, workload)
+    columns = harness.reference_columns(root, data_root, config, mix, cache)
+    refs = {p: reference.Reference(columns, p, data_root) for p in ("exact", "float32")}
 
     def answer(name, params, precision):
         return refs[precision].answer(mix.templates[name].meta["reference"], params)

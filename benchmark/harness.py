@@ -3,8 +3,11 @@
 Everything that belongs to one configuration, one traffic mix or one
 per-layer metric is a file of its own, found by the name ``BENCHMARK.json``
 gives: ``configs/<config>.json`` (the entry's ``file``), ``traffic/<mix>.json``
-with ``templates/<t>.sql|json``, ``metrics/<metric>.py``. A later PR adds a
-cell by adding such files and an entry; nothing here names a cell.
+with ``templates/<t>.sql|json``, ``metrics/<metric>.py``; and on the side that
+decides ``correct``, ``references/<name>.py`` (a template's ``"reference"``) and
+the column providers of ``datasets/<dataset>/`` (a configuration's
+``"dataset"``). A later PR adds a cell by adding such files and an entry;
+nothing here names a cell, a query, a table or a column.
 
 The system under test is the served SQL path and nothing else of the
 program: ``TrinoTpuServer(port=0)`` in this process, and one
@@ -17,7 +20,6 @@ from __future__ import annotations
 
 import argparse
 import contextlib
-import importlib.util
 import json
 import os
 import shutil
@@ -27,6 +29,7 @@ import time
 import traceback
 
 from benchmark import compare, reference, refdata, stats, tracereduce, traffic
+from benchmark.files import Refused, load_module
 
 #: a traced run's window is the traced slice: it ends with the first answer
 #: that comes this long after t0 (or after --seconds, where that is less) ...
@@ -34,10 +37,6 @@ SLICE_MIN_S = 3.0
 #: ... and the profiler stops here, in the middle of a query, where one query
 #: is longer (the query in flight then ends the window)
 SLICE_MAX_S = 10.0
-
-
-class Refused(Exception):
-    """The run cannot be made here: no result line, exit code 2."""
 
 
 def log(message: str) -> None:
@@ -66,10 +65,7 @@ def applies(metric: dict, cell: str) -> bool:
 
 def load_reader(data_root: str, name: str):
     path = os.path.join(data_root, "metrics", name + ".py")
-    spec = importlib.util.spec_from_file_location(f"benchmark_metric_{name}", path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module.read
+    return load_module(path, f"reader of the metric {name!r}").read
 
 
 @contextlib.contextmanager
@@ -136,20 +132,35 @@ class Tracer:
 
 
 def load_cell(root: str, workload: str):
-    """What ``BENCHMARK.json`` and the files it names say of one cell."""
+    """What ``BENCHMARK.json`` and the files it names say of one cell. A
+    template whose reference function is not there, or that reads a column
+    with no provider (or two), is refused here, before anything boots."""
     bench = traffic.load_json(os.path.join(root, "BENCHMARK.json"))
     data_root = os.path.join(root, bench["paths"][0])
     cell = find(bench["workloads"], workload, "workload")
     config_entry = find(bench["configs"], cell["config"], "config")
     config = traffic.load_json(os.path.join(root, config_entry["file"]))
-    return bench, data_root, cell, config, traffic.Mix(data_root, cell["traffic"])
+    mix = traffic.Mix(data_root, cell["traffic"])
+    for template in mix.templates.values():
+        reference.load_function(data_root, template.meta["reference"])
+    dataset, reads = dataset_and_reads(data_root, config, mix)
+    dataset.check(reads)
+    return bench, data_root, cell, config, mix
 
 
-def reference_columns(root: str, config: dict, mix, cache: bool = True):
-    """The reference's columns of the tables the mix's templates read."""
-    tables = sorted({t for tpl in mix.templates.values() for t in tpl.meta["reads"]})
+def dataset_and_reads(data_root: str, config: dict, mix):
+    """The configuration's data set and the columns the mix's templates read."""
+    if "dataset" not in config:
+        raise Refused(f"the configuration {config.get('name')!r} names no \"dataset\"")
+    return (refdata.Dataset(data_root, config["dataset"]),
+            refdata.union_of_reads(mix.templates.values()))
+
+
+def reference_columns(root: str, data_root: str, config: dict, mix, cache: bool = True):
+    """The reference's columns that the mix's templates read."""
     cache_dir = os.path.join(root, ".cache", "benchmark") if cache else None
-    return refdata.load(config["scale_factor"], tables, cache_dir)
+    dataset, reads = dataset_and_reads(data_root, config, mix)
+    return dataset.load(config["scale_factor"], reads, cache_dir)
 
 
 def run_cell(args, *, root: str, platform: str, started: float) -> dict:
@@ -262,7 +273,7 @@ def run_cell(args, *, root: str, platform: str, started: float) -> dict:
 
     # the reference, once the window has closed and the peak has been read
     a = time.perf_counter()
-    ref = reference.Reference(reference_columns(root, config, mix))
+    ref = reference.Reference(reference_columns(root, data_root, config, mix), data_root=data_root)
 
     def answers(name, params):
         return ref.answer(mix.templates[name].meta["reference"], params)

@@ -1,12 +1,18 @@
-"""The plain reference: each query template, over the reference's own columns.
+"""The plain reference: what every query's reference function shares.
 
-Straightforward NumPy and Python integers, one function per template, named
-in the template's metadata (``templates/<t>.json``: ``"reference"``). It
-imports nothing of the program and reads only ``refdata``'s columns and the
-execution's substitution parameters. DECIMAL arithmetic is exact: values are
-scaled integers, sums are int64 (the largest, SF1 Q1's ``sum_charge`` at
-scale 6, is 5.6e16, under 2^63), and an average is the exact quotient rounded
-half up to the column's scale, as SQL's DECIMAL division rounds.
+A template names its reference function (``templates/<t>.json``:
+``"reference": "<name>"``), and ``references/<name>.py`` holds it:
+
+    def answer(tables, params, precision="exact", kept=None)
+        -> {"rows": [...], "tie_rows": [...]}
+
+straightforward NumPy and Python integers over the reference's own columns
+(``refdata.Tables``) and the execution's substitution parameters, importing
+nothing of the program. A later PR brings a query's reference by adding such
+a file; no query is named here. DECIMAL arithmetic is exact: values are
+scaled integers, sums are Python integers past int64's reach, and an average
+is the exact quotient rounded half up to the column's scale, as SQL's
+DECIMAL division rounds.
 
 ``precision="float32"`` is the control (see ``compare.py``): the same
 queries with money arithmetic and sums in float32, the step a later PR on a
@@ -18,11 +24,12 @@ from __future__ import annotations
 
 import datetime
 import json
+import os
 from decimal import Decimal
 
 import numpy as np
 
-from benchmark import refdata
+from benchmark.files import Refused, load_module
 
 EPOCH = datetime.date(1992, 1, 1)
 
@@ -53,6 +60,10 @@ def div_half_up(num: int, den: int) -> int:
     return (2 * num + den) // (2 * den)
 
 
+#: rows a block of an exact grouped sum holds at the most
+GROUPED_BLOCK = 1 << 24
+
+
 class Arithmetic:
     """Exact int64 sums, or the control's float32 ones."""
 
@@ -70,128 +81,39 @@ class Arithmetic:
         return int(np.rint(np.float64(a.sum(dtype=np.float32))))
 
     def grouped(self, a: np.ndarray, group: np.ndarray, n: int) -> list[int]:
-        """Sums of ``a`` by ``group`` in 0..n-1. The exact ones go through
-        ``np.bincount``, whose float64 weights hold a whole number under 2^53:
-        each value is split at 2^24, so over 2^24 rows or fewer the low parts
-        sum to under 2^48 and the high parts (of values under 2^48) likewise."""
+        """Sums of ``a`` by ``group`` in 0..n-1, over any number of rows. The
+        exact ones go through ``np.bincount``, whose float64 weights hold a
+        whole number under 2^53: each value is split at 2^24, so over a block
+        of 2^24 rows or fewer the low parts sum to under 2^48 and the high
+        parts (of values under 2^48) likewise; the blocks' sums are added as
+        Python integers."""
         if self.exact:
-            if len(a) > 1 << 24 or (len(a) and (int(a.max()) >= 1 << 48 or int(a.min()) < 0)):
-                raise ValueError("exact grouped sum: rows or values out of range")
-            low = np.bincount(group, weights=a & 0xFFFFFF, minlength=n)
-            high = np.bincount(group, weights=a >> 24, minlength=n)
-            return [(int(h) << 24) + int(lo) for h, lo in zip(high, low)]
+            if len(a) and (int(a.max()) >= 1 << 48 or int(a.min()) < 0):
+                raise ValueError("exact grouped sum: values out of range")
+            sums = [0] * n
+            for first in range(0, len(a), GROUPED_BLOCK):
+                part, g = a[first:first + GROUPED_BLOCK], group[first:first + GROUPED_BLOCK]
+                low = np.bincount(g, weights=part & 0xFFFFFF, minlength=n)
+                high = np.bincount(g, weights=part >> 24, minlength=n)
+                for i in range(n):
+                    sums[i] += (int(high[i]) << 24) + int(low[i])
+            return sums
         out = np.zeros(n, dtype=np.float32)
         np.add.at(out, group, a)
         return [int(np.rint(np.float64(v))) for v in out]
 
 
-def _q1_values(li, ar: "Arithmetic") -> dict:
-    """What Q1 sums, for every line: no parameter changes these."""
-    price = ar.values(li["l_extendedprice"])
-    disc = ar.values(li["l_discount"])
-    disc_price = price * (100 - disc)  # scale 4
-    return {
-        "qty": ar.values(li["l_quantity"]), "price": price, "disc": disc,
-        "disc_price": disc_price,
-        "charge": disc_price * (100 + ar.values(li["l_tax"])),  # scale 6
-    }
+#: where the committed reference functions are: beside this file
+DATA_ROOT = os.path.dirname(os.path.abspath(__file__))
 
 
-def q1(tables, params, precision="exact", kept=None):
-    """TPC-H Q1 (2.4.1): pricing summary of lines shipped by 1998-12-01
-    less DELTA days, by return flag and line status, in that order."""
-    li = tables["lineitem"]
-    ar = Arithmetic(precision)
-    n_groups = len(refdata.RETURNFLAG) * len(refdata.LINESTATUS)
-    keep = li["l_shipdate"] <= days("1998-12-01") - int(params["DELTA"])
-    # a line the filter drops goes to a group of its own, past the real ones
-    group = np.where(keep, li["l_returnflag"].astype(np.int64) * 2 + li["l_linestatus"], n_groups)
-    kept = {} if kept is None else kept
-    if "q1" not in kept:
-        kept["q1"] = _q1_values(li, ar)
-    count = np.bincount(group, minlength=n_groups + 1)
-    sums = {name: ar.grouped(v, group, n_groups + 1) for name, v in kept["q1"].items()}
-    rows = []
-    for g in range(n_groups):
-        n = int(count[g])
-        if n == 0:
-            continue
-        rows.append((
-            refdata.RETURNFLAG[g // 2], refdata.LINESTATUS[g % 2],
-            dec(sums["qty"][g], 2), dec(sums["price"][g], 2),
-            dec(sums["disc_price"][g], 4), dec(sums["charge"][g], 6),
-            dec(div_half_up(sums["qty"][g], n), 2),
-            dec(div_half_up(sums["price"][g], n), 2),
-            dec(div_half_up(sums["disc"][g], n), 2),
-            n,
-        ))
-    rows.sort(key=lambda r: (r[0], r[1]))
-    return {"rows": rows, "tie_rows": []}
-
-
-def q6(tables, params, precision="exact", kept=None):
-    """TPC-H Q6 (2.4.6): revenue change forecast over one year of lines with
-    DISCOUNT +- 0.01 and quantity under QUANTITY."""
-    li = tables["lineitem"]
-    ar = Arithmetic(precision)
-    lo = days(params["DATE"])
-    hi = days(add_years(params["DATE"], 1))
-    d = scaled(params["DISCOUNT"], 2)
-    keep = (
-        (li["l_shipdate"] >= lo) & (li["l_shipdate"] < hi)
-        & (li["l_discount"] >= d - 1) & (li["l_discount"] <= d + 1)
-        & (li["l_quantity"] < scaled(params["QUANTITY"], 2))
-    )
-    if not keep.any():
-        return {"rows": [(None,)], "tie_rows": []}
-    revenue = ar.total(
-        ar.values(li["l_extendedprice"][keep]) * ar.values(li["l_discount"][keep])
-    )
-    return {"rows": [(dec(revenue, 4),)], "tie_rows": []}
-
-
-def q3(tables, params, precision="exact", kept=None):
-    """TPC-H Q3 (2.4.3): the 10 unshipped orders of highest revenue of one
-    market segment, ordered by revenue descending, then order date.
-
-    Rows that tie with the tenth on both sort keys are returned apart, under
-    ``tie_rows``: SQL leaves the choice among them open."""
-    li, orders, cust = tables["lineitem"], tables["orders"], tables["customer"]
-    ar = Arithmetic(precision)
-    date = days(params["DATE"])
-    segment = refdata.SEGMENTS.index(params["SEGMENT"])
-    in_segment = np.zeros(int(cust["c_custkey"].max()) + 1, dtype=bool)
-    in_segment[cust["c_custkey"][cust["c_mktsegment"] == segment]] = True
-    order_ok = (orders["o_orderdate"] < date) & in_segment[orders["o_custkey"]]
-    # orders come sorted by key, and every line's order exists
-    line = np.nonzero(li["l_shipdate"] > date)[0]
-    pos = np.searchsorted(orders["o_orderkey"], li["l_orderkey"][line])
-    joined = order_ok[pos]
-    line, pos = line[joined], pos[joined]
-    value = ar.values(li["l_extendedprice"][line]) * (100 - ar.values(li["l_discount"][line]))
-    order_pos, group = np.unique(pos, return_inverse=True)
-    revenue = ar.grouped(value, group, len(order_pos))
-    found = sorted(
-        (-revenue[i], int(orders["o_orderdate"][p]), int(orders["o_orderkey"][p]),
-         int(orders["o_shippriority"][p]))
-        for i, p in enumerate(order_pos)
-    )
-
-    def row(f):
-        return (f[2], dec(-f[0], 4), iso(f[1]), f[3])
-
-    limit = int(params.get("LIMIT", 10))
-    top = found[:limit]
-    ties = []
-    if len(found) > limit:
-        last = top[-1][:2]
-        ties = [row(f) for f in found if f[:2] == last]
-        if len(ties) == sum(1 for f in top if f[:2] == last):
-            ties = []
-    return {"rows": [row(f) for f in top], "tie_rows": ties}
-
-
-FUNCTIONS = {"q1": q1, "q3": q3, "q6": q6}
+def load_function(data_root: str, name: str):
+    """``answer`` of ``references/<name>.py``; a ``Refused`` where it is not."""
+    path = os.path.join(data_root, "references", name + ".py")
+    answer = getattr(load_module(path, f"reference function {name!r}"), "answer", None)
+    if not callable(answer):
+        raise Refused(f"{path} is no reference function: it needs answer()")
+    return answer
 
 
 class Reference:
@@ -199,14 +121,18 @@ class Reference:
     answer it has given, and what a template computes alike for every set of
     parameters (``kept``), so a window's answers cost less than the window."""
 
-    def __init__(self, tables, precision: str = "exact"):
+    def __init__(self, tables, precision: str = "exact", data_root: str = DATA_ROOT):
         self.tables = tables
         self.precision = precision
+        self.data_root = data_root
+        self.functions: dict = {}
         self.kept: dict = {}
         self.answers: dict[str, dict] = {}
 
     def answer(self, name: str, params: dict) -> dict:
         key = json.dumps([name, params], sort_keys=True)
         if key not in self.answers:
-            self.answers[key] = FUNCTIONS[name](self.tables, params, self.precision, self.kept)
+            if name not in self.functions:
+                self.functions[name] = load_function(self.data_root, name)
+            self.answers[key] = self.functions[name](self.tables, params, self.precision, self.kept)
         return self.answers[key]

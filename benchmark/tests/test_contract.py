@@ -37,6 +37,11 @@ def test_configs():
         assert held["name"] == c["name"] and held["source"] == c["source"]
         assert sorted(held["reduced"]) == sorted(c["reduced"])
         assert held["guarantees"] and "assumed" in held
+        # the data set whose providers make the reference's columns, and the
+        # rows of every table of it, so that any template's scan can be counted
+        assert os.path.isdir(os.path.join(REPO, "benchmark", "datasets", held["dataset"]))
+        assert set(held["rows"]) == {"lineitem", "orders", "customer", "supplier", "part",
+                                     "partsupp", "nation", "region"}
         assert any(w["config"] == c["name"] for w in BENCH["workloads"])
     assert len({c["source"] for c in BENCH["configs"]}) == len(BENCH["configs"])
 

@@ -32,7 +32,7 @@ def run(root, capsys, workload, seed=3, seconds=1.0, trace=0):
 
 @pytest.mark.parametrize(
     "workload", ["q1-tiny-compiled", "q6-tiny-compiled", "q3-tiny-compiled",
-                 "q1-tiny-default", "mixed-tiny-compiled"])
+                 "q1-tiny-default", "mixed-tiny-compiled", "lines-tiny-default"])
 def test_cell_end_to_end(tiny_root, capsys, workload):
     result, err = run(tiny_root, capsys, workload, seed=2**31 + 12345)
     assert list(result)[:5] == ["correct", "attempted", "failed", "metrics", "device"]
