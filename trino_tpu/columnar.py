@@ -337,12 +337,16 @@ class Batch:
         out_cols = []
         for c in b.columns:
             data, valid = c.to_numpy()
-            col = [
-                c.type.to_python(data[i], c.dictionary) if valid[i] else None
-                for i in range(b.num_rows)
-            ]
+            valid = valid[: b.num_rows]
+            if valid.all():
+                col = c.type.to_python_list(data[: b.num_rows], c.dictionary)
+            else:
+                col = [
+                    c.type.to_python(data[i], c.dictionary) if valid[i] else None
+                    for i in range(b.num_rows)
+                ]
             out_cols.append(col)
-        return [tuple(col[i] for col in out_cols) for i in range(b.num_rows)]
+        return list(zip(*out_cols)) if out_cols else [()] * b.num_rows
 
     @staticmethod
     def from_pylist(schema: Sequence[tuple[str, T.SqlType]], rows: Sequence[Sequence[Any]]):

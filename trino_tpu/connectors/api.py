@@ -367,5 +367,9 @@ def register_catalog_spec(manager: CatalogManager, spec: str) -> None:
         from trino_tpu.connectors.tpch import TpchConnector
 
         manager.register(name, TpchConnector())
+    elif kind == "h2o":
+        from trino_tpu.connectors.h2o import H2oConnector
+
+        manager.register(name, H2oConnector())
     else:
         raise ValueError(f"unknown catalog kind in spec: {spec!r}")

@@ -93,6 +93,7 @@ class Engine:
 
         if catalogs is None:
             from trino_tpu.connectors.blackhole import BlackHoleConnector
+            from trino_tpu.connectors.h2o import H2oConnector
             from trino_tpu.connectors.memory import MemoryConnector
             from trino_tpu.connectors.tpch import TpchConnector
 
@@ -101,6 +102,7 @@ class Engine:
             catalogs = CatalogManager()
             catalogs.register("tpch", TpchConnector())
             catalogs.register("tpcds", TpcdsConnector())
+            catalogs.register("h2o", H2oConnector())
             catalogs.register("memory", MemoryConnector())
             catalogs.register("blackhole", BlackHoleConnector())
         self.catalogs = catalogs

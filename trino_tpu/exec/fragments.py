@@ -187,16 +187,31 @@ def _is_resource_exhausted(e: BaseException) -> bool:
     return any(m in msg for m in _RESOURCE_ERROR_MARKERS)
 
 
-def grow_or_raise(name: str, caps: "_Caps") -> None:
+def grow_or_raise(name: str, caps: "_Caps", need: int = 0) -> None:
     """Dispatch one fired traced flag: capacity names grow for a retry;
     ``err!<message>`` names are data-dependent runtime ERRORS discovered
     inside a compiled program (e.g. a scalar subquery returning multiple
-    rows) and fail the query."""
+    rows) and fail the query. ``need`` is the flag's own value: a group
+    budget's flag carries the groups its program counted (``need_flag``),
+    so the budget grows to hold them at once where that is more than the
+    next rung; every other flag is 1."""
     if name.startswith("err!"):
         raise ExecutionError(name[4:])
     # spill/hot tiers are deliberately small (the cold bucket absorbs the
     # common case), so when they do overflow, converge in few retries
-    caps.grow(name, 4 if name.startswith(("agg", "spill", "hot")) else 2)
+    caps.grow(name, 4 if name.startswith(("agg", "spill", "hot")) else 2, need)
+    if name.startswith("agg"):
+        span = get_tracer().current()
+        if span is not None:
+            span.add("groupBudgetGrowths")
+
+
+def need_flag(overflow, num_groups):
+    """A group budget's overflow flag (traced): 0 while the groups fit, else
+    how many the program counted, which ``grow_or_raise`` grows to. A count
+    made after an earlier budget dropped groups is a lower bound, and the
+    ladder goes on from there."""
+    return jnp.where(overflow, num_groups, 0).astype(jnp.int32)
 
 
 def query_fusable(sub: SubPlan) -> bool:
@@ -330,12 +345,14 @@ class _Caps:
         fl = self._seed_floor.get(name)
         return (fl[0], fl[1]) if fl is not None else None
 
-    def grow(self, name: str, factor: int = 2) -> None:
+    def grow(self, name: str, factor: int = 2, need: int = 0) -> None:
         # quantize growth to power-of-two buckets: stats-seeded odd-sized
         # caps would otherwise walk a per-query ladder of unique shapes,
         # and every distinct capacity signature is a separate traced
         # program in the cross-query store
-        self.vals[name] = bucket_capacity(self.vals[name] * factor, minimum=1)
+        self.vals[name] = bucket_capacity(
+            max(self.vals[name] * factor, need), minimum=1
+        )
         prev = self.provenance.get(name, "default")
         if not prev.endswith("+grown"):
             self.provenance[name] = prev + "+grown"
@@ -1051,12 +1068,18 @@ class FragmentedExecutor(DistributedExecutor):
             flag_vals = extra_vals[: len(deferred)]
             counter_vals = list(extra_vals[len(deferred):])
             overflowed = False
+            span = get_tracer().current()
+            if span is not None and any(
+                nm.startswith("agg") for _, names, _, _ in deferred for nm in names
+            ):
+                # one more run of every grouped aggregate of the plan
+                span.add("aggAttempts")
             for (key, names, _, caps), seg in zip(deferred, flag_vals):
                 seg = np.atleast_1d(np.asarray(seg))
                 for nm, fl in zip(names, seg):
                     if fl:
                         overflowed = True
-                        grow_or_raise(nm, caps)
+                        grow_or_raise(nm, caps, int(fl))
                 # the overflowed program stays in the store: its key
                 # carries the capacity signature it was traced at, so the
                 # grown rerun traces fresh while a later same-sized query
@@ -1515,8 +1538,8 @@ class FragmentedExecutor(DistributedExecutor):
                 ).run()
                 break
             except StreamOverflow as e:
-                for nm in e.names:
-                    grow_or_raise(nm, caps)
+                for nm, need in e.needs.items():
+                    grow_or_raise(nm, caps, need)
         if isinstance(frag.root, P.Output):
             names_holder[frag.id] = list(frag.root.column_names)
             cols = [res.column(s) for s in frag.root.symbols]
@@ -1524,7 +1547,9 @@ class FragmentedExecutor(DistributedExecutor):
                 Batch(cols, res.batch.capacity, res.batch.sel),
                 {s.name: i for i, s in enumerate(frag.root.symbols)},
             )
-        if frag.output_exchange in (None, "single"):
+        if frag.output_exchange in (None, "single") or self.mesh.devices.size == 1:
+            # (one device: the exchange is the identity, see
+            # ``apply_output_exchange``)
             return res
         # apply the fragment's output exchange as its own small program
 
@@ -1737,7 +1762,7 @@ class FragmentedExecutor(DistributedExecutor):
             # scalar transfer pays the full runtime round-trip latency
             if flags:
                 stacked = jnp.stack([jnp.reshape(f, ()) for f in flags])
-                flags_np = [bool(x) for x in np.asarray(stacked)]
+                flags_np = [int(x) for x in np.asarray(stacked)]
             else:
                 flags_np = []
             if stats_sink is not None:
@@ -1768,7 +1793,7 @@ class FragmentedExecutor(DistributedExecutor):
             self.exchange_stats["overflow_retries"] += 1
             for nm, f in zip(meta.overflow_names, flags_np):
                 if f:
-                    grow_or_raise(nm, caps)
+                    grow_or_raise(nm, caps, f)
         if meta.batch_size:
             # batched program: data/sel are tuples over the K members —
             # demux into one Result per member (all members share the
@@ -2120,7 +2145,7 @@ class FragmentedExecutor(DistributedExecutor):
                 for nm, fl in zip(names, seg):
                     if fl:
                         overflowed = True
-                        grow_or_raise(nm, caps)
+                        grow_or_raise(nm, caps, int(fl))
             if not overflowed:
                 for names, stacked, static in dcounters:
                     vals = (
@@ -2840,7 +2865,7 @@ class _FragmentTracer(DistributedExecutor):
                 outs.append(v)
                 if c is not None:
                     outs.append(c)
-            ovf_any = jax.lax.pmax(ovf.astype(jnp.int32), AXIS)
+            ovf_any = jax.lax.pmax(need_flag(ovf, ng), AXIS)
             return tuple(outs), live, ovf_any
 
         # outputs: keys*2 + per agg (1 for count kinds, else value+count)
@@ -3036,7 +3061,7 @@ class _FragmentTracer(DistributedExecutor):
                 outs.extend([kd[i2], kv[i2]])
             for r in raw:
                 outs.append(r[0])  # all combine kinds return (value, cnt)
-            ovf_any = jax.lax.pmax(ovf.astype(jnp.int32), AXIS)
+            ovf_any = jax.lax.pmax(need_flag(ovf, ng), AXIS)
             return tuple(outs), live, ovf_any
 
         n_out = 2 * nkeys + len(combine_specs)
@@ -3101,7 +3126,7 @@ class _FragmentTracer(DistributedExecutor):
         key_cols = [res.column(k) for k in node.group_keys]
         G = self.caps.get(f"agg{id(node)}", 1 << 12)
         (kd, kv), raw, ng, ovf = group_aggregate(keys, sel, agg_inputs, specs, G)
-        self.overflows.append((f"agg{id(node)}", ovf.astype(jnp.int32)))
+        self.overflows.append((f"agg{id(node)}", need_flag(ovf, ng)))
         live = jnp.arange(G) < ng
         cols = []
         for i, (ksym, kc) in enumerate(zip(node.group_keys, key_cols)):
@@ -3496,6 +3521,11 @@ class _FragmentTracer(DistributedExecutor):
     def apply_output_exchange(self, frag: PlanFragment, res: Result) -> Result:
         if frag.output_exchange in (None, "single"):
             return res  # SPMD consumers read global arrays directly
+        if self.n == 1 and not self.skew:
+            # one device: every row already is where its key's hash sends
+            # it, and a repartition would sort and scatter every lane (and
+            # climb a bucket and a spill ladder of its own) to say so
+            return res
         b = res.batch
         sel = b.selection_mask()
         # flatten columns into 1-D lane arrays (wide DECIMAL columns ship

@@ -670,7 +670,7 @@ class LocalExecutor:
         which way the rows were grouped: the batch is whole here, so its
         dictionaries are final and their lengths are the keys' domains."""
         max_groups = 1 << 12
-        attempts = 1
+        capacities = [max_groups]
         key_domains = key_domains_from(keys, key_dicts)
         slots = domain_slots(keys, agg_inputs, specs, max_groups, key_domains)
         while True:
@@ -679,15 +679,22 @@ class LocalExecutor:
             )
             if not bool(overflow):
                 break
-            max_groups <<= 2
-            attempts += 1
+            # the run counted its groups: room for them at once, where that
+            # is more than the next rung
+            max_groups = bucket_capacity(max(max_groups << 2, int(ng)))
+            capacities.append(max_groups)
             if max_groups > (1 << 26):
                 raise ExecutionError("group-by cardinality too large")
         span = get_tracer().current()
         if span is not None:
             span.set("groupBy", "domain" if slots else "sort")
-            span.add("attempts", attempts)
+            span.add("attempts", len(capacities))
+            span.add("aggAttempts", len(capacities))
+            span.add("groupBudgetGrowths", len(capacities) - 1)
             span.set("maxGroups", max_groups)
+            span.set(
+                "capacities", span.attrs.get("capacities", []) + capacities
+            )
             if slots:
                 span.set("slots", slots)
         return keys_out, results, int(ng)

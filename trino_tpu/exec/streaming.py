@@ -56,11 +56,12 @@ from trino_tpu.planner import plan as P
 
 
 class StreamOverflow(Exception):
-    """A capacity overflowed mid-stream; retry with grown caps."""
+    """A capacity overflowed mid-stream; retry with grown caps. ``needs``:
+    each fired flag's value by its capacity's name (``grow_or_raise``)."""
 
-    def __init__(self, names):
-        super().__init__(f"stream capacity overflow: {names}")
-        self.names = names
+    def __init__(self, needs: dict):
+        super().__init__(f"stream capacity overflow: {sorted(needs)}")
+        self.needs = needs
 
 
 def streamable_chain(frag_root: P.PlanNode):
@@ -178,7 +179,7 @@ class StreamingAggregator:
                 fired = np.asarray(flags)
                 if fired.any():
                     raise StreamOverflow(
-                        [nm for nm, f in zip(names, fired) if f]
+                        {nm: int(f) for nm, f in zip(names, fired) if f}
                     )
 
     # === chunk source ====================================================
@@ -324,7 +325,9 @@ class StreamingAggregator:
             return
         fired = np.asarray(state["overflow"])
         if fired.any():
-            raise StreamOverflow([nm for nm, f in zip(names, fired) if f])
+            raise StreamOverflow(
+                {nm: int(f) for nm, f in zip(names, fired) if f}
+            )
 
     # === device-resident slab source =====================================
 
@@ -720,7 +723,7 @@ class StreamingAggregator:
         nspec = len(specs)
         Gc = G  # chunk groups bounded by the same budget
 
-        from trino_tpu.exec.fragments import pack_opt_pairs
+        from trino_tpu.exec.fragments import need_flag, pack_opt_pairs
 
         flat, pack = pack_opt_pairs(keys, sel, agg_inputs)
         flat.extend(state["key_data"])
@@ -801,7 +804,10 @@ class StreamingAggregator:
                     lanes.setdefault(j, [None] * widths[j])[lane] = val
             for j, ln in lanes.items():
                 nvals[j] = jnp.stack(ln, axis=1)
-            ovf = jax.lax.pmax((covf | novf).astype(jnp.int32), AXIS)
+            # the budget holds both the chunk's groups and the merged ones
+            ovf = jax.lax.pmax(
+                need_flag(covf | novf, jnp.maximum(cng, nng)), AXIS
+            )
             return (
                 tuple(nkd), tuple(nkv), nlive,
                 tuple(nvals), tuple(ncnts), ovf,

@@ -475,7 +475,30 @@ def query_phases(spans: Iterable[Dict[str, Any]]) -> Dict[str, Any]:
         "phaseMs": {k: round(v, 3) for k, v in phases.items()},
         "operatorMs": {k: round(v, 3) for k, v in operators.items()},
         **compile_counts(spans),
+        **aggregate_counts(spans),
     }
+
+
+def aggregate_counts(spans: Iterable[Dict[str, Any]]) -> Dict[str, Any]:
+    """What the capacity ladder of the group-bys cost a query, and what the
+    query delivered. ``aggAttempts``: runs of its grouped aggregates, the
+    surviving ones included (the default session counts each ``op:Aggregate``
+    up its own ladder; the compiled session each pass over the fragments,
+    which runs every aggregate of the plan once): 1 an aggregate or a pass
+    where every budget held. ``groupBudgetGrowths``: budgets that were
+    outgrown and grown. ``resultRows``: rows ``result.pull`` typed for the
+    client. The first two are absent where no grouped aggregate ran."""
+    attempts = growths = rows = 0
+    for s in spans:
+        attrs = s.get("attrs") or {}
+        attempts += attrs.get("aggAttempts", 0)
+        growths += attrs.get("groupBudgetGrowths", 0)
+        if s["name"] == "result.pull":
+            rows += attrs.get("rows", 0)
+    out: Dict[str, Any] = {"resultRows": rows}
+    if attempts:
+        out.update(aggAttempts=attempts, groupBudgetGrowths=growths)
+    return out
 
 
 def compile_counts(spans: Iterable[Dict[str, Any]]) -> Dict[str, Any]:

@@ -497,7 +497,10 @@ class _SortedSegments:
             return jnp.where(self.nonempty, run[self._last_idx], 0)
         cs = _prefix_sum(x)
         csz = jnp.concatenate([jnp.zeros((1,), x.dtype), cs])
-        return csz[self.starts[1:]] - csz[self.starts[:-1]]
+        # one gather at the G + 1 boundaries, not one at each end of the G
+        # segments: at a million groups the gathers are the step's time
+        at = csz[self.starts]
+        return at[1:] - at[:-1]
 
     def extreme(self, masked, kind: str):
         """Per-segment min/max of pre-masked values via one segmented

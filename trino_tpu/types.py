@@ -41,6 +41,12 @@ class SqlType:
         """Convert one storage scalar to a Python value for client output."""
         return storage_value
 
+    def to_python_list(self, data: np.ndarray, dictionary=None) -> list:
+        """A column's storage values as ``to_python`` gives them, all at
+        once (a result of a million rows is typed here). Types whose values
+        NumPy can hand over whole override this."""
+        return [self.to_python(v, dictionary) for v in data]
+
 
 @dataclasses.dataclass(frozen=True)
 class BooleanType(SqlType):
@@ -64,6 +70,9 @@ class IntegerLikeType(SqlType):
 
     def to_python(self, v, dictionary=None):
         return int(v)
+
+    def to_python_list(self, data, dictionary=None):
+        return data.tolist()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -135,6 +144,20 @@ class DecimalType(SqlType):
             iv = int(v)
         return Decimal(iv) / (10**self.scale) if self.scale else Decimal(iv)
 
+    def to_python_list(self, data, dictionary=None):
+        from decimal import Decimal
+
+        if data.ndim == 2:  # wide storage: (hi, lo) lanes
+            from trino_tpu.ops.decimal128 import pair_to_int
+
+            ints = map(pair_to_int, data[:, 0].tolist(), data[:, 1].tolist())
+        else:
+            ints = data.tolist()
+        if not self.scale:
+            return [Decimal(iv) for iv in ints]
+        unscale = 10**self.scale
+        return [Decimal(iv) / unscale for iv in ints]
+
 
 @dataclasses.dataclass(frozen=True)
 class VarcharType(SqlType):
@@ -156,6 +179,12 @@ class VarcharType(SqlType):
         if dictionary is None:
             raise ValueError("varchar column without dictionary")
         return dictionary.decode(int(v))
+
+    def to_python_list(self, data, dictionary=None):
+        if dictionary is None:
+            raise ValueError("varchar column without dictionary")
+        values = dictionary.values
+        return [None if c < 0 else values[c] for c in data.tolist()]
 
 
 @dataclasses.dataclass(frozen=True)
