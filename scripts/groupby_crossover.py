@@ -12,6 +12,18 @@ of ``--calls`` calls after one that compiles. A slot count over
 the point: the reading is what sets that constant (``PERF.md`` section 6).
 One JSON line per reading, the last one the summary; nothing here is part
 of the benchmark.
+
+    python scripts/groupby_crossover.py --step-rows 2097152 8388608 16777216 \
+        [--groups 1048576]
+
+times instead one step of the compiled session's slab loop
+(``StreamingAggregator._step_grouped``: the chunk partial over ``rows`` raw
+rows, then the merge of the ``groups``-sized state with the chunk's groups)
+at each width, at the lanes of h2o's q5 (one BIGINT key uniform over
+``groups`` values, two BIGINT sums, one DECIMAL sum of three limb lanes), the
+state full: what a row of a step costs and what the state costs whatever the
+rows. The reading that sets ``exec/streaming.py::SLAB_ROWS_PER_GROUP``
+(``PERF.md`` section 6, PR 34).
 """
 
 import argparse
@@ -69,14 +81,75 @@ def timed(fn, args, calls: int):
     return first, ms
 
 
+def slab_steps(widths, groups: int, calls: int, seed: int, device) -> None:
+    """One JSON line a width: a slab step at q5's lanes (module docstring)."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from trino_tpu.exec.streaming import StreamingAggregator
+    from trino_tpu.parallel.mesh import AXIS, make_mesh
+
+    sagg = object.__new__(StreamingAggregator)  # the step reads these four
+    sagg.nkeys, sagg.G, sagg.n, sagg.mesh = 1, groups, 1, make_mesh(1)
+    placed = NamedSharding(sagg.mesh, PartitionSpec(AXIS))
+    specs = (A.AggSpec("sum"), A.AggSpec("sum"), A.AggSpec("sum128"))
+    combine, limbs = ["sum"] * 3, [1, 1, 3]
+
+    @jax.jit
+    def step(state, key, v1, v2, v3):
+        sel = jnp.ones(key.shape, jnp.bool_)
+        out = sagg._step_grouped(
+            state, [(key, None)], sel, [(v1, None), (v2, None), (v3, None)],
+            specs, combine, limbs, None)
+        return {k: v for k, v in out.items() if k != "overflow"}, out["overflow"]
+
+    for rows in sorted(widths):
+        ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+        chunk = [jax.random.randint(k, (rows,), 1, hi + 1, jnp.int64)
+                 for k, hi in zip(ks, (groups, 5, 15, 99_999_999))]
+        state = jax.device_put({
+            "key_data": [jnp.zeros(groups, jnp.int64)],
+            "key_valid": [jnp.zeros(groups, jnp.bool_)],
+            "live": jnp.zeros(groups, jnp.bool_),
+            "values": [jnp.zeros((groups,) if w == 1 else (groups, w), jnp.int64)
+                       for w in limbs],
+            "counts": [jnp.zeros(groups, jnp.int64) for _ in limbs],
+        }, placed)  # as ``_init_state`` places it: one compilation a width
+        t0 = time.perf_counter()
+        state, overflow = jax.block_until_ready(step(state, *chunk))
+        first = time.perf_counter() - t0
+        state, overflow = jax.block_until_ready(step(state, *chunk))
+        ms = []
+        for _ in range(calls):  # the state is full by now
+            t0 = time.perf_counter()
+            state, overflow = jax.block_until_ready(step(state, *chunk))
+            ms.append((time.perf_counter() - t0) * 1000.0)
+        memory = device.memory_stats() or {}
+        print(json.dumps({
+            "path": "slab_step", "rows": rows, "groups": groups,
+            "groups_live": int(jnp.sum(state["live"])), "overflow": int(overflow),
+            "first_s": first, "ms": ms, "median_ms": statistics.median(ms),
+            "us_per_row": statistics.median(ms) * 1000.0 / rows,
+            # the process's high-water mark: widths run in ascending order
+            "peak_bytes_in_use": memory.get("peak_bytes_in_use"),
+        }), flush=True)
+        del chunk, state
+
+
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--rows", type=int, default=1 << 21)
     ap.add_argument("--slots", type=int, nargs="+", default=[12, 16, 64, 256])
+    ap.add_argument("--step-rows", type=int, nargs="+", default=None)
+    ap.add_argument("--groups", type=int, default=1 << 20)
     ap.add_argument("--calls", type=int, default=7)
     ap.add_argument("--seed", type=int, default=29)
     args = ap.parse_args()
     device = jax.devices()[0]
+    if args.step_rows:
+        print(json.dumps({"device": {"platform": device.platform,
+                                     "kind": device.device_kind}}), flush=True)
+        slab_steps(args.step_rows, args.groups, args.calls, args.seed, device)
+        return 0
     out = {"device": {"platform": device.platform, "kind": device.device_kind},
            "rows": args.rows, "max_slots": A.DOMAIN_MAX_SLOTS, "domain_ms": {}}
     for slots in args.slots:

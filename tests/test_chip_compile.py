@@ -189,6 +189,27 @@ def test_keypack_sort(one_chip, case):
     )
 
 
+@pytest.mark.parametrize("rows", [1 << 23, 1 << 24])
+def test_an_int64_prefix_sum_inside_a_loop_at_a_wide_slab_steps_rows(one_chip, rows):
+    """``_SortedSegments.sum``'s prefix sum inside the slab loop, at the rows
+    of a step widened from a million-group budget (``slab_step_rows``). With
+    the block totals scanned in one window the compiler refuses both sizes
+    inside a loop (scoped vmem 64.23M and 19.09M over 16.00M, 25-50 s each to
+    say so); ``_blocked_scan`` sends them through itself past
+    ``_SCAN_DIRECT_BLOCKS``."""
+    from trino_tpu.ops.aggregation import _SCAN_DIRECT_BLOCKS, _prefix_sum
+
+    assert rows // 512 > _SCAN_DIRECT_BLOCKS == (1 << 21) // 512
+
+    def loop(x, steps):
+        def body(i, acc):
+            return acc + _prefix_sum(x + i.astype(jnp.int64))
+
+        return jax.lax.fori_loop(0, steps, body, jnp.zeros_like(x))
+
+    _compile(loop, _shape(one_chip, (rows,), jnp.int64), _shape(one_chip, (), jnp.int32))
+
+
 def test_repartition_is_an_all_to_all_on_four_chips(topo):
     """The repartition shuffle over a 4-device mesh of the described
     chips: the compiler must put an all-to-all in, on every device."""

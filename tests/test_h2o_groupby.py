@@ -246,6 +246,62 @@ def test_the_slabs_budget_grows_to_the_groups_it_counted(served):
     assert caps["agg@2#0"]["provenance"] == "default+grown"
 
 
+def test_the_grown_pass_of_the_cold_ladder_runs_wide():
+    """q5 cold on a server of its own: the first pass (budget 4,096) takes the
+    session's width, 4 steps of 65,536 rows; the budget grows to 32,768 and
+    the pass at it takes one step of 524,288 (16 rows a group of the budget,
+    ``slab_step_rows``): the narrow pass's width is not remembered against
+    it. The second query finds the wide program: nothing traced, nothing
+    staged again, ``slabSteps`` 1."""
+    import urllib.request
+
+    from trino_tpu import client
+    from trino_tpu.engine import Engine
+    from trino_tpu.parallel.mesh import make_mesh
+    from trino_tpu.server.http import TrinoTpuServer
+
+    engine = Engine()
+    engine.mesh = make_mesh(1)
+    server = TrinoTpuServer(engine=engine, port=0).start()
+    try:
+        conn = client.Connection(server.base_uri, client.ClientSession(
+            catalog="h2o", schema=SCHEMA, properties=dict(SESSIONS["compiled"])))
+        sql = template("g1q5")[0]
+        infos, slabs = [], []
+        for _ in range(2):
+            rows, _ = conn.execute(sql)
+            info = last_query(conn)
+            with urllib.request.urlopen(
+                    f"{conn.base_uri}/v1/query/{info['queryId']}/timeline") as f:
+                timeline = json.load(f)
+            spans = timeline["spans"] if isinstance(timeline, dict) else timeline
+            infos.append(info)
+            slabs.append([s["attrs"] for s in spans if s["name"] == "stream.slab"])
+    finally:
+        server.stop()
+    cold, warm = slabs
+    base = SESSIONS["compiled"]["stream_device_chunk_rows"]
+    assert [(a["groups"], a["cap"], a["baseCap"], a["steps"], a["cacheHit"])
+            for a in cold[:2]] == [
+        (4096, base, base, 4, False), (32768, 8 * base, base, 1, False)]
+    # a third pass, where the final aggregate's budget grew, hits the store
+    assert all((a["cap"], a["cacheHit"]) == (8 * base, True) for a in cold[2:])
+    assert [(a["groups"], a["cap"], a["baseCap"], a["steps"], a["cacheHit"], a["attempt"])
+            for a in warm] == [(32768, 8 * base, base, 1, True, 1)]
+    assert all(a["site"] == "agg@2#0" and a["groupBy"] == "sort" for a in cold + warm)
+    cold_info, warm_info = infos
+    assert cold_info["queryStats"]["slabSteps"] == warm_info["queryStats"]["slabSteps"] == 1
+    assert cold_info["ingestStats"]["h2d_bytes"] == 4 * 8 * (1 << 22)  # 4 columns, padded
+    assert warm_info["ingestStats"]["h2d_bytes"] == 0
+    assert warm_info["traceCount"] == 0 and warm_info["queryStats"]["xlaCompiles"] == 0
+    assert len(rows) > 19_990
+    with engine._query_cache_lock:
+        keys = [k for e in engine._query_cache.values() for k in e["programs"]]
+    assert not [k for k in keys if isinstance(k, tuple) and k[0] == "slabcap"]
+    assert sorted(k[2:4] for k in keys if isinstance(k, tuple) and k[0] == "slab") == [
+        (4096, base), (32768, 8 * base)]
+
+
 def test_the_default_sessions_ladder_names_its_capacities(served):
     import urllib.request
 

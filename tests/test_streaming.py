@@ -506,6 +506,9 @@ class TestDomainGroupByThroughTheSlab:
         r = DistributedQueryRunner(n_devices=1)
         r.session.set("stream_scan_threshold_rows", 1)
         r.session.set("stream_device_chunk_rows", 32768)  # two steps of tiny
+        # ... which stay two on the sort path: 16 x 2,048 groups is no more
+        # than the width (``slab_step_rows``)
+        r.session.set("stream_group_budget", 2048)
         return r
 
     def test_q1_takes_the_domain_path_and_its_program_sorts_no_chunk(
@@ -522,8 +525,10 @@ class TestDomainGroupByThroughTheSlab:
         stored = []
         orig = S.StreamingAggregator._slab_attempt
 
-        def keeping(self, programs, slab, chunk_cols, num_rows, cap, span):
-            res = orig(self, programs, slab, chunk_cols, num_rows, cap, span)
+        def keeping(self, programs, slab, chunk_cols, num_rows, cap, span,
+                    meta=None):
+            res = orig(self, programs, slab, chunk_cols, num_rows, cap, span,
+                       meta)
             program, meta = programs[("slab", self.site, self.G, cap, False)]
             steps = np.int32((num_rows + cap - 1) // cap)
             stored.append((cap, self.G, jax.make_jaxpr(program)(
@@ -718,16 +723,19 @@ class TestDomainGroupByThroughTheSlab:
             return key
 
         assert {kind(k) for k in store} == {
-            "slab", "slabcap",              # exec/streaming.py
+            "slab",                         # exec/streaming.py
+            # (no "slabcap": a width is remembered only where the compiler
+            # refused a wider one, as ``("slabcap", site, budget)``)
             # (no "post": on one device the streamed fragment's output
             # exchange is the identity and gets no program of its own)
             "fused", "caps",                # fragment programs, capacity sites
             "__subplan__", "__fusedunits__", "__fragstats__", "__skewroles__",
             "__stats__",
         }
-        slab = [k for k in store if kind(k) in ("slab", "slabcap")]
-        assert sorted(k[0] for k in slab) == ["slab", "slabcap"]
-        assert {k[1] for k in slab} == {"agg@2#0"}
+        slab = [k for k in store if kind(k) == "slab"]
+        # Q1's key is what it was before the step could widen: the site,
+        # the budget, the session's width, a staged slab
+        assert slab == [("slab", "agg@2#0", 4096, 1 << 21, False)]
         program, meta = store[next(k for k in slab if k[0] == "slab")]
         assert callable(program) and meta["slots"] == 12
         held = [
@@ -789,3 +797,241 @@ def test_equal_plans_at_other_addresses_share_the_slab_program():
             f" where l_quantity < {literal} group by l_linestatus")
         # partial accumulators: key, sum, count of the sum, count(*)
         assert [(g[0], g[1], g[-1]) for g in got] == sorted(want)
+
+
+# --- the width of a slab step, chosen from the group budget -------------------
+
+
+@pytest.mark.parametrize("case, base, groups, sort_path, held, want", [
+    # the programs of before keep the session's width, and with it their key
+    ("q1: 12 slots on the domain path", 1 << 21, 4096, False, 1 << 23, 1 << 21),
+    ("q2 of h2o: 16,384 groups, sort path", 1 << 21, 16384, True, 100_663_296, 1 << 21),
+    ("q4 of h2o: 100 integer groups", 1 << 21, 4096, True, 100_663_296, 1 << 21),
+    ("16 chunks of groups just fit", 1 << 21, 1 << 17, True, 100_663_296, 1 << 21),
+    ("a domain path under a large budget", 1 << 21, 1 << 20, False, 100_663_296, 1 << 21),
+    ("a global aggregate", 1 << 21, 1, False, 100_663_296, 1 << 21),
+    # the sort path past 16 rows a group of the budget widens
+    ("one group more than fits", 1 << 21, (1 << 17) + 1, True, 100_663_296, 1 << 22),
+    ("q5 of h2o: a million groups, six steps", 1 << 21, 1 << 20, True, 100_663_296, 1 << 24),
+    ("a table smaller than the wide step: one step", 1 << 21, 1 << 20, True, 3 << 22, 3 << 22),
+    ("a slab no larger than the base width", 1 << 12, 1 << 20, True, 1 << 12, 1 << 12),
+])
+def test_slab_step_rows(case, base, groups, sort_path, held, want):
+    from trino_tpu.exec.streaming import SLAB_ROWS_PER_GROUP, slab_step_rows
+
+    assert SLAB_ROWS_PER_GROUP == 16
+    assert slab_step_rows(base, groups, sort_path, held) == want, case
+    if "six steps" in case:
+        assert held == 6 * want
+
+
+class TestWideSlabStep:
+    """A step widened from the group budget reads the same rows once each
+    and answers as the session's width and the default session do."""
+
+    BASE, BUDGET, WIDE = 4096, 1024, 16384
+
+    @pytest.fixture(scope="class")
+    def runner(self):
+        r = DistributedQueryRunner(n_devices=1)
+        r.session.set("stream_scan_threshold_rows", 1)
+        r.session.set("stream_device_chunk_rows", self.BASE)
+        r.session.set("stream_group_budget", self.BUDGET)
+        return r
+
+    @pytest.fixture(scope="class")
+    def tables(self, runner):
+        """``wide50k``: 50,000 rows, padded (at the quantum the cases set)
+        to 53,248, which 16,384 does not divide; ``wide10k``: 10,000 rows
+        in 12,288, less than one wide step."""
+        import numpy as np
+
+        from trino_tpu import types as T
+        from trino_tpu.columnar import Batch, Column
+        from trino_tpu.connectors.api import ColumnSchema, TableSchema
+
+        mem = runner.catalogs.get("memory")
+        for name, n in (("wide50k", 50_000), ("wide10k", 10_000)):
+            rng = np.random.default_rng(n)
+            cols = [
+                Column(T.BIGINT, rng.integers(0, 300, n).astype(np.int64)),
+                Column(T.BIGINT, rng.integers(0, 3, n).astype(np.int64)),
+                Column(T.BIGINT, rng.integers(0, 9, n).astype(np.int64),
+                       rng.integers(0, 5, n) > 0),
+                Column(T.BIGINT, rng.integers(-(1 << 40), 1 << 40, n)),
+            ]
+            mem.create_table("default", name, TableSchema(name, tuple(
+                ColumnSchema(c, T.BIGINT) for c in ("k", "k2", "kn", "v"))))
+            mem.insert("default", name, Batch(cols, n))
+
+    _CASES = {
+        # table, keys, aggregates, steps at the wide width
+        "rows-not-a-multiple-of-the-width": ("wide50k", "k", "sum(v), count(*)", 4),
+        "a-width-larger-than-the-table": ("wide10k", "k", "sum(v), count(*)", 1),
+        "null-keys": ("wide50k", "kn", "sum(v), count(*), count(kn)", 4),
+        "a-two-key-group-by": ("wide50k", "k, k2", "sum(v), avg(v)", 4),
+        "min-max-beside-sums": ("wide50k", "k2", "min(v), max(v), sum(v)", 4),
+    }
+
+    @pytest.mark.parametrize("case", list(_CASES))
+    def test_the_wide_step_answers_as_the_base_width_and_the_default_session(
+        self, runner, tables, slab_spans, monkeypatch, case
+    ):
+        from trino_tpu.connectors import api
+        from trino_tpu.exec import streaming as S
+
+        table, keys, aggs, steps = self._CASES[case]
+        # a quantum of the base width, so a wide step need not divide the
+        # padded rows (on the chip: 4,194,304 under steps of 16,777,216)
+        monkeypatch.setattr(api, "SLAB_PAD_QUANTUM", self.BASE)
+        sql = (f"select {keys}, {aggs} from memory.default.{table}"
+               f" group by {keys} order by {keys}")
+        wide = runner.engine.execute_statement(sql, runner.session)
+        again = runner.engine.execute_statement(sql, runner.session)
+        assert again.trace_count == 0
+        monkeypatch.setattr(S, "SLAB_ROWS_PER_GROUP", 0)  # the rule off
+        narrow = runner.engine.execute_statement(sql, runner.session)
+        want = LocalQueryRunner(engine=runner.engine).execute(sql)[0]
+        assert wide.rows == again.rows == narrow.rows == want and len(want) > 2
+        rows, held = (50_000, 53_248) if table == "wide50k" else (10_000, 12_288)
+        cap = min(self.WIDE, held)
+        assert [(a["cap"], a["baseCap"], a["steps"], a["cacheHit"]) for a in slab_spans] == [
+            (cap, self.BASE, steps, False), (cap, self.BASE, steps, True),
+            (self.BASE, self.BASE, -(-rows // self.BASE), False),
+        ]
+        assert all(a["groupBy"] == "sort" and a["groups"] == self.BUDGET for a in slab_spans)
+
+    def test_a_refused_wide_compile_halves_answers_and_is_remembered(
+        self, runner, tables, slab_spans, monkeypatch
+    ):
+        """The compiler refusing the wide step's program (scoped vmem) costs
+        one failed compile, once: the ladder halves from the wide start, the
+        query answers, and the store remembers the width for that budget."""
+        import jax
+
+        from trino_tpu.exec import streaming as S
+
+        sql = ("select k, sum(v), count(*) from memory.default.wide50k"
+               " where v > 0 group by k order by k")
+        orig = S.StreamingAggregator._make_slab_program
+        refused = []
+
+        def refusing(self, meta, cap, chunk_cols=None):
+            if cap > 8192:
+                refused.append(cap)
+
+                def program(*args):
+                    raise jax.errors.JaxRuntimeError(
+                        "RESOURCE_EXHAUSTED: Ran out of memory in memory space"
+                        " vmem while allocating on stack for %reduce-window")
+
+                return program
+            return orig(self, meta, cap, chunk_cols)
+
+        monkeypatch.setattr(S.StreamingAggregator, "_make_slab_program", refusing)
+        monkeypatch.setattr(S, "SLAB_MIN_ROWS", 1024)
+        first = runner.engine.execute_statement(sql, runner.session)
+        again = runner.engine.execute_statement(sql, runner.session)
+        assert first.rows == again.rows == LocalQueryRunner(
+            engine=runner.engine).execute(sql)[0]
+        assert refused == [16384]
+        assert [(a["attempt"], a["cap"], a["cacheHit"]) for a in slab_spans] == [
+            (1, 16384, False), (2, 8192, False), (1, 8192, True)]
+        assert again.trace_count == 0
+        with runner.engine._query_cache_lock:
+            stores = [e["programs"] for e in runner.engine._query_cache.values()]
+        held = [s for s in stores if ("slabcap", "agg@2#0", self.BUDGET) in s]
+        assert len(held) == 1 and held[0]["slabcap", "agg@2#0", self.BUDGET] == 8192
+
+
+def test_a_program_the_rule_leaves_alone_lowers_to_the_text_it_had(slab_spans):
+    """Q1's slab program (domain path, base width) against the loop body as
+    it stood before the step could widen, written out here: the same
+    StableHLO text, so the clamp of a wide last step costs it nothing."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from trino_tpu.columnar import Batch, Column
+    from trino_tpu.exec import streaming as S
+
+    def parent_program(sagg, meta, cap):
+        inner = sagg._make_step(meta)
+
+        def program(state, slab, n_steps, num_rows, params):
+            def body(i, state):
+                off = i.astype(jnp.int64) * cap
+                cnt = jnp.minimum(cap, (num_rows - off).astype(jnp.int32))
+                cols = []
+                for c in slab.columns:
+                    data = jax.lax.dynamic_slice_in_dim(c.data, off, cap, axis=0)
+                    valid = (
+                        None if c.valid is None
+                        else jax.lax.dynamic_slice_in_dim(c.valid, off, cap, axis=0))
+                    cols.append(Column(c.type, data, valid, c.dictionary))
+                live = jnp.arange(cap, dtype=jnp.int32) < cnt
+                return inner(state, Batch(cols, cap, live), None, params)
+
+            return jax.lax.fori_loop(0, n_steps, body, state)
+
+        return program
+
+    texts = []
+    orig = S.StreamingAggregator._slab_attempt
+
+    def lowering(self, programs, slab, chunk_cols, num_rows, cap, span, meta=None):
+        res = orig(self, programs, slab, chunk_cols, num_rows, cap, span, meta)
+        _, meta = programs[("slab", self.site, self.G, cap, False)]
+        args = (self._init_state(meta), slab, np.int32(1), np.int64(num_rows),
+                self.params)
+        texts.append((cap, [
+            jax.jit(make(meta, cap)).lower(*args).as_text()
+            for make in (lambda m, c: self._make_slab_program(m, c),
+                         lambda m, c: parent_program(self, m, c))]))
+        return res
+
+    runner = DistributedQueryRunner(n_devices=1)
+    runner.session.set("stream_scan_threshold_rows", 1)
+    runner.session.set("stream_device_chunk_rows", 32768)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(S.StreamingAggregator, "_slab_attempt", lowering)
+        runner.engine.execute_statement(Q1.format(90), runner.session)
+    ((cap, (ours, parents)),) = texts
+    assert [(a["cap"], a["baseCap"]) for a in slab_spans] == [(cap, cap)] == [(32768, 32768)]
+    assert "dynamic_slice" in ours and ours == parents
+
+
+def _slab_span(at, site, steps, **attrs):
+    return {"name": "stream.slab", "spanId": f"s{at}", "startNs": at, "endNs": at + 1,
+            "durationMs": 1e-6, "attrs": {"site": site, "steps": steps, **attrs}}
+
+
+@pytest.mark.parametrize("case, spans, want", [
+    ("a warm query: one loop", [_slab_span(1, "agg@2#0", 6)], 6),
+    ("the cold ladder: the pass that outgrew its budget does not count",
+     [_slab_span(1, "agg@2#0", 48), _slab_span(5, "agg@2#0", 6), _slab_span(9, "agg@2#0", 6)], 6),
+    ("a refused width: the attempt that answered",
+     [_slab_span(1, "agg@2#0", 6, attempt=1), _slab_span(2, "agg@2#0", 12, attempt=2)], 12),
+    ("two streamed aggregates add up, whatever order the spans come in",
+     [_slab_span(7, "agg@4#0", 3), _slab_span(1, "agg@2#0", 48), _slab_span(3, "agg@2#0", 6)], 9),
+    ("nothing streamed through a slab program",
+     [{"name": "execute_plan", "spanId": "e", "startNs": 0, "endNs": 9, "attrs": {}}], None),
+])
+def test_slab_steps_are_those_of_each_aggregates_last_loop(case, spans, want):
+    """``queryStats.slabSteps`` and the benchmark's reader of it
+    (``benchmark/metrics/slab_steps.py``), which reads nothing from a
+    program that has no such counter and does not raise."""
+    import os
+
+    from benchmark import harness
+    from trino_tpu.obs.trace import aggregate_counts, query_phases
+
+    assert aggregate_counts(spans).get("slabSteps") == want, case
+    stats = query_phases(spans)
+    assert stats.get("slabSteps") == want and stats["resultRows"] == 0
+    read = harness.load_reader(
+        os.path.join(os.path.dirname(os.path.dirname(__file__)), "benchmark"), "slab_steps")
+    infos = [{"state": "FINISHED", "queryStats": stats}] * 2
+    assert read({"infos": infos}) == want
+    assert read({"infos": []}) is None
+    assert read({"infos": [{"state": "FINISHED", "queryStats": {"aggAttempts": 1}}]}) is None

@@ -95,8 +95,13 @@ class Session:
         # into HBM (memory connector) stream it via in-program
         # dynamic_slice chunks; cap on staged bytes per table
         ("stream_device_cache_bytes", 4 << 30),
-        # 2M rows: the in-loop int64 cumsum's reduce-window must fit
-        # scoped vmem (16MB on v5e; 4M-row chunks exceed it)
+        # the BASE width of a slab step, 2M rows: what the domain path, a
+        # global aggregate and a small group budget take. On the sort path
+        # exec/streaming.py widens the step from the group budget
+        # (``slab_step_rows``: 16 rows a group), since every step merges
+        # the whole group state. (A 4M-row chunk's in-loop int64 cumsum
+        # once passed the 16 MB of scoped vmem on v5e; the scans are
+        # blocked since, ``ops/aggregation.py::_blocked_scan``.)
         ("stream_device_chunk_rows", 1 << 21),
         # initial per-shard group budget for streamed aggregation (grows
         # on overflow)

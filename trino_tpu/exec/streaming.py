@@ -64,6 +64,32 @@ class StreamOverflow(Exception):
         self.needs = needs
 
 
+# Rows of a slab step for each group of the budget, on the sort path. A
+# step's merge sorts, gathers and scans the ``2 G`` rows of state and chunk
+# groups whatever the chunk held, so a chunk has to outweigh them. On the
+# chip at ``G`` = 1,048,576 and h2o q5's lanes one step of 2,097,152 rows
+# takes 0.851 s, of 8,388,608 1.165, of 16,777,216 1.782
+# (``scripts/groupby_crossover.py --step-rows``), and the query over 1e8
+# rows 16.1 s at 8 rows a group (12 steps), 12.7 at 16 (6), 14.6 at 32 (3:
+# the deeper sorts and the gathers from a wider source cost more than three
+# merges save), 42.6 at the base width (48); peak memory 3.71, 3.77, 3.83
+# and 3.74 GB (``PERF.md`` section 6, PR 34).
+SLAB_ROWS_PER_GROUP = 16
+# a compile failure at this width or under is not the width's: no halving
+SLAB_MIN_ROWS = 1 << 18
+
+
+def slab_step_rows(base: int, groups: int, sort_path: bool, held: int) -> int:
+    """Rows a slab step takes: ``base`` (the session's
+    ``stream_device_chunk_rows``) on the domain path, for a global aggregate
+    (``sort_path`` false for both) and while ``SLAB_ROWS_PER_GROUP`` chunks
+    of ``groups`` fit in it; else the smallest power of two that holds
+    them, and no more than the ``held`` rows the slab has."""
+    if not sort_path or SLAB_ROWS_PER_GROUP * groups <= base:
+        return base
+    return min(bucket_capacity(SLAB_ROWS_PER_GROUP * groups), max(base, held))
+
+
 def streamable_chain(frag_root: P.PlanNode):
     """Detect a streamable fragment:
     Output?→Aggregate→(Filter|Project|Join)*→TableScan along the PROBE
@@ -378,53 +404,52 @@ class StreamingAggregator:
             # the step closes over this query's materialized build
             # batches; a cached program would pin stale builds
             programs = None
+        # the step widens with the group budget (``slab_step_rows``); which
+        # way the step groups is asked only once the budget could widen it
+        base, meta = cap, None
+        if self.nkeys and SLAB_ROWS_PER_GROUP * self.G > base:
+            meta = self._probe_meta(slab, chunk_cols, base)
+            cap = slab_step_rows(
+                base, self.G, not meta["slots"],
+                slab.capacity if slab is not None else bucket_capacity(num_rows),
+            )
         # wide pipelines (many payload lanes) can exceed scoped vmem at
-        # large chunk sizes: on a compile failure, halve the chunk (the
-        # slab's quantum padding stays valid for any smaller power of
-        # two) and REMEMBER the working cap so warm queries never repeat
+        # large chunk sizes: on a compile failure, halve the chunk (a wide
+        # step that no longer divides the slab's padded rows clamps its
+        # last offset) and REMEMBER the working cap, for the budget the
+        # compiler refused the wider one at, so warm queries never repeat
         # the failing compile
+        cap_key = ("slabcap", self.site, self.G)
         if programs is not None:
-            cap = min(cap, programs.get(("slabcap", self.site), cap))
+            cap = min(cap, programs.get(cap_key, cap))
         attempt = 0
         while True:
             attempt += 1
             with get_tracer().span(
                 "stream.slab",
                 attrs={
+                    "site": self.site,
                     "steps": (num_rows + cap - 1) // cap,
                     "cap": cap,
+                    "baseCap": base,
                     "groups": self.G,
                     "params": len(self.params or ()),
                     "attempt": attempt,
                 },
             ) as span:
                 res = self._slab_attempt(
-                    programs, slab, chunk_cols, num_rows, cap, span
+                    programs, slab, chunk_cols, num_rows, cap, span, meta
                 )
             if res is not None:
+                if attempt > 1 and programs is not None:
+                    programs[cap_key] = cap
                 return res
             cap //= 2
 
-    def _slab_attempt(
-        self, programs, slab, chunk_cols, num_rows: int, cap: int, span
-    ) -> Optional[Result]:
-        """One run of the slab program at chunk size ``cap``: the stored
-        program if there is one, else trace, compile and store it. None
-        when the compiler refused the size and a halved ``cap`` may fit."""
-        n_steps = (num_rows + cap - 1) // cap
-        prog_key = ("slab", self.site, self.G, cap, slab is None)
-        hit = programs.get(prog_key) if programs is not None else None
-        span.set("cacheHit", hit is not None)
-        if hit is not None:
-            program, meta = hit
-            self._note_group_by(span, meta)
-            state = self._init_state(meta)
-            state = program(
-                state, slab, np.int32(n_steps), np.int64(num_rows), self.params
-            )
-            self.executor.count_program(hit=True)
-            self._check_overflow(state, prog_key, meta)
-            return self._finish(state, meta)
+    def _probe_meta(self, slab, chunk_cols, cap: int) -> dict:
+        """``_collect_meta`` over an abstract chunk of ``cap`` rows of the
+        slab (or of the connector's generator): nothing in it depends on
+        ``cap``."""
         if slab is not None:
             probe_cols = [
                 Column(
@@ -455,7 +480,32 @@ class StreamingAggregator:
         # a resident slab's dictionaries are final at staging, so their
         # lengths are the group keys' domains; a program stored with them
         # lives in a store that the table's data version names (engine.py)
-        meta = self._collect_meta(probe_chunk, resident=slab is not None)
+        return self._collect_meta(probe_chunk, resident=slab is not None)
+
+    def _slab_attempt(
+        self, programs, slab, chunk_cols, num_rows: int, cap: int, span,
+        meta: Optional[dict] = None,
+    ) -> Optional[Result]:
+        """One run of the slab program at chunk size ``cap``: the stored
+        program if there is one, else trace, compile and store it (``meta``
+        where the caller has probed it already). None when the compiler
+        refused the size and a halved ``cap`` may fit."""
+        n_steps = (num_rows + cap - 1) // cap
+        prog_key = ("slab", self.site, self.G, cap, slab is None)
+        hit = programs.get(prog_key) if programs is not None else None
+        span.set("cacheHit", hit is not None)
+        if hit is not None:
+            program, meta = hit
+            self._note_group_by(span, meta)
+            state = self._init_state(meta)
+            state = program(
+                state, slab, np.int32(n_steps), np.int64(num_rows), self.params
+            )
+            self.executor.count_program(hit=True)
+            self._check_overflow(state, prog_key, meta)
+            return self._finish(state, meta)
+        if meta is None:
+            meta = self._probe_meta(slab, chunk_cols, cap)
         self._note_group_by(span, meta)
         state = self._init_state(meta)
         program = jax.jit(
@@ -473,7 +523,7 @@ class StreamingAggregator:
                 tok in msg
                 for tok in ("compile", "vmem", "resource_exhausted")
             )
-            if not compile_failure or cap <= 1 << 18:
+            if not compile_failure or cap <= SLAB_MIN_ROWS:
                 raise
             return None
         # trace + lower + compile are synchronous in the first call and
@@ -485,7 +535,6 @@ class StreamingAggregator:
         )
         if programs is not None:
             programs[prog_key] = (program, meta)
-            programs[("slabcap", self.site)] = cap
         self._check_overflow(state, prog_key, meta)
         return self._finish(state, meta)
 
@@ -512,19 +561,30 @@ class StreamingAggregator:
                 # generator path has no table-size bound)
                 off = i.astype(jnp.int64) * cap
                 cnt = jnp.minimum(cap, (num_rows - off).astype(jnp.int32))
+                skip = None
                 if slab is not None:
+                    start = off
+                    if slab.capacity % cap:
+                        # a wide step that does not divide the padded
+                        # rows: the last one starts where it still fits
+                        # and masks the rows the step before it read
+                        start = jnp.minimum(off, slab.capacity - cap)
+                        skip = (off - start).astype(jnp.int32)
                     cols = []
                     for c in slab.columns:
-                        data = jax.lax.dynamic_slice_in_dim(c.data, off, cap, axis=0)
+                        data = jax.lax.dynamic_slice_in_dim(c.data, start, cap, axis=0)
                         valid = (
                             None
                             if c.valid is None
-                            else jax.lax.dynamic_slice_in_dim(c.valid, off, cap, axis=0)
+                            else jax.lax.dynamic_slice_in_dim(c.valid, start, cap, axis=0)
                         )
                         cols.append(Column(c.type, data, valid, c.dictionary))
                 else:
                     cols = chunk_cols(off, cap)
-                live = jnp.arange(cap, dtype=jnp.int32) < cnt
+                pos = jnp.arange(cap, dtype=jnp.int32)
+                live = pos < cnt
+                if skip is not None:
+                    live = (pos >= skip) & (pos - skip < cnt)
                 return inner(state, Batch(cols, cap, live), None, params)
 
             return body

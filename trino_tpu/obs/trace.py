@@ -487,17 +487,29 @@ def aggregate_counts(spans: Iterable[Dict[str, Any]]) -> Dict[str, Any]:
     which runs every aggregate of the plan once): 1 an aggregate or a pass
     where every budget held. ``groupBudgetGrowths``: budgets that were
     outgrown and grown. ``resultRows``: rows ``result.pull`` typed for the
-    client. The first two are absent where no grouped aggregate ran."""
+    client. ``slabSteps``: steps of the slab loops whose answer the query
+    kept, each streamed aggregate's last ``stream.slab`` span (the passes
+    before it outgrew a budget, the attempts before it a width the compiler
+    refused). The first two are absent where no grouped aggregate ran, the
+    last where nothing streamed through a slab program."""
     attempts = growths = rows = 0
+    last: Dict[Any, Tuple[int, int]] = {}  # site -> (start, steps), the latest
     for s in spans:
         attrs = s.get("attrs") or {}
         attempts += attrs.get("aggAttempts", 0)
         growths += attrs.get("groupBudgetGrowths", 0)
         if s["name"] == "result.pull":
             rows += attrs.get("rows", 0)
+        elif s["name"] == "stream.slab" and "steps" in attrs:
+            loop = (s.get("startNs") or 0, attrs["steps"])
+            site = attrs.get("site")
+            if site not in last or loop[0] >= last[site][0]:
+                last[site] = loop
     out: Dict[str, Any] = {"resultRows": rows}
     if attempts:
         out.update(aggAttempts=attempts, groupBudgetGrowths=growths)
+    if last:
+        out["slabSteps"] = sum(steps for _, steps in last.values())
     return out
 
 

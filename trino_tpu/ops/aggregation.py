@@ -388,6 +388,12 @@ class _SlotSegments:
         return b1, self._extreme(tied, k2, kind)
 
 
+# Blocks whose totals ``_blocked_scan`` still scans in one window: what a
+# slab step of 2,097,152 rows has, the widest scan inside a loop before PR 34,
+# so no program of that many rows or fewer changes
+_SCAN_DIRECT_BLOCKS = 1 << 12
+
+
 def _blocked_scan(x, scan, combine, ident):
     """Inclusive prefix scan via a blocked two-level scan.
 
@@ -396,7 +402,14 @@ def _blocked_scan(x, scan, combine, ident):
     streaming chunk loop), and XLA:TPU takes ~1min to COMPILE an int64
     reduce-window at odd (non-power-of-two) sizes. Odd sizes are padded
     with ``ident`` to a block multiple so every window stays small and
-    power-of-two shaped."""
+    power-of-two shaped.
+
+    The second level (one total a block) is one window as long as there
+    are blocks. Up to ``_SCAN_DIRECT_BLOCKS`` of them it is scanned
+    directly; past that it goes through this function again: inside the
+    slab loop the v5e compiler refuses the direct one at the 16,384 blocks
+    of an 8,388,608-row step (scoped vmem 64.23M over 16.00M) and at the
+    32,768 of a 16,777,216-row one (19.09M) (``PERF.md`` section 6, PR 34)."""
     n = x.shape[0]
     blk = 512
     if n <= blk:
@@ -405,7 +418,11 @@ def _blocked_scan(x, scan, combine, ident):
     xp = jnp.concatenate([x, jnp.full((pad,), ident, x.dtype)]) if pad else x
     xb = jnp.reshape(xp, ((n + pad) // blk, blk))
     within = scan(xb, axis=1)
-    offsets = scan(within[:, -1])
+    totals = within[:, -1]
+    if totals.shape[0] > _SCAN_DIRECT_BLOCKS:
+        offsets = _blocked_scan(totals, scan, combine, ident)
+    else:
+        offsets = scan(totals)
     offsets = jnp.concatenate([jnp.full((1,), ident, x.dtype), offsets[:-1]])
     out = jnp.reshape(combine(within, offsets[:, None]), (n + pad,))
     return out[:n] if pad else out
