@@ -228,3 +228,41 @@ def test_repartition_is_an_all_to_all_on_four_chips(topo):
         _shape(rows, (n,), jnp.bool_),
     )
     assert "all-to-all" in compiled.as_text()
+
+
+def test_a_shards_step_is_sliced_where_it_lies_on_four_chips(topo):
+    """The mesh's slab loop (``StreamingAggregator._shard_chunk`` inside a
+    ``fori_loop``) at SF1's shapes: lineitem's four columns row-sharded, each
+    shard padded to 4,194,304 rows, a step of 2,097,152 rows a shard. Every
+    device slices its own rows: no collective moves a row of the table."""
+    from trino_tpu import types as T
+    from trino_tpu.columnar import Batch, Column
+    from trino_tpu.exec.streaming import StreamingAggregator
+
+    mesh = Mesh(np.asarray(topo.devices).reshape(4), (AXIS,))
+    rows, held, cap = NamedSharding(mesh, PS(AXIS)), 1 << 22, 1 << 21
+    sagg = StreamingAggregator.__new__(StreamingAggregator)
+    sagg.mesh = mesh
+
+    def loop(columns, valid, steps):
+        slab = Batch(
+            [Column(T.BIGINT, c, valid if j == 0 else None) for j, c in enumerate(columns)],
+            4 * held,
+        )
+
+        def body(i, acc):
+            chunk = sagg._shard_chunk(slab, i.astype(jnp.int64) * cap, cap)
+            live = chunk[0].valid
+            return acc + sum(jnp.where(live, c.data, 0) for c in chunk)
+
+        return jax.lax.fori_loop(0, steps, body, jnp.zeros(4 * cap, jnp.int64))
+
+    compiled = _compile(
+        loop,
+        [_shape(rows, (4 * held,), jnp.int64) for _ in range(4)],
+        _shape(rows, (4 * held,), jnp.bool_),
+        _shape(NamedSharding(mesh, PS()), (), jnp.int32),
+    )
+    text = compiled.as_text()
+    assert "dynamic-slice" in text
+    assert not [op for op in ("all-gather", "all-to-all", "collective-permute") if op in text]

@@ -363,7 +363,7 @@ class TestSelfTimes:
         got = query_phases(spans)
         assert got["phaseMs"] == {
             "parse": 2.0, "plan": 4.0, "optimize": 5.0, "canonicalize": 1.0,
-            "execute": 100.0, "resultPull": 9.0, "slab": 0.0,
+            "execute": 100.0, "resultPull": 9.0, "build": 0.0, "slab": 0.0,
         }
         assert got["operatorMs"] == {
             "Output": 2.0, "Aggregate": 78.0, "TableScan": 20.0,
@@ -590,8 +590,9 @@ class TestServedDefaultPathSpans:
         stats = info["queryStats"]
         phases, operators = stats["phaseMs"], stats["operatorMs"]
         assert set(phases) == {"parse", "plan", "optimize", "canonicalize",
-                               "execute", "resultPull", "slab"}
-        assert phases["slab"] == 0  # the compiled tier's, inside execute
+                               "execute", "resultPull", "build", "slab"}
+        # the compiled tier's, inside execute
+        assert phases["slab"] == 0 and phases["build"] == 0
         assert set(operators) == set(kinds)
         assert sum(operators.values()) == pytest.approx(phases["execute"], rel=0.01)
         assert stats["queuedMs"] + sum(phases.values()) \
@@ -688,7 +689,7 @@ class TestServedCompiledPathSpans:
         assert phases["execute"] > 0 and 0 < phases["slab"] <= phases["execute"]
         assert phases["execute"] == pytest.approx(execute_plan["durationMs"], abs=0.01)
         assert phases["slab"] == pytest.approx(slab["durationMs"], abs=0.01)
-        sequential = sum(v for k, v in phases.items() if k != "slab")
+        sequential = sum(v for k, v in phases.items() if k not in ("build", "slab"))
         assert stats["queuedMs"] + sequential \
             == pytest.approx(stats["elapsedMs"], rel=0.05, abs=2.0)
         assert info["traceCount"] == 0 and info["programCacheHits"] >= 1

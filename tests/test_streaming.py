@@ -404,11 +404,11 @@ class TestLiteralVariantsThroughTheSlab:
         nth = compiled["seen"].get(shape, 0)
         compiled["seen"][shape] = nth + 1
         if shape == "join":
-            # the step closes over this query's build sides, so it is not
-            # stored and every execution traces it again: ROADMAP S2 (d)
+            # (the cached session's run and the baked one's)
             assert compiled["calls"]["joins"] == before["joins"] + 2
-            assert res.trace_count >= 1
-        elif nth:
+        if nth:
+            # the join's step too: its build sides are arguments (C10), so
+            # the second variant's other build rides the stored program
             assert res.trace_count == 0 and res.program_cache_misses == 0
             assert res.program_cache_hits >= 1
 
@@ -529,11 +529,11 @@ class TestDomainGroupByThroughTheSlab:
                     meta=None):
             res = orig(self, programs, slab, chunk_cols, num_rows, cap, span,
                        meta)
-            program, meta = programs[("slab", self.site, self.G, cap, False)]
-            steps = np.int32((num_rows + cap - 1) // cap)
+            program, meta, _ = programs[("slab", self.site, self.G, cap, False, 1)]
+            rows = np.int64(num_rows[0])
+            steps = np.int32((rows + cap - 1) // cap)
             stored.append((cap, self.G, jax.make_jaxpr(program)(
-                self._init_state(meta), slab, steps, np.int64(num_rows),
-                self.params)))
+                self._init_state(meta), slab, steps, rows, self.params, ())))
             return res
 
         monkeypatch.setattr(S.StreamingAggregator, "_slab_attempt", keeping)
@@ -734,9 +734,9 @@ class TestDomainGroupByThroughTheSlab:
         }
         slab = [k for k in store if kind(k) == "slab"]
         # Q1's key is what it was before the step could widen: the site,
-        # the budget, the session's width, a staged slab
-        assert slab == [("slab", "agg@2#0", 4096, 1 << 21, False)]
-        program, meta = store[next(k for k in slab if k[0] == "slab")]
+        # the budget, the session's width, a staged slab; and the mesh's size
+        assert slab == [("slab", "agg@2#0", 4096, 1 << 21, False, 1)]
+        program, meta, dicts = store[next(k for k in slab if k[0] == "slab")]
         assert callable(program) and meta["slots"] == 12
         held = [
             (k, type(leaf).__name__)
@@ -958,7 +958,7 @@ def test_a_program_the_rule_leaves_alone_lowers_to_the_text_it_had(slab_spans):
     def parent_program(sagg, meta, cap):
         inner = sagg._make_step(meta)
 
-        def program(state, slab, n_steps, num_rows, params):
+        def program(state, slab, n_steps, num_rows, params, builds):
             def body(i, state):
                 off = i.astype(jnp.int64) * cap
                 cnt = jnp.minimum(cap, (num_rows - off).astype(jnp.int32))
@@ -970,7 +970,7 @@ def test_a_program_the_rule_leaves_alone_lowers_to_the_text_it_had(slab_spans):
                         else jax.lax.dynamic_slice_in_dim(c.valid, off, cap, axis=0))
                     cols.append(Column(c.type, data, valid, c.dictionary))
                 live = jnp.arange(cap, dtype=jnp.int32) < cnt
-                return inner(state, Batch(cols, cap, live), None, params)
+                return inner(state, Batch(cols, cap, live), None, params, builds)
 
             return jax.lax.fori_loop(0, n_steps, body, state)
 
@@ -981,9 +981,10 @@ def test_a_program_the_rule_leaves_alone_lowers_to_the_text_it_had(slab_spans):
 
     def lowering(self, programs, slab, chunk_cols, num_rows, cap, span, meta=None):
         res = orig(self, programs, slab, chunk_cols, num_rows, cap, span, meta)
-        _, meta = programs[("slab", self.site, self.G, cap, False)]
-        args = (self._init_state(meta), slab, np.int32(1), np.int64(num_rows),
-                self.params)
+        _, meta, _ = programs[("slab", self.site, self.G, cap, False, 1)]
+        # (no join on the probe spine: no build side among the arguments)
+        args = (self._init_state(meta), slab, np.int32(1), np.int64(num_rows[0]),
+                self.params, ())
         texts.append((cap, [
             jax.jit(make(meta, cap)).lower(*args).as_text()
             for make in (lambda m, c: self._make_slab_program(m, c),
@@ -1035,3 +1036,194 @@ def test_slab_steps_are_those_of_each_aggregates_last_loop(case, spans, want):
     assert read({"infos": infos}) == want
     assert read({"infos": []}) is None
     assert read({"infos": [{"state": "FINISHED", "queryStats": {"aggAttempts": 1}}]}) is None
+
+
+# --- a streamed aggregate over a join: the build sides are arguments (C10) ----
+
+Q3 = """select l.l_orderkey, sum(l.l_extendedprice * (1 - l.l_discount)) as revenue,
+               o.o_orderdate, o.o_shippriority
+        from {customer} c, {orders} o, tpch.tiny.lineitem l
+        where c.c_mktsegment = 'BUILDING' and c.c_custkey = o.o_custkey
+          and l.l_orderkey = o.o_orderkey and o.o_orderdate < date '1995-03-15'
+          and l.l_shipdate > date '1995-03-15'
+        group by l.l_orderkey, o.o_orderdate, o.o_shippriority
+        order by revenue desc, o.o_orderdate limit 10"""
+
+
+def _mesh_runner(devices, **session):
+    r = DistributedQueryRunner(n_devices=devices)
+    r.session.set("stream_scan_threshold_rows", 1)
+    r.session.set("stream_device_chunk_rows", 8192)  # a shard's step
+    for name, value in session.items():
+        r.session.set(name, value)
+    return r
+
+
+@pytest.mark.parametrize("devices", [1, 4])
+def test_a_streamed_join_is_stored_and_answers_from_this_querys_build(devices, slab_spans):
+    """Q3 twice in the compiled session: the second traces nothing and both
+    equal the default session. Then, one program store held across writes (the
+    engine's own is the data version's): a row appended to the build side's
+    table gives the new answer out of the STORED slab program, its build sides
+    being arguments; a build side grown past its capacity is another key, a
+    new program and the right answer."""
+    import numpy as np
+
+    from trino_tpu import types as T
+    from trino_tpu.columnar import Batch, Column
+    from trino_tpu.connectors.api import ColumnSchema, TableSchema
+    from trino_tpu.planner.canonicalize import canonicalize_plan
+    from trino_tpu.sql.parser import parse_statement
+
+    r = _mesh_runner(devices)
+    local = LocalQueryRunner(engine=r.engine)
+    sql = Q3.format(customer="tpch.tiny.customer", orders="tpch.tiny.orders")
+    cold = r.engine.execute_statement(sql, r.session)
+    warm = r.engine.execute_statement(sql, r.session)
+    want = local.execute(sql)[0]
+    assert cold.rows == warm.rows == want and len(want) == 10
+    assert cold.trace_count >= 1
+    assert warm.trace_count == 0 and warm.program_cache_hits >= 1
+    assert [(a["cacheHit"], a["shards"]) for a in slab_spans][-1] == (True, devices)
+
+    # the build side's table in the memory catalog, where it can be written to
+    mem = r.catalogs.get("memory")
+    mem.create_table("default", "orders_m", TableSchema("orders_m", (
+        ColumnSchema("o_orderkey", T.BIGINT), ColumnSchema("o_custkey", T.BIGINT),
+        ColumnSchema("o_orderdate", T.DATE), ColumnSchema("o_shippriority", T.INTEGER))))
+
+    def insert(rows):
+        cols = [Column.from_values(t, [row[i] for row in rows])
+                for i, t in enumerate((T.BIGINT, T.BIGINT, T.DATE, T.INTEGER))]
+        mem.insert("default", "orders_m", Batch(cols, len(rows)))
+
+    orders = local.execute(
+        "select o_orderkey, o_custkey, o_orderdate, o_shippriority from tpch.tiny.orders")[0]
+    insert([(k, c, str(d), p) for k, c, d, p in orders])
+    sql = Q3.format(customer="tpch.tiny.customer", orders="memory.default.orders_m")
+    plan = r.engine.plan(parse_statement(sql), r.session)
+    plan, params, fingerprint = canonicalize_plan(plan, r.session, devices)
+    assert fingerprint is not None
+    programs: dict = {}
+
+    def compiled():
+        del slab_spans[:]
+        res = r.engine._execute_query_plan(plan, r.session, programs=programs, params=params)
+        (slab,) = [a for a in slab_spans if a["attempt"] == 1][-1:]
+        return res.rows, slab["cacheHit"]
+
+    first, hit = compiled()
+    assert first == want and not hit
+    again, hit = compiled()
+    assert again == want and hit
+    # the order whose lines shipped after the date are worth most, given a
+    # second row that qualifies: a BUILDING customer, a date before, priority 7
+    (key, _), = local.execute(
+        "select l_orderkey, sum(l_extendedprice * (1 - l_discount)) from tpch.tiny.lineitem"
+        " where l_shipdate > date '1995-03-15' group by l_orderkey order by 2 desc limit 1")[0]
+    (customer,), = local.execute(
+        "select min(c_custkey) from tpch.tiny.customer where c_mktsegment = 'BUILDING'")[0]
+    insert([(key, customer, "1995-01-01", 7)])
+    written, hit = compiled()
+    assert hit, "a written build side has to ride the stored program as its argument"
+    assert written == local.execute(sql)[0] and written != want
+    assert written[0][0] == key and written[0][3] == 7
+    # 70,000 orders more that qualify (and have no lines): the join below
+    # outgrows its capacity, and the build side comes at a larger one
+    insert([(10_000_000 + i, customer, "1995-01-01", 0) for i in range(70_000)])
+    grown, hit = compiled()
+    assert not hit, "a build side at another capacity is another program"
+    assert grown == written == local.execute(sql)[0]
+    stored = [k for k in programs if isinstance(k, tuple) and k[0] == "slab"]
+    assert len(stored) >= 2 and not [k for k in stored if "id(" in repr(k)]
+    for k in stored:  # site, budget, rows a step, staged, mesh; capacities, builds
+        assert k[1] == "agg@2#0" and k[5] == devices and len(k) == 8
+        assert all(site.split("@")[0] in ("agg", "join", "densejoin", "semi")
+                   for site, _ in k[6] if isinstance(site, str) and "@" in site)
+
+
+def test_the_host_chunk_step_is_stored_on_a_mesh():
+    """C7: where the table is not staged (no room for a slab), the chunks
+    come from the host and the jitted step is stored under a key that holds
+    the mesh's size: a warm query on four devices traces nothing."""
+    r = _mesh_runner(4, stream_device_cache_bytes=0, stream_chunk_rows=4096)
+    sql = Q3.format(customer="tpch.tiny.customer", orders="tpch.tiny.orders")
+    cold = r.engine.execute_statement(sql, r.session)
+    warm = r.engine.execute_statement(sql, r.session)
+    assert cold.rows == warm.rows == LocalQueryRunner(engine=r.engine).execute(sql)[0]
+    assert cold.trace_count >= 1 and warm.trace_count == 0
+    with r.engine._query_cache_lock:
+        keys = [k for e in r.engine._query_cache.values() for k in e["programs"]]
+    (step,) = [k for k in keys if isinstance(k, tuple) and k[0] == "step"]
+    assert step[1] == "agg@2#0" and step[3] == 4
+    assert not [k for k in keys if isinstance(k, tuple) and k[0] == "slab"]
+
+
+def test_the_slab_is_row_sharded_and_a_warm_query_ships_nothing():
+    """Four devices at tpch.tiny: the staged slab's first column lies on all
+    four, none holding over 40% of it (what ``chip_smoke.py::four_chips``
+    asserts of a scanned column); a warm query puts no byte on the devices,
+    sends rows through the exchange, and answers as one device does."""
+    from trino_tpu.connectors.api import slab_shard_rows
+
+    sql = Q3.format(customer="tpch.tiny.customer", orders="tpch.tiny.orders")
+    four, one = _mesh_runner(4), _mesh_runner(1)
+    cold = four.engine.execute_statement(sql, four.session)
+    warm = four.engine.execute_statement(sql, four.session)
+    alone = one.engine.execute_statement(sql, one.session)
+    assert cold.rows == warm.rows == alone.rows and len(warm.rows) == 10
+    assert cold.ingest_stats["h2d_bytes"] > 0 and warm.ingest_stats["h2d_bytes"] == 0
+    assert warm.exchange_stats["shuffle_rows"] > 0 and warm.exchange_stats["exchanges"] == 1
+    assert alone.exchange_stats["shuffle_rows"] == 0
+    ((slab, rows),) = four.catalogs.get("tpch")._device_slabs.values()
+    first = slab.columns[0].data
+    shards = first.addressable_shards
+    assert len({s.device for s in shards}) == 4
+    assert max(s.data.nbytes for s in shards) <= 0.4 * first.nbytes
+    assert rows == 60175 and list(slab_shard_rows(rows, 4)) == [15044, 15044, 15044, 15043]
+    # each shard holds its run of the rows from its first row on
+    held = slab.capacity // 4
+    import numpy as np
+
+    keys = np.asarray(first).reshape(4, held)
+    ((whole, _),) = one.catalogs.get("tpch")._device_slabs.values()
+    assert (np.concatenate([keys[s, :n] for s, n in enumerate(slab_shard_rows(rows, 4))])
+            == np.asarray(whole.columns[0].data)[:rows]).all()
+
+
+def test_the_shards_partial_states_merged_are_one_devices_state():
+    """The share tied to the whole: the group states the four shards of a
+    mesh carry out of the slab loop, merged by key, are the state one device
+    carries out of it over the same rows."""
+    from trino_tpu.exec import streaming as S
+    from trino_tpu.exec.fragments import FragmentedExecutor, _Caps
+    from trino_tpu.planner.canonicalize import canonicalize_plan
+    from trino_tpu.planner.fragmenter import fragment_plan
+    from trino_tpu.sql.parser import parse_statement
+
+    sql = ("select l_suppkey, sum(l_quantity), count(*), min(l_shipdate) from lineitem"
+           " where l_quantity < 30 group by l_suppkey")
+
+    def partial_state(devices):
+        r = _mesh_runner(devices)
+        plan = r.engine.plan(parse_statement(sql), r.session)
+        plan, params, _ = canonicalize_plan(plan, r.session, devices)
+        ex = FragmentedExecutor(r.engine.catalogs, r.session, r.engine.mesh,
+                                programs={}, params=params)
+        frag = next(f for f in fragment_plan(plan).all_fragments()
+                    if S.streamable_chain(f.root) is not None)
+        agg, scan, _ = S.streamable_chain(frag.root)
+        assert agg.step == "partial"
+        sagg = S.StreamingAggregator(ex, frag, agg, scan, _Caps())
+        return sagg.run().batch.compact().to_pylist()
+
+    # key, sum, its count, count(*), min, its count
+    merged: dict = {}
+    shards = partial_state(4)
+    for key, total, n, rows, least, m in shards:
+        have = merged.get(key)
+        merged[key] = (total, n, rows, least, m) if have is None else (
+            have[0] + total, have[1] + n, have[2] + rows, min(have[3], least), have[4] + m)
+    one = {row[0]: tuple(row[1:]) for row in partial_state(1)}
+    assert len(shards) > len(one) == 100, "each shard has to hold a part of a group"
+    assert merged == one

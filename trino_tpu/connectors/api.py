@@ -250,13 +250,26 @@ def slab_padded_rows(rows: int, cap: int) -> int:
     return ((rows + quantum - 1) // quantum) * quantum
 
 
-def slab_bytes_estimate(types: Sequence, rows: int, cap: int) -> int:
-    """Bytes needed to stage ``rows`` of these column types in HBM —
-    measured at the PADDED allocation (wide DECIMALs store (n, 2) int64
-    lanes; +1 byte/row validity), so admission bounds reflect reality."""
+def slab_shard_rows(rows: int, shards: int):
+    """Rows each shard of a slab staged over ``shards`` devices holds: equal
+    runs of the table's rows in order, the last ones short (int64, one a
+    shard; the first is the largest)."""
     import numpy as np
 
-    padded = slab_padded_rows(rows, cap)
+    run = (rows + shards - 1) // shards
+    return np.clip(rows - run * np.arange(shards, dtype=np.int64), 0, run)
+
+
+def slab_bytes_estimate(
+    types: Sequence, rows: int, cap: int, shards: int = 1
+) -> int:
+    """Bytes needed to stage ``rows`` of these column types in HBM, on the
+    fullest of ``shards`` devices — measured at the PADDED allocation (wide
+    DECIMALs store (n, 2) int64 lanes; +1 byte/row validity), so admission
+    bounds reflect reality."""
+    import numpy as np
+
+    padded = slab_padded_rows(int(slab_shard_rows(rows, shards)[0]), cap)
     nbytes = 0
     for t in types:
         width = np.dtype(t.storage_dtype).itemsize
@@ -267,7 +280,8 @@ def slab_bytes_estimate(types: Sequence, rows: int, cap: int) -> int:
 
 
 def stage_device_slab(
-    host_batches: Sequence[Batch], cap: int, stats: Optional[dict] = None
+    host_batches: Sequence[Batch], cap: int, stats: Optional[dict] = None,
+    mesh=None,
 ):
     """Stage host batches into device HBM as ONE slab padded to a
     multiple of ``cap`` rows (so a compiled streaming step can
@@ -275,6 +289,11 @@ def stage_device_slab(
     are unified during the concat. Returns (slab_batch, num_rows). The
     bytes put on the device count into ``stats["h2d_bytes"]`` (the
     query's ``ingestStats``), as a scan's do.
+
+    With a ``mesh`` of several devices the slab is row-sharded over it:
+    shard ``s`` holds its run of the rows (``slab_shard_rows``) from its
+    first row on and is padded like a slab of its own, so every device
+    steps through its rows with the offsets of the others.
 
     Shared by connectors whose data can live device-resident (memory
     pages, generated tpch splits): HBM plays the role the reference's
@@ -286,23 +305,30 @@ def stage_device_slab(
 
     host = concat_batches(list(host_batches))
     total_rows = host.num_rows
-    quantum = max(cap, SLAB_PAD_QUANTUM)
-    padded_rows = ((total_rows + quantum - 1) // quantum) * quantum
-    pad = padded_rows - total_rows
+    shards = 1 if mesh is None else int(mesh.devices.size)
+    runs = slab_shard_rows(total_rows, shards)
+    held = slab_padded_rows(int(runs[0]), cap)  # rows a shard allocates
+    sharding = None
+    if shards > 1:
+        from trino_tpu.parallel.mesh import row_sharding
+
+        sharding = row_sharding(mesh)
+
+    def padded(a):
+        a = np.asarray(a)[:total_rows]
+        out = np.zeros((shards * held,) + a.shape[1:], dtype=a.dtype)
+        for s, run in enumerate(runs):
+            lo = s * int(runs[0])
+            out[s * held : s * held + run] = a[lo : lo + run]
+        return out
+
     cols = []
     nbytes = 0
     for c in host.columns:
-        data, valid = np.asarray(c.data), c.valid
-        if pad:
-            data = np.concatenate(
-                [data, np.zeros((pad,) + data.shape[1:], dtype=data.dtype)]
-            )
-            if valid is not None:
-                valid = np.concatenate(
-                    [np.asarray(valid), np.zeros(pad, dtype=np.bool_)]
-                )
-        dev = jax.device_put(data)
-        dvalid = None if valid is None else jax.device_put(valid)
+        data = padded(c.data)
+        valid = None if c.valid is None else padded(c.valid)
+        dev = jax.device_put(data, sharding)
+        dvalid = None if valid is None else jax.device_put(valid, sharding)
         nbytes += data.nbytes + (0 if valid is None else valid.nbytes)
         cols.append(Column(c.type, dev, dvalid, c.dictionary))
     from trino_tpu.obs.metrics import get_registry
@@ -310,7 +336,7 @@ def stage_device_slab(
     get_registry().counter("trino_tpu_ingest_h2d_bytes_total").inc(nbytes)
     if stats is not None:
         stats["h2d_bytes"] = stats.get("h2d_bytes", 0) + nbytes
-    return Batch(cols, padded_rows), total_rows
+    return Batch(cols, shards * held), total_rows
 
 
 def batch_column_stats(columns, batch) -> dict:

@@ -203,15 +203,20 @@ class H2oConnector(Connector):
 
     def device_slab(
         self, schema, table, columns, cap: int, max_bytes: int,
-        stats: Optional[dict] = None,
+        stats: Optional[dict] = None, mesh=None,
     ):
         """The table's ``columns`` in HBM, each made once on the host (a
         column at a time, so the host holds one) and kept on the device
         for every later query that reads it, padded as
         ``stage_device_slab`` pads. Columns no query of the moment reads
         leave, oldest use first, where the resident ones would pass
-        ``max_bytes``; None where these columns alone would."""
+        ``max_bytes``; None where these columns alone would, and for a
+        ``mesh`` of several devices (the columns live on one; the stream
+        then reads the splits on the host)."""
         import jax
+
+        if mesh is not None:
+            return None
 
         from trino_tpu.obs.metrics import get_registry
 

@@ -80,23 +80,25 @@ class MemoryConnector(Connector):
         self._device.clear()
 
     def device_slab(self, schema, table, columns: Sequence[str], cap: int,
-                    max_bytes: int, stats: Optional[dict] = None):
+                    max_bytes: int, stats: Optional[dict] = None, mesh=None):
         """Stage the table's requested columns into device HBM as ONE slab
         padded to a multiple of ``cap`` rows (so a compiled step can
-        ``dynamic_slice`` any chunk without clamping). Returns
-        (slab_batch, num_rows) or None when the table exceeds
-        ``max_bytes`` (the stream then falls back to host chunking).
+        ``dynamic_slice`` any chunk without clamping), row-sharded over a
+        ``mesh`` of several devices. Returns (slab_batch, num_rows) or None
+        when the table exceeds ``max_bytes`` a device (the stream then
+        falls back to host chunking).
 
-        Cached per (columns, cap, version): repeated queries pay zero
+        Cached per (columns, version, mesh): repeated queries pay zero
         host->device transfer — HBM is this connector's page store."""
         import numpy as np
 
         parts = self._data.get((schema, table))
         if parts is None:
             return None
-        key = (schema, table, tuple(columns), self._version)
+        shards = 1 if mesh is None else int(mesh.devices.size)
+        key = (schema, table, tuple(columns), self._version, mesh)
         hit = self._device.get(key)
-        if hit is not None and hit[0].capacity % cap == 0:
+        if hit is not None and hit[0].capacity // shards % cap == 0:
             return hit
         total_rows = sum(b.num_rows for b in parts)
         if total_rows == 0:
@@ -110,7 +112,7 @@ class MemoryConnector(Connector):
 
         nbytes = slab_bytes_estimate(
             [ts.columns[name_to_idx[c]].type for c in columns],
-            total_rows, cap,
+            total_rows, cap, shards,
         )
         if nbytes > max_bytes:
             return None
@@ -126,6 +128,7 @@ class MemoryConnector(Connector):
             ],
             cap,
             stats,
+            mesh,
         )
         self._device[key] = staged
         return staged

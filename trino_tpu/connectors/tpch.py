@@ -297,13 +297,14 @@ class TpchConnector(Connector):
     # --- data generation -------------------------------------------------
     def device_slab(
         self, schema, table, columns, cap: int, max_bytes: int,
-        stats: Optional[dict] = None,
+        stats: Optional[dict] = None, mesh=None,
     ):
         """Stage a generated table's columns into device HBM once (the
         reference's tpch connector generates into worker pages; HBM is
-        our page store). Bounded by ``max_bytes``; falls back to host
-        chunking beyond it. One slab per (schema, table, columns) —
-        quantum padding lets every chunk-size setting reuse it."""
+        our page store). Bounded by ``max_bytes`` a device; falls back to
+        host chunking beyond it. One slab per (schema, table, columns) and
+        mesh (row-sharded over a ``mesh`` of several devices) — quantum
+        padding lets every chunk-size setting reuse it."""
         scale_factor(schema)  # validates the schema name
         rows = self.estimate_rows(schema, table)
         if rows is None:
@@ -315,13 +316,14 @@ class TpchConnector(Connector):
 
         ts = self.get_table(schema, table)
         by_name = {c.name: c for c in ts.columns}
+        shards = 1 if mesh is None else int(mesh.devices.size)
         if slab_bytes_estimate(
-            [by_name[c].type for c in columns], rows, cap
+            [by_name[c].type for c in columns], rows, cap, shards
         ) > max_bytes:
             return None
-        key = (schema, table, tuple(columns))
+        key = (schema, table, tuple(columns), mesh)
         hit = self._device_slabs.get(key)
-        if hit is not None and hit[0].capacity % cap == 0:
+        if hit is not None and hit[0].capacity // shards % cap == 0:
             return hit
         sf = scale_factor(schema)
         n_splits = max(1, (rows + self.split_rows - 1) // self.split_rows)
@@ -334,7 +336,7 @@ class TpchConnector(Connector):
             cols = gen(sf, i, n_splits, columns=set(columns))
             out = [cols[c] for c in columns]
             parts.append(Batch(out, out[0].data.shape[0] if out else 0))
-        staged = stage_device_slab(parts, cap, stats)
+        staged = stage_device_slab(parts, cap, stats, mesh)
         self._device_slabs[key] = staged
         return staged
 

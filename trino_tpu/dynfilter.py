@@ -210,27 +210,25 @@ def _replace_join_sides(node: P.Join, left: P.PlanNode, right: P.PlanNode) -> P.
     )
 
 
-def collect_and_push(
-    plan_node: P.PlanNode,
+def probe_domain(
     probe_sym: P.Symbol,
     build_sym: P.Symbol,
     data: np.ndarray,
     valid: Optional[np.ndarray],
     build_rows: int,
     stats_out: Optional[list],
-) -> P.PlanNode:
-    """Shared per-criteria DF core used by the interpreter join and the
-    fragment-level paths: build domain -> coerce to the probe type ->
-    record stats -> push into the probe plan."""
+) -> Optional[Domain]:
+    """Per-criterion DF core: build domain -> coerce to the probe type ->
+    record stats. None where there is nothing to push."""
     data = np.asarray(data)
     if data.ndim != 1:
-        return plan_node  # wide-decimal (hi, lo) lanes: no host domain
+        return None  # wide-decimal (hi, lo) lanes: no host domain
     domain = domain_from_build(data, valid, build_sym.type)
     if domain is None or domain.is_all():
-        return plan_node
+        return None
     domain = convert_domain(domain, build_sym.type, probe_sym.type)
     if domain is None or domain.is_all():
-        return plan_node
+        return None
     if stats_out is not None:
         dv = domain.values.discrete_values()
         stats_out.append(
@@ -243,6 +241,25 @@ def collect_and_push(
                 build_rows,
             )
         )
+    return domain
+
+
+def collect_and_push(
+    plan_node: P.PlanNode,
+    probe_sym: P.Symbol,
+    build_sym: P.Symbol,
+    data: np.ndarray,
+    valid: Optional[np.ndarray],
+    build_rows: int,
+    stats_out: Optional[list],
+) -> P.PlanNode:
+    """The interpreter join's DF: ``probe_domain`` pushed into the probe
+    plan."""
+    domain = probe_domain(
+        probe_sym, build_sym, data, valid, build_rows, stats_out
+    )
+    if domain is None:
+        return plan_node
     return push_probe_domain(plan_node, probe_sym, domain)
 
 
@@ -251,6 +268,8 @@ def fragment_dynamic_filters(
     build_lookup,
     session,
     stats_out: Optional[list] = None,
+    memo: Optional[dict] = None,
+    memo_key=None,
 ) -> P.PlanNode:
     """Fragment-level dynamic filtering for fused/cluster execution.
 
@@ -267,13 +286,18 @@ def fragment_dynamic_filters(
     arrays for one build column (or None), or None when the upstream
     result is unavailable (e.g. sharded across hosts).
 
+    ``memo`` (a program store) keeps the last rewrite under ``memo_key``:
+    where this run pushes the same domains into the same ``root``, the
+    rewritten root of before is returned, the same object, so a fragment
+    program keyed by its root is found again instead of traced again.
+
     Reference: ``server/DynamicFilterService.java:95,323`` — here the
     stage-at-a-time schedule makes the filter exact and synchronous.
     """
     if not session.get("enable_dynamic_filtering"):
         return root
     max_rows = int(session.get("dynamic_filtering_max_build_rows"))
-    new_root = root
+    pushes: list[tuple[P.Symbol, Domain]] = []
     for node in P.walk_plan(root):
         if (
             not isinstance(node, P.Join)
@@ -293,8 +317,21 @@ def fragment_dynamic_filters(
             if pair is None:
                 continue
             data, valid = pair
-            new_root = collect_and_push(
-                new_root, probe_sym, build_sym, data, valid,
-                int(n_rows), stats_out,
+            domain = probe_domain(
+                probe_sym, build_sym, data, valid, int(n_rows), stats_out
             )
+            if domain is not None:
+                pushes.append((probe_sym, domain))
+    if not pushes:
+        return root
+    pushed = tuple((sym.name, domain) for sym, domain in pushes)
+    if memo is not None:
+        last = memo.get(memo_key)
+        if last is not None and last[0] is root and last[1] == pushed:
+            return last[2]
+    new_root = root
+    for sym, domain in pushes:
+        new_root = push_probe_domain(new_root, sym, domain)
+    if memo is not None:
+        memo[memo_key] = (root, pushed, new_root)
     return new_root

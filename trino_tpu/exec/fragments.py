@@ -1069,6 +1069,8 @@ class FragmentedExecutor(DistributedExecutor):
             counter_vals = list(extra_vals[len(deferred):])
             overflowed = False
             span = get_tracer().current()
+            if span is not None:
+                span.set("meshDevices", int(self.mesh.devices.size))
             if span is not None and any(
                 nm.startswith("agg") for _, names, _, _ in deferred for nm in names
             ):
@@ -1271,7 +1273,8 @@ class FragmentedExecutor(DistributedExecutor):
         members = []
         for frag in unit.fragments:
             root = fragment_dynamic_filters(
-                frag.root, lookup, self.session, self.dynamic_filters
+                frag.root, lookup, self.session, self.dynamic_filters,
+                memo=self.programs, memo_key=("dfroot", frag.id),
             )
             members.append(dataclasses.replace(frag, root=root))
 
@@ -1330,6 +1333,7 @@ class FragmentedExecutor(DistributedExecutor):
         if aux:
             self._hot_sets[unit.id] = aux
         span.set("mode", "fused-pipeline")
+        self._note_exchange(span)
         if sink:
             span.set("attempts", sink.get("attempts", 1))
         get_registry().counter("trino_tpu_fused_programs_total").inc()
@@ -1399,6 +1403,8 @@ class FragmentedExecutor(DistributedExecutor):
             self._df_build_lookup(results),
             self.session,
             self.dynamic_filters,
+            memo=self.programs,
+            memo_key=("dfroot", frag.id),
         )
         frag = dataclasses.replace(frag, root=root)
 
@@ -1453,6 +1459,7 @@ class FragmentedExecutor(DistributedExecutor):
         if aux:
             self._hot_sets[frag.id] = aux
         span.set("mode", "fused")
+        self._note_exchange(span)
         if sink:
             span.set("attempts", sink.get("attempts", 1))
         if self.stats_collector is not None:
@@ -1565,10 +1572,28 @@ class FragmentedExecutor(DistributedExecutor):
 
             return post
 
-        return self._retry_traced(
-            caps, build_post, (res.batch,), program_key=("post", frag.id),
-            defer=True,
-        )
+        with get_tracer().span("exchange") as span:
+            out = self._retry_traced(
+                caps, build_post, (res.batch,), program_key=("post", frag.id),
+                defer=True,
+            )
+            self._note_exchange(span, frag.output_exchange)
+        return out
+
+    def _note_exchange(self, span, kind: Optional[str] = None) -> None:
+        """The exchanges of the program ``_retry_traced`` ran last, on the
+        span that dispatched it: ``exchange`` where the exchange is a program
+        of its own, else the fused program's. ``rows`` and ``bytes`` are what
+        the mesh's devices put on the wire, padding included (static; the
+        live rows come with the deferred pull: ``exchangeStats.shuffle_rows``)."""
+        static = getattr(self, "_last_exchange_static", None) or {}
+        if kind is None and not static.get("exchanges"):
+            return
+        span.set("kind", kind or "hash")
+        span.set("exchanges", static.get("exchanges", 0))
+        span.set("rows", static.get("padded_shuffle_rows", 0))
+        span.set("bytes", static.get("shuffle_bytes", 0))
+        span.set("devices", int(self.mesh.devices.size))
 
     def _retry_traced(
         self,
@@ -1727,6 +1752,7 @@ class FragmentedExecutor(DistributedExecutor):
                     program_label(program_key), meta.device_stats, compile_ms
                 )
             self._last_aux = aux
+            self._last_exchange_static = meta.exchange_static or {}
             if defer and getattr(self, "deferred_flags", None) is not None:
                 if flags:
                     stacked = jnp.stack([jnp.reshape(f, ()) for f in flags])

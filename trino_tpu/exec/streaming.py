@@ -27,8 +27,14 @@ literals (``planner/canonicalize.py``) as its ``params`` ARGUMENT, the
 ``__params__`` of a fragment program. The engine executes one cached plan
 object for every query of a fingerprint, so a program that closed over a
 literal's value would answer each later variant with the first one's rows.
+The same rule for the build sides of probe-spine joins: ``_prebuild``
+materializes them anew every query and every program takes them as its
+``builds`` ARGUMENT, so a stored program answers from this query's builds.
 What goes into the executor's program store is keyed by content (the
-aggregate's fragment id and ordinal), never by ``id(node)``."""
+aggregate's fragment id and ordinal, the capacities by their stable site
+names, the build batches' capacities and schemas, the mesh's size), never by
+``id(node)``; the dictionaries a program was traced against are held beside
+it and compared by identity on a hit."""
 
 from __future__ import annotations
 
@@ -42,6 +48,7 @@ from jax.sharding import NamedSharding, PartitionSpec as PS
 
 from trino_tpu import types as T
 from trino_tpu.columnar import Batch, Column, bucket_capacity
+from trino_tpu.connectors.api import slab_shard_rows
 from trino_tpu.exec.local import Result
 from trino_tpu.obs.trace import get_tracer
 from trino_tpu.ops.aggregation import (
@@ -155,7 +162,10 @@ class StreamingAggregator:
         self.build_roots = list(build_roots)
         self.build_inputs = build_inputs or {}
         self.build_layouts = build_layouts or {}
-        self._prememo: Optional[dict] = None
+        # this query's materialized build sides, in ``build_roots``' order:
+        # batches (an ARGUMENT of every program built here) and layouts
+        self._builds: Optional[tuple] = None
+        self._build_layouts: list[dict] = []
         self.nkeys = len(agg_node.group_keys)
         from trino_tpu.exec.fragments import agg_site
 
@@ -176,23 +186,46 @@ class StreamingAggregator:
         self._sensitive_dicts: set[int] = set()
 
     def _prebuild(self) -> None:
-        """Materialize the build sides of probe-spine joins once (device
-        resident for the whole stream). Their overflow flags join the
-        deferred check; build capacities grow through the same retry."""
-        if self._prememo is not None or not self.build_roots:
-            self._prememo = self._prememo or {}
+        """Materialize the build sides of probe-spine joins once a query
+        (device resident for the whole stream), under the span
+        ``stream.build``. Their overflow flags join the deferred check;
+        build capacities grow through the same retry. Each batch is
+        normalized to one pytree shape (``num_rows`` its capacity, the live
+        rows in ``sel``), so a table that gained a row is the same argument
+        shape to a stored program."""
+        if self._builds is not None:
+            return
+        if not self.build_roots:
+            self._builds = ()
             return
         from trino_tpu.exec.fragments import _FragmentTracer
 
-        tracer = _FragmentTracer(
-            self.executor,
-            self._with_params(self.build_inputs, self.params),
-            self.build_layouts,
-            self.caps,
-        )
-        self._prememo = {}
-        for root in self.build_roots:
-            self._prememo[id(root)] = tracer._exec(root)
+        with get_tracer().span(
+            "stream.build", attrs={"site": self.site}
+        ) as span:
+            tracer = _FragmentTracer(
+                self.executor,
+                self._with_params(self.build_inputs, self.params),
+                self.build_layouts,
+                self.caps,
+            )
+            builds = []
+            for root in self.build_roots:
+                res = tracer._exec(root)
+                b = res.batch
+                if b.sel is None or b.num_rows != b.capacity:
+                    b = Batch(b.columns, b.capacity, b.selection_mask())
+                builds.append(b)
+                self._build_layouts.append(dict(res.layout))
+            self._builds = tuple(builds)
+            span.set("builds", len(builds))
+            span.set("capacities", [b.capacity for b in builds])
+            # the one wait of the span: the build sides' live rows, which
+            # the device has once the fragments below have run
+            live = np.asarray(
+                jnp.stack([jnp.sum(b.sel, dtype=jnp.int64) for b in builds])
+            )
+            span.set("rows", int(live.sum()))
         if tracer.overflows:
             names = [nm for nm, _ in tracer.overflows]
             flags = jnp.stack(
@@ -207,6 +240,47 @@ class StreamingAggregator:
                     raise StreamOverflow(
                         {nm: int(f) for nm, f in zip(names, fired) if f}
                     )
+
+    def _program_key(self, kind: str, *shape) -> tuple:
+        """A stored program's key, by content: the aggregate's site, its
+        budget and ``shape`` (the step's rows and what else the caller's
+        program was traced at). With build sides also every capacity the
+        trace consulted, under its restart-stable site name, and each build
+        batch's capacity and column types: a build that outgrew its
+        capacity is another key, never a program run on the wrong shape."""
+        key = (kind, self.site, self.G) + shape
+        if not self.build_roots:
+            return key
+        caps = self.caps
+        sited = [(caps.sites.get(nm, nm), v) for nm, v in caps.vals.items()]
+        # (the fragment's output exchange sizes a program of its own)
+        held = tuple(sorted(
+            sv for sv in sited
+            if not sv[0].startswith(("exch@", "spill@", "hot@"))
+        )) + tuple(sorted(caps.demoted))
+        builds = tuple((b.capacity, _columns_shape(b)) for b in self._builds)
+        return key + (held, builds)
+
+    def _traced_dicts(self, *batches) -> tuple:
+        """The dictionaries of the build sides and of ``batches``: static
+        data of a traced program (``Column``'s pytree aux), held beside the
+        stored program and compared by identity (``_stored``) before it
+        is called again. An equal key over other dictionaries is a miss,
+        and the program traced for it takes the entry."""
+        return tuple(
+            c.dictionary
+            for b in (*self._builds, *batches) if b is not None
+            for c in b.columns
+        )
+
+    @staticmethod
+    def _stored(programs, key: tuple, dicts: tuple):
+        """The store's ``(program, meta, dicts)`` under ``key`` if it was
+        traced against these very dictionaries, else None."""
+        hit = programs.get(key) if programs is not None else None
+        if hit is None or len(hit[2]) != len(dicts):
+            return None
+        return hit if all(x is y for x, y in zip(hit[2], dicts)) else None
 
     # === chunk source ====================================================
 
@@ -308,9 +382,22 @@ class StreamingAggregator:
     def run(self) -> Result:
         chunk_rows = int(self.executor.session.get("stream_chunk_rows"))
         self._prebuild()
-        res = self._run_device_slab(chunk_rows)
-        if res is not None:
+        try:
+            res = self._run_device_slab(chunk_rows)
+            if res is None:
+                res = self._run_host_chunks(chunk_rows)
             return res
+        finally:
+            # a stored program's closure keeps this object: not this
+            # query's build sides nor what they were made from
+            self._builds = None
+            self.build_inputs = {}
+
+    def _run_host_chunks(self, chunk_rows: int) -> Result:
+        """The table read split by split on the host, a padded chunk a
+        step. The jitted step is stored like the slab program, under a key
+        that holds the mesh's size and the chunk's shape, so a warm query
+        traces nothing on any mesh."""
         it = self._chunks(chunk_rows)
         first = next(it, None)
         if first is None:
@@ -319,23 +406,52 @@ class StreamingAggregator:
             raise FusedUnsupported("streaming scan with zero splits")
         parts, cap = first
         chunk, counts = _pad_batch(self.mesh, parts, cap)
-        meta = self._collect_meta(chunk)
-        state = self._init_state(meta)
-        step = jax.jit(self._make_step(meta), donate_argnums=(0,))
-        # the real trace happens on this first call — log dictionary
-        # accesses here too (eval_shape in _collect_meta covers the same
-        # path, but belt-and-braces keeps the invalidation set complete)
-        from trino_tpu.columnar import Dictionary
+        programs = getattr(self.executor, "programs", None)
 
-        prev_log = Dictionary.begin_trace_log()
-        try:
-            state = step(state, chunk, counts, self.params)
-        finally:
-            log = Dictionary.end_trace_log(prev_log)
-        self._sensitive_dicts |= set(log.get("growth_sensitive", ()))
+        def key():
+            return self._program_key(
+                "step", self.n, cap, counts is None, chunk.sel is None,
+                _columns_shape(chunk),
+            )
+
+        dicts = self._traced_dicts(chunk)
+        hit = self._stored(programs, key(), dicts)
+        if hit is not None:
+            step, meta, _ = hit
+            self._sensitive_dicts = set(meta["sensitive_dicts"])
+            self.executor.count_program(hit=True)
+            state = step(
+                self._init_state(meta), chunk, counts, self.params, self._builds
+            )
+        else:
+            meta = self._collect_meta(chunk)
+            step = jax.jit(self._make_step(meta), donate_argnums=(0,))
+            # the real trace happens on this first call — log dictionary
+            # accesses here too (eval_shape in _collect_meta covers the same
+            # path, but belt-and-braces keeps the invalidation set complete)
+            from trino_tpu.columnar import Dictionary
+
+            prev_log = Dictionary.begin_trace_log()
+            t0 = time.perf_counter()
+            try:
+                state = step(
+                    self._init_state(meta), chunk, counts, self.params,
+                    self._builds,
+                )
+            finally:
+                log = Dictionary.end_trace_log(prev_log)
+            self._sensitive_dicts |= set(log.get("growth_sensitive", ()))
+            meta["sensitive_dicts"] = frozenset(self._sensitive_dicts)
+            self.executor.count_program(
+                hit=False,
+                compile_ms=(time.perf_counter() - t0) * 1000.0,
+                stored=programs is not None,
+            )
+            if programs is not None:
+                programs[key()] = (step, meta, dicts)
         for parts, cap in it:
             chunk, counts = _pad_batch(self.mesh, parts, cap)
-            state = step(state, chunk, counts, self.params)
+            state = step(state, chunk, counts, self.params, self._builds)
         self._check_overflow(state, None, meta)
         return self._finish(state, meta)
 
@@ -363,13 +479,14 @@ class StreamingAggregator:
         ``dynamic_slice`` INSIDE the compiled step — zero per-chunk host
         work or host->device transfer, one dispatch per chunk.
 
-        Single-device meshes only (a sharded slab would need per-shard
-        offsets; multi-device streams use the host chunk path)."""
-        if self.n != 1:
-            return None
+        On a mesh of several devices the connector stages the table
+        row-sharded (``slab_shard_rows``: each device an equal run of the
+        rows, padded alike), and every device walks its own rows: a step is
+        ``cap`` rows of each shard, sliced inside a ``shard_map``."""
         connector = self.executor.catalogs.get(self.scan.catalog)
         # device chunks can be much larger than host chunks (no transfer
-        # to overlap, and fewer dispatches beat smaller sorts)
+        # to overlap, and fewer dispatches beat smaller sorts); on a mesh
+        # the width is each device's
         cap = bucket_capacity(
             max(1, int(self.executor.session.get("stream_device_chunk_rows")))
         )
@@ -384,14 +501,14 @@ class StreamingAggregator:
             stats.setdefault("h2d_bytes", 0)
             staged = stage(
                 self.scan.schema, self.scan.table, self.scan.column_names,
-                cap, limit, stats,
+                cap, limit, stats, self.mesh if self.n > 1 else None,
             )
             if staged is not None:
                 slab, num_rows = staged
-                cap = min(cap, slab.capacity)
+                cap = min(cap, slab.capacity // self.n)
         if slab is None:
             gen = getattr(connector, "device_generator", None)
-            if gen is None:
+            if gen is None or self.n > 1:
                 return None
             spec = gen(self.scan.schema, self.scan.table, self.scan.column_names)
             if spec is None:
@@ -400,10 +517,8 @@ class StreamingAggregator:
             if num_rows <= 0:
                 return None
         programs = getattr(self.executor, "programs", None)
-        if self.build_roots:
-            # the step closes over this query's materialized build
-            # batches; a cached program would pin stale builds
-            programs = None
+        # rows each shard holds (one shard: all of them), the loop's bound
+        shard_rows = slab_shard_rows(num_rows, self.n)
         # the step widens with the group budget (``slab_step_rows``); which
         # way the step groups is asked only once the budget could widen it
         base, meta = cap, None
@@ -411,7 +526,8 @@ class StreamingAggregator:
             meta = self._probe_meta(slab, chunk_cols, base)
             cap = slab_step_rows(
                 base, self.G, not meta["slots"],
-                slab.capacity if slab is not None else bucket_capacity(num_rows),
+                slab.capacity // self.n
+                if slab is not None else bucket_capacity(num_rows),
             )
         # wide pipelines (many payload lanes) can exceed scoped vmem at
         # large chunk sizes: on a compile failure, halve the chunk (a wide
@@ -429,16 +545,17 @@ class StreamingAggregator:
                 "stream.slab",
                 attrs={
                     "site": self.site,
-                    "steps": (num_rows + cap - 1) // cap,
+                    "steps": (int(shard_rows[0]) + cap - 1) // cap,
                     "cap": cap,
                     "baseCap": base,
+                    "shards": self.n,
                     "groups": self.G,
                     "params": len(self.params or ()),
                     "attempt": attempt,
                 },
             ) as span:
                 res = self._slab_attempt(
-                    programs, slab, chunk_cols, num_rows, cap, span, meta
+                    programs, slab, chunk_cols, shard_rows, cap, span, meta
                 )
             if res is not None:
                 if attempt > 1 and programs is not None:
@@ -447,9 +564,10 @@ class StreamingAggregator:
             cap //= 2
 
     def _probe_meta(self, slab, chunk_cols, cap: int) -> dict:
-        """``_collect_meta`` over an abstract chunk of ``cap`` rows of the
-        slab (or of the connector's generator): nothing in it depends on
-        ``cap``."""
+        """``_collect_meta`` over an abstract chunk of ``cap`` rows of each
+        shard of the slab (or of the connector's generator): nothing in it
+        depends on ``cap``."""
+        cap *= self.n
         if slab is not None:
             probe_cols = [
                 Column(
@@ -483,31 +601,36 @@ class StreamingAggregator:
         return self._collect_meta(probe_chunk, resident=slab is not None)
 
     def _slab_attempt(
-        self, programs, slab, chunk_cols, num_rows: int, cap: int, span,
+        self, programs, slab, chunk_cols, shard_rows, cap: int, span,
         meta: Optional[dict] = None,
     ) -> Optional[Result]:
         """One run of the slab program at chunk size ``cap``: the stored
         program if there is one, else trace, compile and store it (``meta``
         where the caller has probed it already). None when the compiler
         refused the size and a halved ``cap`` may fit."""
-        n_steps = (num_rows + cap - 1) // cap
-        prog_key = ("slab", self.site, self.G, cap, slab is None)
-        hit = programs.get(prog_key) if programs is not None else None
+        n_steps = np.int32((int(shard_rows[0]) + cap - 1) // cap)
+        # one device: the table's row count; a mesh: each shard's
+        rows = np.int64(shard_rows[0]) if self.n == 1 else shard_rows
+        dicts = self._traced_dicts(slab)
+
+        def key():
+            return self._program_key("slab", cap, slab is None, self.n)
+
+        hit = self._stored(programs, key(), dicts)
         span.set("cacheHit", hit is not None)
         if hit is not None:
-            program, meta = hit
+            program, meta, _ = hit
             self._note_group_by(span, meta)
-            state = self._init_state(meta)
             state = program(
-                state, slab, np.int32(n_steps), np.int64(num_rows), self.params
+                self._init_state(meta), slab, n_steps, rows, self.params,
+                self._builds,
             )
             self.executor.count_program(hit=True)
-            self._check_overflow(state, prog_key, meta)
+            self._check_overflow(state, key(), meta)
             return self._finish(state, meta)
         if meta is None:
             meta = self._probe_meta(slab, chunk_cols, cap)
         self._note_group_by(span, meta)
-        state = self._init_state(meta)
         program = jax.jit(
             self._make_slab_program(meta, cap, chunk_cols),
             donate_argnums=(0,),
@@ -515,7 +638,8 @@ class StreamingAggregator:
         t0 = time.perf_counter()
         try:
             state = program(
-                state, slab, np.int32(n_steps), np.int64(num_rows), self.params
+                self._init_state(meta), slab, n_steps, rows, self.params,
+                self._builds,
             )
         except jax.errors.JaxRuntimeError as e:
             msg = str(e).lower()
@@ -534,8 +658,9 @@ class StreamingAggregator:
             stored=programs is not None,
         )
         if programs is not None:
-            programs[prog_key] = (program, meta)
-        self._check_overflow(state, prog_key, meta)
+            # (the key now holds the capacities the trace consulted)
+            programs[key()] = (program, meta, dicts)
+        self._check_overflow(state, key(), meta)
         return self._finish(state, meta)
 
     def _note_group_by(self, span, meta: dict) -> None:
@@ -552,49 +677,90 @@ class StreamingAggregator:
         generator (``chunk_cols``) — and folds it into the carried
         accumulators. One dispatch per query regardless of table size,
         and the dynamic trip count means one compilation serves any row
-        count."""
+        count. On a mesh chunk i is rows ``[i*cap, (i+1)*cap)`` of every
+        shard (``_shard_chunk``); ``rows`` is then the shards' row counts."""
         inner = self._make_step(meta)
+        n = self.n
 
-        def body_for(slab, num_rows, params):
+        def body_for(slab, rows, params, builds):
             def body(i, state):
                 # int64 offset: i*cap wraps int32 past 2^31 rows (the
                 # generator path has no table-size bound)
                 off = i.astype(jnp.int64) * cap
-                cnt = jnp.minimum(cap, (num_rows - off).astype(jnp.int32))
+                # rows left: the table's, or on a mesh each shard's
+                cnt = jnp.minimum(cap, (rows - off).astype(jnp.int32))
                 skip = None
                 if slab is not None:
+                    held = slab.capacity // n  # rows a shard holds, padded
                     start = off
-                    if slab.capacity % cap:
+                    if held % cap:
                         # a wide step that does not divide the padded
                         # rows: the last one starts where it still fits
                         # and masks the rows the step before it read
-                        start = jnp.minimum(off, slab.capacity - cap)
+                        start = jnp.minimum(off, held - cap)
                         skip = (off - start).astype(jnp.int32)
-                    cols = []
-                    for c in slab.columns:
-                        data = jax.lax.dynamic_slice_in_dim(c.data, start, cap, axis=0)
-                        valid = (
-                            None
-                            if c.valid is None
-                            else jax.lax.dynamic_slice_in_dim(c.valid, start, cap, axis=0)
-                        )
-                        cols.append(Column(c.type, data, valid, c.dictionary))
+                    if n > 1:
+                        cols = self._shard_chunk(slab, start, cap)
+                    else:
+                        cols = [
+                            Column(
+                                c.type,
+                                jax.lax.dynamic_slice_in_dim(c.data, start, cap, axis=0),
+                                None
+                                if c.valid is None
+                                else jax.lax.dynamic_slice_in_dim(c.valid, start, cap, axis=0),
+                                c.dictionary,
+                            )
+                            for c in slab.columns
+                        ]
                 else:
                     cols = chunk_cols(off, cap)
-                pos = jnp.arange(cap, dtype=jnp.int32)
+                pos = jnp.arange(n * cap, dtype=jnp.int32)
+                if n > 1:
+                    # each row's place in its shard's step, and that shard's
+                    cnt, pos = cnt[pos // cap], pos % cap
                 live = pos < cnt
                 if skip is not None:
                     live = (pos >= skip) & (pos - skip < cnt)
-                return inner(state, Batch(cols, cap, live), None, params)
+                return inner(
+                    state, Batch(cols, n * cap, live), None, params, builds
+                )
 
             return body
 
-        def program(state, slab, n_steps, num_rows, params):
+        def program(state, slab, n_steps, rows, params, builds):
             return jax.lax.fori_loop(
-                0, n_steps, body_for(slab, num_rows, params), state
+                0, n_steps, body_for(slab, rows, params, builds), state
             )
 
         return program
+
+    def _shard_chunk(self, slab: Batch, start, cap: int) -> list[Column]:
+        """Rows ``[start, start + cap)`` of every shard of a row-sharded
+        slab, as the columns of one chunk of ``n * cap`` rows sharded alike:
+        each device slices what it holds, nothing moves."""
+        arrays = [
+            a for c in slab.columns for a in (c.data, c.valid) if a is not None
+        ]
+
+        def take(at, *held):
+            return tuple(
+                jax.lax.dynamic_slice_in_dim(a, at, cap, axis=0) for a in held
+            )
+
+        taken = iter(smap(
+            take,
+            mesh=self.mesh,
+            in_specs=(PS(),) + (PS(AXIS),) * len(arrays),
+            out_specs=(PS(AXIS),) * len(arrays),
+        )(start, *arrays))
+        return [
+            Column(
+                c.type, next(taken),
+                None if c.valid is None else next(taken), c.dictionary,
+            )
+            for c in slab.columns
+        ]
 
     # === metadata (abstract pass over the first chunk) ===================
 
@@ -604,11 +770,12 @@ class StreamingAggregator:
         ``_FragmentTracer`` (and with it every ``ExprCompiler``) reads them."""
         return inputs if params is None else {**inputs, "__params__": params}
 
-    def _tracer_for(self, chunk: Batch, params):
-        """The chunk's tracer. ``params`` is the caller's OWN argument: the
-        traced tuple inside a compiled step, the abstract one under
-        ``_collect_meta``'s ``eval_shape``; never ``self.params`` from inside
-        a stored program, which would bake the first query's literals in."""
+    def _tracer_for(self, chunk: Batch, params, builds):
+        """The chunk's tracer. ``params`` and ``builds`` are the caller's
+        OWN arguments: the traced ones inside a compiled step, the abstract
+        ones under ``_collect_meta``'s ``eval_shape``; never ``self.params``
+        or ``self._builds`` from inside a stored program, which would bake
+        the first query's literals and build sides in."""
         from trino_tpu.exec.fragments import _FragmentTracer
 
         tracer = _FragmentTracer(
@@ -621,11 +788,13 @@ class StreamingAggregator:
             },
             self.caps,
         )
-        if self._prememo:
-            # build sides of probe-spine joins: already materialized, so
-            # the chunk trace consumes them as constants instead of
-            # re-executing the build per chunk
-            tracer._memo.update(self._prememo)
+        # build sides of probe-spine joins: already materialized, so the
+        # chunk trace reads them as its arguments instead of re-executing
+        # the build per chunk
+        for root, layout, batch in zip(
+            self.build_roots, self._build_layouts, builds
+        ):
+            tracer._memo[id(root)] = Result(batch, dict(layout))
         return tracer
 
     def _chunk_prep(self, tracer):
@@ -654,8 +823,8 @@ class StreamingAggregator:
 
         box = {}
 
-        def probe(ch, params):
-            tracer = self._tracer_for(ch, params)
+        def probe(ch, params, builds):
+            tracer = self._tracer_for(ch, params, builds)
             agg_inputs, specs, string_dicts, keys, key_dicts, sel = (
                 self._chunk_prep(tracer)
             )
@@ -677,7 +846,7 @@ class StreamingAggregator:
 
         prev_log = Dictionary.begin_trace_log()
         try:
-            jax.eval_shape(probe, chunk, self.params)
+            jax.eval_shape(probe, chunk, self.params, self._builds)
         finally:
             log = Dictionary.end_trace_log(prev_log)
         self._sensitive_dicts = set(log.get("growth_sensitive", ()))
@@ -745,7 +914,7 @@ class StreamingAggregator:
         nspec = len(specs)
         sagg = self
 
-        def step(state, chunk: Batch, counts, params):
+        def step(state, chunk: Batch, counts, params, builds):
             if counts is not None:
                 # per-shard valid-row counts (dynamic) instead of a host
                 # mask: tail chunks keep the same pytree structure, so the
@@ -754,7 +923,7 @@ class StreamingAggregator:
                 pos = jnp.arange(chunk.capacity, dtype=jnp.int32)
                 live = pos % cap < counts[pos // cap]
                 chunk = Batch(chunk.columns, chunk.num_rows, live)
-            tracer = sagg._tracer_for(chunk, params)
+            tracer = sagg._tracer_for(chunk, params, builds)
             agg_inputs, _specs, _sd, keys, _kd, sel = sagg._chunk_prep(tracer)
             prev_ovf = state["overflow"]
             if nkeys == 0:
@@ -1077,6 +1246,14 @@ class StreamingAggregator:
 
 
 # === host-side batch helpers ================================================
+
+
+def _columns_shape(b: Batch) -> tuple:
+    """What a traced program holds of a batch's columns beside its rows:
+    each column's type, storage and whether it carries a validity mask."""
+    return tuple(
+        (str(c.type), str(c.data.dtype), c.valid is None) for c in b.columns
+    )
 
 
 def _slice_rows(b: Batch, lo: int, hi: int) -> Batch:

@@ -443,13 +443,16 @@ def self_times(
     return out
 
 
-#: span name -> the ``phaseMs`` key that sums its durations. ``slab`` (the
-#: compiled tier's streamed slab program, lookup to result) lies inside
-#: ``execute``; the others follow one another
+#: span name -> the ``phaseMs`` key that sums its durations. ``build`` (a
+#: streamed aggregate's build sides made ready, with the wait for the
+#: fragments that compute them) and ``slab`` (the compiled tier's streamed
+#: slab program, lookup to result) lie inside ``execute``, one after the
+#: other; the others follow one another
 PHASE_OF_SPAN = {
     "parse": "parse", "plan": "plan", "optimize": "optimize",
     "canonicalize": "canonicalize", "execute_plan": "execute",
-    "result.pull": "resultPull", "stream.slab": "slab",
+    "result.pull": "resultPull", "stream.build": "build",
+    "stream.slab": "slab",
 }
 OPERATOR_PREFIX = "op:"
 
@@ -490,14 +493,17 @@ def aggregate_counts(spans: Iterable[Dict[str, Any]]) -> Dict[str, Any]:
     client. ``slabSteps``: steps of the slab loops whose answer the query
     kept, each streamed aggregate's last ``stream.slab`` span (the passes
     before it outgrew a budget, the attempts before it a width the compiler
-    refused). The first two are absent where no grouped aggregate ran, the
-    last where nothing streamed through a slab program."""
-    attempts = growths = rows = 0
+    refused). ``meshDevices``: devices of the mesh the compiled session ran
+    the plan on (``execute_plan``'s attribute). The first two are absent
+    where no grouped aggregate ran, ``slabSteps`` where nothing streamed
+    through a slab program, ``meshDevices`` in the default session."""
+    attempts = growths = rows = devices = 0
     last: Dict[Any, Tuple[int, int]] = {}  # site -> (start, steps), the latest
     for s in spans:
         attrs = s.get("attrs") or {}
         attempts += attrs.get("aggAttempts", 0)
         growths += attrs.get("groupBudgetGrowths", 0)
+        devices = max(devices, attrs.get("meshDevices", 0))
         if s["name"] == "result.pull":
             rows += attrs.get("rows", 0)
         elif s["name"] == "stream.slab" and "steps" in attrs:
@@ -510,6 +516,8 @@ def aggregate_counts(spans: Iterable[Dict[str, Any]]) -> Dict[str, Any]:
         out.update(aggAttempts=attempts, groupBudgetGrowths=growths)
     if last:
         out["slabSteps"] = sum(steps for _, steps in last.values())
+    if devices:
+        out["meshDevices"] = devices
     return out
 
 
