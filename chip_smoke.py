@@ -69,11 +69,11 @@ DISTRIBUTED = {"execution_mode": "distributed"}
 # What each session runs on one chip; the compiled tier goes first. Q3 is
 # not in the distributed list: all six pairs passed on the chip (PR 24) but
 # took 1,435 s cold, 85% of it compiling, and the contract is 1,200 s. Q3 in
-# the distributed session reads 788 s cold (about 600 of it compiling) and
-# 36.1 s warm, all of the warm time the device's (benchmark cell
-# q3-compiled, PR 35; ROADMAP.md S13): the smoke would not stay inside its
-# contract with it, so it stays in the default session and in --chips 4,
-# and the cell holds the compiled Q3 to the published answer.
+# the distributed session reads 325 s cold and 4.6 s warm since its joins
+# are sort-merge (PR 36; 788 s and 36.1 s before): the smoke would not
+# stay inside its contract with it beside Q1 and Q6 (ROADMAP.md S4), so it
+# stays in the default session and in --chips 4, and the benchmark cell
+# q3-compiled holds the compiled Q3 to the published answer.
 DISTRIBUTED_QUERIES = (6, 1)
 LOCAL_QUERIES = (6, 1, 3)
 # A second literal for each compiled query: the same plan fingerprint, so

@@ -40,21 +40,20 @@ Boots a 2-worker cluster and runs three scenarios:
    dense join tier's multiway fusion) runs once clean, then with the
    worker that executed the fused unit's task SIGKILLed right after the
    task finishes. FAIL on row drift, on a query-level retry
-   (queryAttempts > 1), on the query not fusing, or on the dense
-   strategy not being the one that ran (exchangeStats.joinStrategy) —
+   (queryAttempts > 1), on the query not fusing, or on a join site
+   running another kernel than the one ``auto`` names
+   (exchangeStats.joinStrategy: sort-merge since PR 36) —
    recovery must engage at unit granularity, same ladder as
    fused-node-death but across a multiway join program.
 
 8. ``adaptive-warmup`` (in-process, no cluster): a Zipf-skewed
    partitioned join with skew handling OFF runs cold, recording
-   observed truth (capacities AND the dense-join key domain) into a
-   persistent query-history store; a FRESH engine sharing the same
-   ``history_dir`` then repeats the query. FAIL unless the warm run
-   shows ``overflow_retries == 0`` AND ``compile_halvings == 0`` AND
-   bit-identical rows AND the history-driven join promotion: the cold
-   run picks the dense tier, the warm run reads the history-seeded key
-   domain through the cost gate and promotes the same site to the
-   matmul tier (``joinStrategy`` dense -> matmul). When the cold run
+   observed truth (capacities) into a persistent query-history store;
+   a FRESH engine sharing the same ``history_dir`` then repeats the
+   query. FAIL unless the warm run shows ``overflow_retries == 0`` AND
+   ``compile_halvings == 0`` AND bit-identical rows AND the same join
+   kernel as the cold run (``joinStrategy``: the history seeds
+   capacities and promotes no tier since PR 36). When the cold run
    actually grew a site, the warm run must additionally show at least
    one capacity with provenance ``history``.
 
@@ -1061,11 +1060,10 @@ def main() -> int:
                   " broadcast absorption silently did not happen")
             summary["ok"] = False
             return 1
-        if "dense" not in sj["join_strategies"]:
+        if sj["join_strategies"] != ["sort"]:
             print(
-                "FAIL: star-join ran without the dense tier"
-                f" (joinStrategy={sj['join_strategies']}) — the scenario"
-                " exercised the sort path instead"
+                "FAIL: star-join left the kernel auto names"
+                f" (joinStrategy={sj['join_strategies']}, want sort)"
             )
             summary["ok"] = False
             return 1
@@ -1095,20 +1093,18 @@ def main() -> int:
             )
             summary["ok"] = False
             return 1
-        if aw["warm_strategies"] != ["matmul"]:
+        if aw["warm_strategies"] != aw["cold_strategies"]:
             print(
-                "FAIL: adaptive-warmup warm run did not take the"
-                " history-driven matmul promotion (cold"
-                f" {aw['cold_strategies']} -> warm {aw['warm_strategies']})"
-                " — the recorded dense-join domain never reached the cost"
-                " gate"
+                "FAIL: adaptive-warmup warm run joined by another kernel"
+                f" than the cold one ({aw['cold_strategies']} -> warm"
+                f" {aw['warm_strategies']}) — history seeds capacities,"
+                " it promotes no tier"
             )
             summary["ok"] = False
             return 1
         if aw["cold_retries"] == 0:
             print("WARN: adaptive-warmup cold run never overflowed — the"
-                  " warm zero-retry check only proves the strategy loop"
-                  " at this size")
+                  " warm zero-retry check proves nothing at this size")
         if recovered == 0:
             print("WARN: no recovered tasks — the worker-exit fault"
                   " never bit a consumer")

@@ -338,12 +338,14 @@ def interpreter_ref():
     return get
 
 
-# every strategy on the star query; auto/sort on the TPC-H pair — a
-# cold `auto` resolves to `dense` (no history), so the dense column is
-# already covered and the explicit pin only needs one query's worth of
-# suite time. q10 repeats the q5 evidence on a second join spine, so
-# it rides in the slow lane. The small memory join runs under every tier,
-# and there the ladder has to stay at rest as well.
+# every strategy on the star query; auto and sort on Q5 (`auto` resolves
+# to `sort` since PR 36, the chip's readings: `_join_strategy`, so the
+# table tiers are covered by their pins on the star and the memory join;
+# `auto` on the memory join is MORE_E2E_CASES, which
+# tests/test_join_rule.py runs through the same function). q10 repeats the
+# q5 evidence on a second join spine, so it rides in the slow lane. The
+# small memory join runs under every tier, and there the ladder has to
+# stay at rest as well.
 E2E_CASES = [
     ("auto", "q5"), ("sort", "q5"),
     pytest.param("auto", "q10", marks=pytest.mark.slow),
@@ -351,6 +353,7 @@ E2E_CASES = [
     ("auto", "star"), ("sort", "star"), ("dense", "star"),
     ("sort", "mem"), ("dense", "mem"), ("matmul", "mem"),
 ]
+MORE_E2E_CASES = [("auto", "mem")]
 
 
 @pytest.mark.parametrize("strategy,qkey", E2E_CASES)
@@ -369,17 +372,21 @@ def test_strategies_bit_identical(strategy, qkey, strategy_runners,
         ex = res.exchange_stats
         assert ex["overflow_retries"] == 0, ex
         assert ex["dispatchRoundTrips"] >= 1
-        assert set(ex["joinStrategy"].values()) == {strategy}, ex["joinStrategy"]
+        want = "sort" if strategy == "auto" else strategy
+        assert set(ex["joinStrategy"].values()) == {want}, ex["joinStrategy"]
 
 
-def test_star_query_fuses_multiway():
+def star_query_fuses_multiway(pin, want):
     """Acceptance: under the default (broadcast) distribution the
     dimension builds fuse INTO the fact-probe program — one multiway
     fused star join in ONE dispatch round-trip, strictly more fragments
     fused and strictly fewer round-trips than with the dense tier off
     (broadcast links never fused pairwise), with the chosen strategy
-    surfaced per site in exchangeStats.joinStrategy."""
+    surfaced per site in exchangeStats.joinStrategy: sort-merge under
+    ``auto`` (the fusion is ``dense_join``'s, not the kernel's), the table
+    under its pin."""
     r = DistributedQueryRunner()
+    r.session.set("join_strategy", pin)
     res = r.engine.execute_statement(STAR_SQL, r.session)
     ex = res.exchange_stats or {}
 
@@ -391,13 +398,17 @@ def test_star_query_fuses_multiway():
     assert res.rows == res_s.rows
     strategies = ex.get("joinStrategy") or {}
     assert strategies, "no per-site join strategies surfaced"
-    assert set(strategies.values()) == {"dense"}
+    assert set(strategies.values()) == {want}
     assert all(s.startswith("densejoin@") for s in strategies)
     assert ex.get("dispatchRoundTrips", 99) == 1, ex
     assert ex.get("fusedFragments", 0) > ex_s.get("fusedFragments", 0)
     assert ex.get("dispatchRoundTrips", 99) < ex_s.get(
         "dispatchRoundTrips", 0
     )
+
+
+def test_star_query_fuses_multiway():
+    star_query_fuses_multiway("auto", "sort")
 
 
 def test_matmul_strategy_pinned_by_session(tmp_path):
@@ -418,15 +429,18 @@ def test_matmul_strategy_pinned_by_session(tmp_path):
 
 
 def test_warm_repeat_zero_overflow_retries(tmp_path):
-    """The PR-15 loop through the dense tier: a history-halved
+    """The PR-15 loop through the dense tier, under its explicit pin
+    (``auto`` answers ``sort`` since PR 36): a history-halved
     ``densejoin@…`` site forces ONE graceful in-ladder re-hash (never
     the interpreter); the grown truth is recorded, and a FRESH engine
     sharing only the history_dir repeats with ZERO overflow retries off
-    a history-provenance seed — bit-identical rows throughout."""
+    a history-provenance seed — bit-identical rows throughout. The seed
+    is a capacity and nothing more: ``auto`` beside it stays on sort."""
     def _props(**extra):
         return {
             "execution_mode": "distributed",
             "history_dir": str(tmp_path),
+            "join_strategy": "dense",
             **extra,
         }
 
@@ -437,7 +451,6 @@ def test_warm_repeat_zero_overflow_retries(tmp_path):
     cold = cold_runner.engine.execute_statement(
         MEM_JOIN_SQL, Session(properties=_props()))
     assert cold.exchange_stats["overflow_retries"] == 0
-    # cold: no history yet, so auto stays on the hashed dense tier
     assert set(
         (cold.exchange_stats.get("joinStrategy") or {}).values()
     ) == {"dense"}
@@ -460,11 +473,9 @@ def test_warm_repeat_zero_overflow_retries(tmp_path):
         MEM_JOIN_SQL, Session(properties=_props()))
     assert mid.rows == cold.rows
     assert mid.exchange_stats["overflow_retries"] == 1
-    # the history-provenance seed also satisfies the auto->matmul cost
-    # gate (single integer key, seeded domain under the bound): the
-    # warm runs get the identity-binned tier for free
+    # one re-hash, no demotion: the pinned tier answers
     strategies = mid.exchange_stats.get("joinStrategy") or {}
-    assert set(strategies.values()) == {"matmul"}, strategies
+    assert set(strategies.values()) == {"dense"}, strategies
 
     # the in-ladder growth was the table site: the store now holds the
     # grown truth (8 -> 16) under the restart-stable densejoin site
@@ -479,17 +490,19 @@ def test_warm_repeat_zero_overflow_retries(tmp_path):
         MEM_JOIN_SQL, Session(properties=_props()))
     assert warm.rows == cold.rows
     assert warm.exchange_stats["overflow_retries"] == 0
-    # history seeding proven through the cost gate: auto->matmul needs a
-    # history-provenance densejoin floor (grown floors below the
-    # engineered default never install as the capacity itself)
+    # (the grown 16 is a floor below the engineered default of 1,024
+    # slots: it never installs as the capacity itself, so nothing overflows)
     strategies = warm.exchange_stats.get("joinStrategy") or {}
-    assert set(strategies.values()) == {"matmul"}, strategies
+    assert set(strategies.values()) == {"dense"}, strategies
 
-    # the sort tier agrees bit-identically, closing the loop
+    # the sort tier agrees bit-identically, closing the loop, and so does
+    # auto with the history beside it: a seeded table site promotes nothing
     off_runner = LocalQueryRunner()
     _mem_tables(off_runner.catalogs)
-    off = off_runner.engine.execute_statement(
-        MEM_JOIN_SQL,
-        Session(properties=_props(join_strategy="sort",
-                                  query_history=False)))
-    assert off.rows == cold.rows
+    for pin in ("sort", "auto"):
+        off = off_runner.engine.execute_statement(
+            MEM_JOIN_SQL,
+            Session(properties=_props(join_strategy=pin,
+                                      query_history=pin == "auto")))
+        assert off.rows == cold.rows
+        assert set(off.exchange_stats["joinStrategy"].values()) == {"sort"}

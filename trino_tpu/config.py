@@ -57,13 +57,11 @@ class Session:
         # tables with graceful overflow (densejoin@ capacity sites), the
         # spill-cliff removal, and broadcast-link star-join fusion
         ("dense_join", True),
-        # auto | sort | dense | matmul — auto picks dense for INNER/LEFT
-        # equi-joins and escalates single-key dense-domain builds to the
-        # binned (matmul) tier when PR-15 history proves the domain fits
+        # auto | sort | dense | matmul. auto answers sort: on the chip
+        # sort-merge took 656-782 ms where the table tiers took 2.9-6.2 s
+        # at all three shapes read (PR 36: exec/fragments.py::
+        # _join_strategy holds the figures); dense and matmul stay pins
         ("join_strategy", "auto"),
-        # largest binned key domain the auto gate may promote to the
-        # matmul tier (explicit join_strategy=matmul is not bounded)
-        ("matmul_join_max_domain", 1 << 13),
         ("enable_dynamic_filtering", True),
         ("dynamic_filtering_max_build_rows", 1 << 20),
         ("query_max_memory_bytes", 8 << 30),

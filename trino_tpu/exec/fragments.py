@@ -299,7 +299,9 @@ class _Caps:
         # window — doubling can never place it (same key ⇒ same slot
         # sequence), so the site demotes to the sort strategy and the
         # retrace drops its table entirely (graceful, still compiled).
-        self.join_strategies: dict[str, str] = {}
+        # Per site also the capacities (build, probe) it was last traced
+        # at: the ``joins`` attribute of the span over the site's program.
+        self.join_sites: dict[str, tuple[str, int, int]] = {}
         self.grow_counts: dict[str, int] = {}
         self.demoted: set[str] = set()
 
@@ -344,6 +346,20 @@ class _Caps:
             return self.vals[name], self.provenance.get(name, "default")
         fl = self._seed_floor.get(name)
         return (fl[0], fl[1]) if fl is not None else None
+
+    def joins(self, wanted=None) -> list[dict]:
+        """The join sites traced under these capacities (those whose
+        runtime name ``wanted`` accepts, where given), by restart-stable
+        site: the kernel each runs and the static shapes it was chosen at."""
+        by_site = {}
+        for nm, (strategy, build_cap, probe_cap) in self.join_sites.items():
+            if wanted is None or wanted(nm):
+                site = self.sites.get(nm, nm)
+                by_site[site] = {
+                    "site": site, "strategy": strategy,
+                    "buildCap": build_cap, "probeCap": probe_cap,
+                }
+        return [by_site[site] for site in sorted(by_site)]
 
     def grow(self, name: str, factor: int = 2, need: int = 0) -> None:
         # quantize growth to power-of-two buckets: stats-seeded odd-sized
@@ -954,7 +970,7 @@ class FragmentedExecutor(DistributedExecutor):
                     }
                     if prov.startswith("history"):
                         history_seeds += 1
-                for nm, strat in val.join_strategies.items():
+                for nm, (strat, _, _) in val.join_sites.items():
                     join_strategy[val.sites.get(nm, nm)] = strat
         st["capacities"] = caps
         # capacity sites whose value came from the observed-history store
@@ -1334,6 +1350,7 @@ class FragmentedExecutor(DistributedExecutor):
             self._hot_sets[unit.id] = aux
         span.set("mode", "fused-pipeline")
         self._note_exchange(span)
+        self._note_joins(span, "fused", tuple(unit.fragment_ids))
         if sink:
             span.set("attempts", sink.get("attempts", 1))
         get_registry().counter("trino_tpu_fused_programs_total").inc()
@@ -1460,6 +1477,7 @@ class FragmentedExecutor(DistributedExecutor):
             self._hot_sets[frag.id] = aux
         span.set("mode", "fused")
         self._note_exchange(span)
+        self._note_joins(span, frag.id)
         if sink:
             span.set("attempts", sink.get("attempts", 1))
         if self.stats_collector is not None:
@@ -1594,6 +1612,15 @@ class FragmentedExecutor(DistributedExecutor):
         span.set("rows", static.get("padded_shuffle_rows", 0))
         span.set("bytes", static.get("shuffle_bytes", 0))
         span.set("devices", int(self.mesh.devices.size))
+
+    def _note_joins(self, span, *caps_key) -> None:
+        """``joins`` on the span that covers a program with join sites:
+        per site the kernel ``_join_strategy`` chose and the static
+        capacities it chose at (``_Caps.joins``), a stored program's too."""
+        caps = self.programs.get(("caps",) + caps_key)
+        joins = caps.joins() if caps is not None else []
+        if joins:
+            span.set("joins", joins)
 
     def _retry_traced(
         self,
@@ -2266,6 +2293,7 @@ class FragmentedExecutor(DistributedExecutor):
                 frag, K, pstack, inputs, input_layouts, defer=True
             )
             span.set("mode", "batched")
+            self._note_joins(span, frag.id)
             return out
 
     def _run_fused_unit_batched(
@@ -2320,6 +2348,7 @@ class FragmentedExecutor(DistributedExecutor):
                 unit.fragments, K, pstack, inputs, input_layouts, defer=True
             )
             span.set("mode", "batched-fused")
+            self._note_joins(span, "fused", tuple(unit.fragment_ids))
             get_registry().counter("trino_tpu_fused_programs_total").inc()
             return out
 
@@ -3241,42 +3270,41 @@ class _FragmentTracer(DistributedExecutor):
     # --- joins -----------------------------------------------------------
 
     def _join_strategy(self, node: P.Join, lkeys) -> str:
-        """Pick the join kernel for one Join node (ops/dense_join.py
-        module doc).  ``sort`` is the PR-0 bitonic path; ``dense`` the
-        open-addressing table; ``matmul`` the identity-binned table for
-        densely-binning single integer keys.  The auto→matmul promotion
-        is a cost gate seeded from PR-15 history: a history-seeded
-        ``densejoin`` capacity within the domain bound proves an earlier
-        run's observed table fit a dense domain — static stats cannot
-        prove that cold, and a sparse 64-bit key domain would walk the
-        whole retry ladder before demoting.  Sites the ladder demoted
-        (duplicate chains beyond the probe window) are pinned to sort."""
-        if not bool(self.session.get("dense_join")):
+        """Pick the join kernel for one Join node: ``sort`` (ops/join.py:
+        sort-merge), ``dense`` (ops/dense_join.py: the open-addressing
+        table) or ``matmul`` (the same table under identity binning of a
+        single integer key).
+
+        ``auto`` answers ``sort``, from what one TPU v5 lite chip read
+        (``scripts/join_crossover.py``, PR 36; ``PERF.md`` section 6): the
+        whole per-shard join, one 64-bit key, 2,097,152 probe rows into
+        4,194,304 output slots, took sort / dense / matmul 782 / 6,191 /
+        5,207 ms against a build side of 2,097,152 rows (Q3's slab step),
+        773 / 4,549 / 4,641 ms against 262,144 (the fragment below it) and
+        656 / 2,919 / 2,917 ms against 1,024 (a dimension, the shape the
+        ``matmul`` promotion was written for); Q3 at SF1 end to end 4.59 s
+        against 36.1. The table tiers pay 16 rounds of random gathers,
+        one of them 64-bit, over every probe row and again over every
+        output slot whatever the build side holds, where sort-merge pays
+        three sorts, so no static shape the trace can see (the sides'
+        capacities, the output's, the key lanes, the mesh) buys them a
+        join: the answer is a constant of the key every stored program
+        already has. ``join_strategy=dense|matmul`` still pin them, with
+        their ladder: sites it demoted (duplicate chains beyond the probe
+        window) stay on sort, as does every join with ``dense_join`` off."""
+        pref = str(self.session.get("join_strategy") or "auto").lower()
+        if pref in ("auto", "sort") or not bool(self.session.get("dense_join")):
             return "sort"
-        site = f"densejoin{id(node)}"
         # demotions are recorded under the restart-stable alias (node
         # ids churn across retraces); the alias map is registered by
         # _seed_history before any node of this fragment traces
+        site = f"densejoin{id(node)}"
         if self.caps.sites.get(site, site) in self.caps.demoted:
-            return "sort"
-        pref = str(self.session.get("join_strategy") or "auto").lower()
-        if pref == "sort":
             return "sort"
         matmul_ok = len(lkeys) == 1 and jnp.issubdtype(
             lkeys[0][0].dtype, jnp.integer
         )
-        if pref == "matmul":
-            return "matmul" if matmul_ok else "dense"
-        if pref != "dense" and matmul_ok:
-            seeded = self.caps.seeded(site)
-            bound = int(self.session.get("matmul_join_max_domain"))
-            if (
-                seeded is not None
-                and seeded[1].startswith("history")
-                and 0 < seeded[0] <= bound
-            ):
-                return "matmul"
-        return "dense"
+        return "matmul" if pref == "matmul" and matmul_ok else "dense"
 
     def _exec_join(self, node: P.Join) -> Result:
         if node.join_type in ("SEMI", "ANTI"):
@@ -3335,7 +3363,9 @@ class _FragmentTracer(DistributedExecutor):
         )
         cap = self.caps.get(f"join{id(node)}", default_cap)
         strategy = self._join_strategy(node, lkeys)
-        self.caps.join_strategies[f"densejoin{id(node)}"] = strategy
+        self.caps.join_sites[f"densejoin{id(node)}"] = (
+            strategy, right.batch.capacity, probe_cap,
+        )
         table_cap = None
         if strategy != "sort":
             # table slots per shard: 4x the per-shard build rows (load

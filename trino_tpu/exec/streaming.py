@@ -220,6 +220,9 @@ class StreamingAggregator:
             self._builds = tuple(builds)
             span.set("builds", len(builds))
             span.set("capacities", [b.capacity for b in builds])
+            joins = self.caps.joins(self._build_join_names().__contains__)
+            if joins:
+                span.set("joins", joins)
             # the one wait of the span: the build sides' live rows, which
             # the device has once the fragments below have run
             live = np.asarray(
@@ -240,6 +243,16 @@ class StreamingAggregator:
                     raise StreamOverflow(
                         {nm: int(f) for nm, f in zip(names, fired) if f}
                     )
+
+    def _build_join_names(self) -> set:
+        """The capacity names of the joins inside the build sides' plans:
+        ``stream.build`` runs those, ``stream.slab`` the probe spine's."""
+        return {
+            f"densejoin{id(n)}"
+            for root in self.build_roots
+            for n in P.walk_plan(root)
+            if isinstance(n, P.Join)
+        }
 
     def _program_key(self, kind: str, *shape) -> tuple:
         """A stored program's key, by content: the aggregate's site, its
@@ -557,6 +570,10 @@ class StreamingAggregator:
                 res = self._slab_attempt(
                     programs, slab, chunk_cols, shard_rows, cap, span, meta
                 )
+                below = self._build_join_names()
+                joins = self.caps.joins(lambda nm: nm not in below)
+                if joins:
+                    span.set("joins", joins)
             if res is not None:
                 if attempt > 1 and programs is not None:
                     programs[cap_key] = cap

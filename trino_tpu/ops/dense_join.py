@@ -1,9 +1,14 @@
 """Dense equi-join tier: device-resident open-addressing build table.
 
-The sort tier (``ops/join.py``) pays an O(n log n) bitonic ``lax.sort``
-on every build side.  This tier replaces it with a static-shape
-open-addressing table — the TPU translation of Trino's ``PagesHash``
-linear-probe table — built and probed with fully vectorized rounds:
+The sort tier (``ops/join.py``) pays ``lax.sort``s on every join; this
+tier replaces them with a static-shape open-addressing table — the TPU
+translation of Trino's ``PagesHash`` linear-probe table — built and
+probed with fully vectorized rounds. On the chip that trade loses: the
+rounds are random gathers over every probe row and output slot, and
+one TPU v5 lite read sort-merge at 656-782 ms where this tier took
+2.9-6.2 s (``exec/fragments.py::_join_strategy``, PR 36), so ``auto``
+answers ``sort`` and this tier runs only under ``join_strategy=dense``
+or ``matmul``:
 
 1. Each build row proposes itself for the slots ``base+0 .. base+W-1``
    (``W = PROBE_WINDOW``), one displacement per round.  A round is one

@@ -80,7 +80,6 @@ _CODEGEN_PROPS = (
     "join_distribution_type",
     "join_reordering_strategy",
     "join_strategy",
-    "matmul_join_max_domain",
     # operator telemetry mints extra traced reductions (op! counters), so
     # on/off runs of one plan compile different programs — unlike
     # device_profiling, which observes the SAME program from outside
