@@ -168,7 +168,18 @@ def _shard_assignment(files, n, measured=None):
     return assigned
 
 
+#: files whose tests run after every other file's (ROADMAP.md D13): an xdist
+#: worker aborts in ``test_ingest.py`` when the tests collected before it are
+#: dealt out otherwise than in the runs that passed (PR 36 met it with three
+#: more cases in ``test_dense_join.py``, PR 37 with this file in its
+#: alphabetical place), so a new file joins the queue at its end
+_COLLECTED_LAST = ("test_delivery_account.py",)
+
+
 def pytest_collection_modifyitems(config, items):
+    late = [i for i in items if os.path.basename(str(i.fspath)) in _COLLECTED_LAST]
+    if late:
+        items[:] = [i for i in items if i not in late] + late
     spec = config.getoption("--tt-shard")
     if not spec:
         return

@@ -33,8 +33,9 @@ def _answer(keys=ONE_BIGINT, caps=None, site="densejoin7", **props):
     tracer = types.SimpleNamespace(
         session=Session(properties=props), caps=caps or _Caps())
     node = types.SimpleNamespace()
-    caps = tracer.caps
-    caps.sites.setdefault(f"densejoin{id(node)}", site)
+    # assigned, not ``setdefault``: a node of an earlier call is freed by now
+    # and this one can be given its ``id()``, with the alias left behind
+    tracer.caps.sites[f"densejoin{id(node)}"] = site
     return _FragmentTracer._join_strategy(tracer, node, keys)
 
 

@@ -364,6 +364,7 @@ class TestSelfTimes:
         assert got["phaseMs"] == {
             "parse": 2.0, "plan": 4.0, "optimize": 5.0, "canonicalize": 1.0,
             "execute": 100.0, "resultPull": 9.0, "build": 0.0, "slab": 0.0,
+            "devicePull": 0.0,
         }
         assert got["operatorMs"] == {
             "Output": 2.0, "Aggregate": 78.0, "TableScan": 20.0,
@@ -590,9 +591,11 @@ class TestServedDefaultPathSpans:
         stats = info["queryStats"]
         phases, operators = stats["phaseMs"], stats["operatorMs"]
         assert set(phases) == {"parse", "plan", "optimize", "canonicalize",
-                               "execute", "resultPull", "build", "slab"}
+                               "execute", "resultPull", "build", "slab",
+                               "devicePull"}
         # the compiled tier's, inside execute
         assert phases["slab"] == 0 and phases["build"] == 0
+        assert phases["devicePull"] == 0
         assert set(operators) == set(kinds)
         assert sum(operators.values()) == pytest.approx(phases["execute"], rel=0.01)
         assert stats["queuedMs"] + sum(phases.values()) \
@@ -689,7 +692,8 @@ class TestServedCompiledPathSpans:
         assert phases["execute"] > 0 and 0 < phases["slab"] <= phases["execute"]
         assert phases["execute"] == pytest.approx(execute_plan["durationMs"], abs=0.01)
         assert phases["slab"] == pytest.approx(slab["durationMs"], abs=0.01)
-        sequential = sum(v for k, v in phases.items() if k not in ("build", "slab"))
+        sequential = sum(v for k, v in phases.items()
+                         if k not in ("build", "slab", "devicePull"))
         assert stats["queuedMs"] + sequential \
             == pytest.approx(stats["elapsedMs"], rel=0.05, abs=2.0)
         assert info["traceCount"] == 0 and info["programCacheHits"] >= 1

@@ -107,6 +107,11 @@ class Span:
         if self._tracer is not None:
             self._tracer._record(self)
 
+    def drop(self) -> None:
+        """Close the span and keep it from the sinks: what it was opened
+        round turned out not to be its kind of work."""
+        self._done = True
+
     def context(self) -> Tuple[str, str]:
         return (self.trace_id, self.span_id)
 
@@ -166,6 +171,9 @@ class _NoopSpan:
         pass
 
     def finish(self, status: str = "OK", **attrs: Any) -> None:
+        pass
+
+    def drop(self) -> None:
         pass
 
     def context(self) -> None:
@@ -452,7 +460,7 @@ PHASE_OF_SPAN = {
     "parse": "parse", "plan": "plan", "optimize": "optimize",
     "canonicalize": "canonicalize", "execute_plan": "execute",
     "result.pull": "resultPull", "stream.build": "build",
-    "stream.slab": "slab",
+    "stream.slab": "slab", "device_pull": "devicePull",
 }
 OPERATOR_PREFIX = "op:"
 

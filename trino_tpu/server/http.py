@@ -35,6 +35,7 @@ from typing import Any, Callable, Optional
 from trino_tpu import types as T
 from trino_tpu.config import ServerConfig, Session
 from trino_tpu.engine import Engine
+from trino_tpu.obs.trace import NOOP_SPAN, InMemorySpanSink, get_tracer
 from trino_tpu.server.eventloop import (
     EventLoopHttpServer,
     Request,
@@ -90,7 +91,6 @@ class TrinoTpuServer:
         cluster_memory_limit_bytes: Optional[int] = None,
         server_config: Optional[ServerConfig] = None,
     ):
-        from trino_tpu.obs.trace import InMemorySpanSink, get_tracer
         from trino_tpu.server.resourcegroups import ResourceGroupManager
         from trino_tpu.server.task import SqlTaskManager
 
@@ -271,8 +271,6 @@ class TrinoTpuServer:
                 return
 
     def stop(self) -> None:
-        from trino_tpu.obs.trace import get_tracer
-
         self.state = "STOPPED"
         self._announce_stop.set()
         self.httpd.close()
@@ -406,8 +404,12 @@ class TrinoTpuServer:
             # streaming pager: pages cut on demand by byte budget; acked
             # pages are freed, so peak serving buffer stays bounded
             pager = q.result_pager(budget, PAGE_ROWS)
+            start_ns = time.monotonic_ns()
             rows, more = pager.page(token)
             if rows is not None:
+                q.delivery.page_built(
+                    token, len(rows), start_ns, time.monotonic_ns()
+                )
                 out["data"] = [
                     [_json_value(v) for v in row] for row in rows
                 ]
@@ -422,6 +424,9 @@ class TrinoTpuServer:
             lo = token * PAGE_ROWS
             hi = min(lo + PAGE_ROWS, len(res.rows))
             if lo < len(res.rows):
+                # nothing is cut or sized here: the page's build is 0
+                now_ns = time.monotonic_ns()
+                q.delivery.page_built(token, hi - lo, now_ns, now_ns)
                 out["data"] = [
                     [_json_value(v) for v in row] for row in res.rows[lo:hi]
                 ]
@@ -600,7 +605,10 @@ class TrinoTpuServer:
                 except TransactionError as e:
                     return json_response({"error": str(e)}, 400)
                 q = self.query_manager.create_query(sql, session)
-                return json_response(self.query_results(q, "queued", 0))
+                response = json_response(self.query_results(q, "queued", 0))
+                if q.result is not None:  # answered from the result cache
+                    q.delivery.handed_over(time.monotonic_ns())
+                return response
 
             return self._offload(responder, create)
         if len(parts) == 3 and parts[:2] == ["v1", "task"]:
@@ -1019,6 +1027,7 @@ class TrinoTpuServer:
         machine. A state transition satisfying the phase predicate (or
         the maxWait timer) responds; no thread waits anywhere."""
         q.touch()
+        q.delivery.request_parsed(time.monotonic_ns())
         max_wait = parse_max_wait(
             _parse_duration(
                 request.headers.get(f"{PROTOCOL_HEADER}-Max-Wait", "1s")
@@ -1043,14 +1052,38 @@ class TrinoTpuServer:
             if responder.done:
                 return
             q.touch()
+            # one span a page of the answer, round its build and encoding;
+            # the loop thread has no ambient span, so the root is named
+            span = (
+                get_tracer().span(
+                    "result.page",
+                    trace_id=q.query_id,
+                    parent_id=q.span.span_id,
+                    attrs={"token": token},
+                )
+                if phase == "executing"
+                else NOOP_SPAN
+            )
             try:
-                out = self.query_results(q, phase, token)
+                with span:
+                    out = self.query_results(q, phase, token)
+                    response = _statement_response(out)
+                    if "data" in out:
+                        span.set("rows", len(out["data"]))
+                        span.set("bytes", len(response.body))
+                    else:
+                        span.drop()
             except Exception as e:  # noqa: BLE001
                 responder.respond(
                     json_response({"error": f"internal error: {e}"}, 500)
                 )
                 return
-            responder.respond(_statement_response(out))
+            responder.respond(response)
+            now_ns = time.monotonic_ns()
+            if "data" in out:
+                q.delivery.page_encoded(len(response.body), now_ns)
+            if q.result is not None:
+                q.delivery.handed_over(now_ns)
 
         timer = loop.call_later(max_wait, finish)
 

@@ -93,6 +93,9 @@ class ManagedQuery:
         # lazy byte-budgeted pager over result.rows (streaming protocol)
         self._pager: Optional["ResultPager"] = None
         self._pager_lock = threading.Lock()
+        # what handing the answer over costs, filled by the statement
+        # ``executing`` phase once the result is there (queryStats.delivery)
+        self.delivery = DeliveryAccount(self._pager_lock)
         self.query_attempts = 1  # >1 under retry_policy=QUERY
         self._engine = engine
         self._completed_fired = False
@@ -369,6 +372,10 @@ class ManagedQuery:
         st = self.state.get()
         elapsed = (self._end_mono or time.monotonic()) - self._create_mono
         cluster_stats = self.result.cluster_stats if self.result else {}
+        stats = self._query_stats(elapsed, cluster_stats)
+        # read live: the phases above are kept from the moment the result
+        # was ready, and delivery starts there
+        stats["delivery"] = self.delivery.to_json(self._end_mono)
         return {
             "queryId": self.query_id,
             "state": st.value,
@@ -403,7 +410,7 @@ class ManagedQuery:
             "spooledBytes": cluster_stats.get("spooled_bytes", 0),
             # per-stage rollup (obs): elapsed + sibling task elapsed
             # p50/p99 — the speculative-execution straggler signal
-            "queryStats": self._query_stats(elapsed, cluster_stats),
+            "queryStats": stats,
             # skew-aware exchange counters (shuffle rows/bytes, padding
             # ratio, overflow retries, hot/salted keys, capacity provenance)
             "exchangeStats": self.result.exchange_stats if self.result else None,
@@ -495,6 +502,95 @@ class ManagedQuery:
         if self.start_time is None:
             return None
         return self._create_mono + max(0.0, self.start_time - self.create_time)
+
+
+class DeliveryAccount:
+    """One query's delivery, counted where the pages are made: what
+    ``queryStats.delivery`` serves. The statement ``executing`` phase fills
+    it once the result has materialised (``server/http.py``), a few clock
+    reads a page and none a row; the counters do not come from the
+    ``result.page`` spans, of which a sink keeps only so many a trace.
+
+    ``pages`` / ``rows`` / ``bodyBytes``: responses that carried ``data``,
+    their rows and the bytes of their bodies as they went on the wire (a
+    token asked for again counts once). ``buildMs``: wall inside
+    ``ResultPager.page()``. ``encodeMs``: from there to the response handed
+    to the loop (the ``_json_value`` pass and the body's ``json.dumps``).
+    ``clientGapMs``: from each response handed over after the result was
+    ready to the query's next statement request, parsed: the socket, the
+    client reading and typing the page, its next connection. ``wallMs``:
+    result ready to the last response handed over; the three lie inside it
+    and do not overlap."""
+
+    def __init__(self, lock: threading.Lock):
+        self._lock = lock  # the query's pager lock
+        self.pages = 0
+        self.rows = 0
+        self.body_bytes = 0
+        self.build_ns = 0
+        self.encode_ns = 0
+        self.gap_ns = 0
+        self._token = -1  # the highest token counted
+        self._fresh = False  # the page being encoded is a token's first
+        self._built_ns = 0  # where that page's encoding started
+        self._first_ns: Optional[int] = None
+        self._handed_ns: Optional[int] = None
+        self._awaited = False  # a response is out, its successor not asked
+
+    def request_parsed(self, now_ns: int) -> None:
+        """The query's next statement request is here: close the gap."""
+        with self._lock:
+            if self._awaited:
+                self._awaited = False
+                self.gap_ns += now_ns - self._handed_ns
+
+    def page_built(self, token: int, rows: int, start_ns: int, end_ns: int) -> None:
+        """A response's ``data`` was cut between the two stamps."""
+        with self._lock:
+            if self._first_ns is None:
+                self._first_ns = start_ns
+            self.build_ns += end_ns - start_ns
+            self._built_ns = end_ns
+            self._fresh = token > self._token
+            if self._fresh:
+                self._token = token
+                self.pages += 1
+                self.rows += rows
+
+    def page_encoded(self, body_bytes: int, now_ns: int) -> None:
+        """That page's response has its body."""
+        with self._lock:
+            self.encode_ns += now_ns - self._built_ns
+            if self._fresh:
+                self.body_bytes += body_bytes
+
+    def handed_over(self, now_ns: int) -> None:
+        """A statement response went to the loop, the result being ready."""
+        with self._lock:
+            if self._first_ns is None:
+                self._first_ns = now_ns
+            self._handed_ns = now_ns
+            self._awaited = True
+
+    def to_json(self, ready_mono: Optional[float]) -> dict:
+        with self._lock:
+            wall_ns = 0
+            if self._handed_ns is not None:
+                # the result is handed over by a state listener that can
+                # run before ``_end_mono`` is stamped: the earlier of the two
+                start = self._first_ns
+                if ready_mono is not None:
+                    start = min(start, int(ready_mono * 1e9))
+                wall_ns = self._handed_ns - start
+            return {
+                "pages": self.pages,
+                "rows": self.rows,
+                "bodyBytes": self.body_bytes,
+                "buildMs": round(self.build_ns / 1e6, 3),
+                "encodeMs": round(self.encode_ns / 1e6, 3),
+                "clientGapMs": round(self.gap_ns / 1e6, 3),
+                "wallMs": round(wall_ns / 1e6, 3),
+            }
 
 
 class ResultPager:
