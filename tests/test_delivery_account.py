@@ -121,31 +121,49 @@ def test_a_slow_client_shows_in_the_gap_alone(serve, fetched, monkeypatch, pager
 
 
 def test_a_slow_sizing_shows_in_the_build_alone(serve, monkeypatch):
+    """The pager sizes a page by its one encoding, inside ``buildMs``; the body
+    assembled around its bytes is all ``encodeMs`` holds."""
     server, conn = serve("streaming")
     conn.execute(SQL)
     before = _delivery(server, conn)
-    plain = json.dumps
+    plain = querymanager.encode_rows
+    calls = []
 
-    def slow_dumps(*args, **kwargs):
-        time.sleep(0.0001)  # 0.1 ms a row more
-        return plain(*args, **kwargs)
+    def slow_encode(rows):
+        calls.append(rows)
+        time.sleep(0.005)  # 5 ms a call more
+        return plain(rows)
 
-    produce = querymanager.ResultPager._produce_locked
-
-    def slow_produce(self):
-        # slow for the pager's row loop alone, not for the response's body
-        with monkeypatch.context() as m:
-            m.setattr(json, "dumps", slow_dumps)
-            return produce(self)
-
-    monkeypatch.setattr(querymanager.ResultPager, "_produce_locked", slow_produce)
+    # the pager's global alone: the fixed-row path imported its own name
+    monkeypatch.setattr(querymanager, "encode_rows", slow_encode)
     sql = SQL.replace("8000", "8001")
     conn.execute(sql)
     d = _delivery(server, conn, sql)
-    assert d["rows"] >= ROWS
-    assert d["buildMs"] >= 0.1 * ROWS
-    assert d["buildMs"] >= before["buildMs"] + 0.09 * ROWS
-    assert d["encodeMs"] < 0.05 * ROWS
+    assert d["rows"] >= ROWS and d["pages"] >= 20
+    assert len(calls) >= d["pages"]  # an encoding a page at the least
+    assert d["buildMs"] >= 5.0 * len(calls)
+    assert d["buildMs"] >= before["buildMs"] + 4.5 * d["pages"]
+    assert d["encodeMs"] < 2.5 * d["pages"]
+
+
+def test_a_page_cut_again_is_counted(serve, monkeypatch):
+    server, conn = serve("streaming")
+    # pages of up to 4,096 rows: the first run holds all 2,000 and passes
+    # the 2,600-byte budget long before its last row
+    monkeypatch.setattr(http_module, "PAGE_ROWS", 4096)
+    rows, _ = conn.execute(SQL)
+    d = _delivery(server, conn)
+    q = next(q for q in server.query_manager.queries() if q.sql == SQL)
+    again = querymanager.ResultPager(q.result.rows, 2600, 4096)
+    recut, token = 0, 0
+    while True:
+        page, more = again.page(token)
+        recut += page.recut
+        if not more:
+            break
+        token += 1
+    assert len(rows) == ROWS and d["pages"] == q._pager.pages_produced == token + 1
+    assert d["recutPages"] == recut >= 1
 
 
 def test_the_fixed_row_path_builds_nothing(serve):
@@ -160,9 +178,10 @@ def test_the_parts_lie_inside_the_wall(serve, pager):
     server, conn = serve(pager)
     conn.execute(SQL)
     d = _delivery(server, conn)
-    assert set(d) == {"pages", "rows", "bodyBytes", "buildMs", "encodeMs",
-                      "clientGapMs", "wallMs"}
+    assert set(d) == {"pages", "rows", "recutPages", "bodyBytes", "buildMs",
+                      "encodeMs", "clientGapMs", "wallMs"}
     assert all(v >= 0 for v in d.values())
+    assert d["recutPages"] == 0  # every page is cut at its 100 rows
     assert d["buildMs"] + d["encodeMs"] + d["clientGapMs"] <= d["wallMs"] + 1.0
     assert d["encodeMs"] > 0 and d["clientGapMs"] > 0
 
@@ -252,8 +271,9 @@ def test_a_failed_and_a_cancelled_query_serve_the_account(serve):
         conn.execute("select no_such_column from tpch.tiny.orders")
     failed = [q for q in conn.list_queries() if q["state"] == "FAILED"][-1]
     d = _get(server, f"/v1/query/{failed['queryId']}")["queryStats"]["delivery"]
-    assert d == {"pages": 0, "rows": 0, "bodyBytes": 0, "buildMs": 0.0,
-                 "encodeMs": 0.0, "clientGapMs": 0.0, "wallMs": 0.0}
+    assert d == {"pages": 0, "rows": 0, "recutPages": 0, "bodyBytes": 0,
+                 "buildMs": 0.0, "encodeMs": 0.0, "clientGapMs": 0.0,
+                 "wallMs": 0.0}
     # cancelled in the middle of its delivery: what went out stays counted
     stmt = client.StatementClient(server.base_uri, SQL, conn.session)
     rows = stmt.rows()

@@ -153,6 +153,69 @@ class TestTimerDrivenReap:
 # ---------------------------------------------------------------------------
 
 
+def _serve_all(pager):
+    """Every page of ``pager`` in token order."""
+    pages, token = [], 0
+    while True:
+        page, more = pager.page(token)
+        if page is not None:
+            pages.append(page)
+        if not more:
+            return pages
+        token += 1
+
+
+def _two_pass_rows(rows):
+    """``data`` as the server encoded it before a page was encoded once:
+    a ``Decimal`` to ``str``, then ``json.dumps`` of the body."""
+    from decimal import Decimal
+
+    return [[str(v) if isinstance(v, Decimal) else v for v in row]
+            for row in rows]
+
+
+def _row_cuts(rows, budget, max_rows):
+    """Row counts of the pages a row-at-a-time sizing cuts: a page ends at
+    ``max_rows`` or at the row whose text brings it to ``budget``."""
+    from trino_tpu.server.querymanager import encode_rows
+
+    cuts, n, size = [], 0, 1
+    for row in rows:
+        size += len(encode_rows(row)) + (2 if n else 0)
+        n += 1
+        if size + 1 >= budget or n == max_rows:
+            cuts.append(n)
+            n, size = 0, 1
+    return cuts + ([n] if n else [])
+
+
+def _mixed_rows(n=600):
+    from decimal import Decimal
+
+    texts = ["plain", "ünïcødé ✓", 'quote " and \\ backslash', "tab\tnl\n",
+             "", "\u2028 sep", "x" * 40]
+    return [
+        (
+            (-1) ** i * (2**63 - 1 - i),
+            None if i % 5 == 0 else Decimal(i * 7919) / 100,
+            texts[i % len(texts)],
+            [0.1, 1e300, -2.5e-8, 3.0][i % 4],
+            None if i % 3 == 0 else i % 2 == 0,
+        )
+        for i in range(n)
+    ]
+
+
+def _wide_rows(n=400):
+    return [(i, "w" * 300) for i in range(n)]
+
+
+def _stepped_rows(n=900):
+    # widths step up ten-fold every 150 rows: a run sized by the last
+    # page's bytes a row passes the budget where the step falls inside it
+    return [(i, "s" * (8 if (i // 150) % 2 == 0 else 80)) for i in range(n)]
+
+
 class TestResultPager:
     def _pager(self, n_rows=1000, budget=2048):
         from trino_tpu.server.querymanager import ResultPager
@@ -162,15 +225,10 @@ class TestResultPager:
 
     def test_pages_cover_all_rows_in_order(self):
         rows, pager = self._pager()
-        got, token = [], 0
-        while True:
-            page, more = pager.page(token)
-            if page is not None:
-                got.extend(page)
-            if not more:
-                break
-            token += 1
+        pages = _serve_all(pager)
+        got = [tuple(r) for p in pages for r in json.loads(p.data)]
         assert got == rows
+        assert [p.rows for p in pages] == _row_cuts(rows, 2048, 4096)
         assert pager.pages_produced > 3  # budget forced multiple pages
 
     def test_buffer_stays_bounded(self):
@@ -191,7 +249,8 @@ class TestResultPager:
         _, pager = self._pager()
         first, more1 = pager.page(0)
         again, more2 = pager.page(0)
-        assert first == again and more1 == more2
+        assert first.data == again.data and more1 == more2
+        assert first.rows == again.rows
 
     def test_empty_result(self):
         from trino_tpu.server.querymanager import ResultPager
@@ -199,6 +258,81 @@ class TestResultPager:
         pager = ResultPager([], 1024)
         page, more = pager.page(0)
         assert page is None and not more
+
+    @pytest.mark.parametrize("budget", [1 << 20, 700], ids=["rows", "bytes"])
+    @pytest.mark.parametrize("make", [_mixed_rows, _wide_rows, _stepped_rows])
+    def test_served_bodies_parse_as_the_two_pass_encoding(self, make, budget):
+        from trino_tpu.server.http import _statement_response
+        from trino_tpu.server.querymanager import ResultPager
+
+        rows = make()
+        pager = ResultPager(rows, budget, max_rows_per_page=128)
+        at = 0
+        for token, page in enumerate(_serve_all(pager)):
+            head = {"id": "q", "infoUri": "u", "warnings": [], "stats": {},
+                    "columns": [], "nextUri": f"n/{token}"}
+            body = _statement_response({**head, "data": page}).body
+            want = json.dumps(
+                {**head, "data": _two_pass_rows(rows[at:at + page.rows])})
+            # key for key, value for value, and as many bytes
+            assert json.loads(body) == json.loads(want)
+            assert len(body) == len(want)
+            at += page.rows
+        assert at == len(rows)
+
+    @pytest.mark.parametrize(
+        "make,budget,max_rows",
+        [(_wide_rows, 2000, 4096), (_wide_rows, 5000, 64), (_mixed_rows, 1, 4096),
+         (_stepped_rows, 1500, 4096), (_mixed_rows, 900, 4096),
+         (_stepped_rows, 1 << 20, 100), (_mixed_rows, 1 << 20, 4096)],
+        ids=["wide", "wide-capped", "one-byte", "stepped", "mixed", "stepped-rows",
+             "mixed-rows"],
+    )
+    def test_recuts_are_counted_and_cut_at_the_budget(
+        self, monkeypatch, make, budget, max_rows
+    ):
+        from trino_tpu.server import querymanager
+        from trino_tpu.server.querymanager import ResultPager, encode_rows
+
+        run_rows = []
+        plain = querymanager.encode_rows
+
+        def spy(arg):
+            if isinstance(arg, list):  # a run, not a row sized alone
+                run_rows.append(len(arg))
+            return plain(arg)
+
+        monkeypatch.setattr(querymanager, "encode_rows", spy)
+        rows = make()
+        pager = ResultPager(rows, budget, max_rows_per_page=max_rows)
+        served, recut, token = [], 0, 0
+        while True:
+            run_rows.clear()
+            page, more = pager.page(token)
+            buffered = pager._pages.values()
+            assert pager.buffered_bytes == sum(len(p.data) for p in buffered)
+            if page is None:
+                break
+            # a recut page: its runs held rows past its last
+            assert page.recut == (sum(run_rows) > page.rows)
+            recut += page.recut
+            served.append(page)
+            token += 1
+            if not more:
+                break
+        got = [tuple(r) for p in served for r in json.loads(p.data)]
+        assert got == [tuple(_two_pass_rows([r])[0]) for r in rows]
+        assert [p.rows for p in served] == _row_cuts(rows, budget, max_rows)
+        at = 0
+        for p in served:
+            last = rows[at + p.rows - 1]
+            # past the budget by at most its last row
+            assert p.rows == 1 or len(p.data) - len(encode_rows(last)) - 2 < budget
+            at += p.rows
+        if budget < 1 << 20:
+            assert recut > 0
+        else:
+            assert recut == 0  # cut by rows alone: every page one run
 
 
 # ---------------------------------------------------------------------------

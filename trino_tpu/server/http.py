@@ -29,7 +29,6 @@ import math
 import threading
 import time
 import urllib.parse
-from decimal import Decimal
 from typing import Any, Callable, Optional
 
 from trino_tpu import types as T
@@ -48,7 +47,9 @@ from trino_tpu.server.eventloop import (
 from trino_tpu.server.querymanager import (
     ManagedQuery,
     QueryManager,
+    ResultPage,
     _DispatchPool,
+    encode_rows,
 )
 from trino_tpu.server.statemachine import (
     QueryState,
@@ -62,12 +63,6 @@ VERSION = "trino-tpu-0.1 (356-compatible)"
 # task/spool long-polls re-check on the loop at this cadence instead of
 # parking a thread in the buffer's condition wait
 _TASK_POLL_S = 0.015
-
-
-def _json_value(v: Any) -> Any:
-    if isinstance(v, Decimal):
-        return str(v)
-    return v
 
 
 class TrinoTpuServer:
@@ -405,14 +400,13 @@ class TrinoTpuServer:
             # pages are freed, so peak serving buffer stays bounded
             pager = q.result_pager(budget, PAGE_ROWS)
             start_ns = time.monotonic_ns()
-            rows, more = pager.page(token)
-            if rows is not None:
+            page, more = pager.page(token)
+            if page is not None:
                 q.delivery.page_built(
-                    token, len(rows), start_ns, time.monotonic_ns()
+                    token, page.rows, start_ns, time.monotonic_ns(),
+                    page.recut,
                 )
-                out["data"] = [
-                    [_json_value(v) for v in row] for row in rows
-                ]
+                out["data"] = page
             if more:
                 out["nextUri"] = (
                     f"{uri}/executing/{q.query_id}/{q.slug}/{token + 1}"
@@ -424,12 +418,13 @@ class TrinoTpuServer:
             lo = token * PAGE_ROWS
             hi = min(lo + PAGE_ROWS, len(res.rows))
             if lo < len(res.rows):
-                # nothing is cut or sized here: the page's build is 0
+                # nothing is cut or sized here: the page's build is 0, and
+                # its one encoding falls in the encode window
                 now_ns = time.monotonic_ns()
                 q.delivery.page_built(token, hi - lo, now_ns, now_ns)
-                out["data"] = [
-                    [_json_value(v) for v in row] for row in res.rows[lo:hi]
-                ]
+                out["data"] = ResultPage(
+                    hi - lo, encode_rows(res.rows[lo:hi]).encode()
+                )
             if hi < len(res.rows):
                 out["nextUri"] = (
                     f"{uri}/executing/{q.query_id}/{q.slug}/{token + 1}"
@@ -1067,9 +1062,10 @@ class TrinoTpuServer:
             try:
                 with span:
                     out = self.query_results(q, phase, token)
+                    page = out.get("data")
                     response = _statement_response(out)
-                    if "data" in out:
-                        span.set("rows", len(out["data"]))
+                    if page is not None:
+                        span.set("rows", page.rows)
                         span.set("bytes", len(response.body))
                     else:
                         span.drop()
@@ -1080,7 +1076,7 @@ class TrinoTpuServer:
                 return
             responder.respond(response)
             now_ns = time.monotonic_ns()
-            if "data" in out:
+            if page is not None:
                 q.delivery.page_encoded(len(response.body), now_ns)
             if q.result is not None:
                 q.delivery.handed_over(now_ns)
@@ -1125,7 +1121,9 @@ class TrinoTpuServer:
 
 
 def _statement_response(out: dict) -> Response:
-    """Pop the session-mutation fields into their response headers."""
+    """Pop the session-mutation fields into their response headers; a
+    page's ``data`` (a :class:`ResultPage`) goes in as the bytes it was
+    encoded to, spliced after the other fields' ``json.dumps``."""
     headers: dict[str, str] = {}
     set_session = out.pop("_setSession", None)
     if set_session:
@@ -1146,7 +1144,12 @@ def _statement_response(out: dict) -> Response:
         headers[f"{PROTOCOL_HEADER}-Started-Transaction-Id"] = started
     if out.pop("_clearedTransaction", None):
         headers[f"{PROTOCOL_HEADER}-Clear-Transaction-Id"] = "true"
-    return json_response(out, headers=headers)
+    page = out.get("data")
+    if page is None:
+        return json_response(out, headers=headers)
+    head = json.dumps({k: v for k, v in out.items() if k != "data"})
+    body = b"".join((head[:-1].encode(), b', "data": ', page.data, b"}"))
+    return Response(200, body, "application/json", headers)
 
 
 def _raw_type(ty: T.SqlType) -> str:

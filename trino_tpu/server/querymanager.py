@@ -18,11 +18,12 @@ from __future__ import annotations
 import dataclasses
 import inspect
 import itertools
+import json
 import secrets
 import threading
 import time
 import traceback
-from typing import Any, Optional
+from typing import Any, NamedTuple, Optional
 
 from trino_tpu import types as T
 from trino_tpu.config import Session
@@ -513,9 +514,12 @@ class DeliveryAccount:
 
     ``pages`` / ``rows`` / ``bodyBytes``: responses that carried ``data``,
     their rows and the bytes of their bodies as they went on the wire (a
-    token asked for again counts once). ``buildMs``: wall inside
-    ``ResultPager.page()``. ``encodeMs``: from there to the response handed
-    to the loop (the ``_json_value`` pass and the body's ``json.dumps``).
+    token asked for again counts once). ``recutPages``: of those pages, the
+    ones whose first encoding passed the budget and were cut again
+    (``ResultPage.recut``). ``buildMs``: wall inside ``ResultPager.page()``,
+    which holds the page's one encoding. ``encodeMs``: from there to the
+    response handed to the loop (the body assembled around the page's
+    bytes; on the fixed-row path, the page's encoding too).
     ``clientGapMs``: from each response handed over after the result was
     ready to the query's next statement request, parsed: the socket, the
     client reading and typing the page, its next connection. ``wallMs``:
@@ -526,6 +530,7 @@ class DeliveryAccount:
         self._lock = lock  # the query's pager lock
         self.pages = 0
         self.rows = 0
+        self.recut_pages = 0
         self.body_bytes = 0
         self.build_ns = 0
         self.encode_ns = 0
@@ -544,7 +549,10 @@ class DeliveryAccount:
                 self._awaited = False
                 self.gap_ns += now_ns - self._handed_ns
 
-    def page_built(self, token: int, rows: int, start_ns: int, end_ns: int) -> None:
+    def page_built(
+        self, token: int, rows: int, start_ns: int, end_ns: int,
+        recut: bool = False,
+    ) -> None:
         """A response's ``data`` was cut between the two stamps."""
         with self._lock:
             if self._first_ns is None:
@@ -556,6 +564,7 @@ class DeliveryAccount:
                 self._token = token
                 self.pages += 1
                 self.rows += rows
+                self.recut_pages += recut
 
     def page_encoded(self, body_bytes: int, now_ns: int) -> None:
         """That page's response has its body."""
@@ -585,6 +594,7 @@ class DeliveryAccount:
             return {
                 "pages": self.pages,
                 "rows": self.rows,
+                "recutPages": self.recut_pages,
                 "bodyBytes": self.body_bytes,
                 "buildMs": round(self.build_ns / 1e6, 3),
                 "encodeMs": round(self.encode_ns / 1e6, 3),
@@ -593,28 +603,47 @@ class DeliveryAccount:
             }
 
 
+def encode_rows(rows) -> str:
+    """Result rows as the statement protocol's ``data``: one ``json.dumps``
+    of the run, a ``Decimal`` (any value JSON has no type for) as
+    ``str(v)``. A row's text inside the run is ``encode_rows(row)``."""
+    return json.dumps(rows, default=str)
+
+
+class ResultPage(NamedTuple):
+    """One page of the answer: its row count and its ``data`` as the JSON
+    text that goes on the wire, whose length is the page's bytes."""
+
+    rows: int
+    data: bytes
+    recut: bool = False  # the first encoding passed the budget: cut again
+
+
 class ResultPager:
     """Byte-budgeted page server over a query's result rows.
 
     Reference: ``server/protocol/Query.java`` (targetResultSize paging).
     Pages are cut on demand as the client polls ``nextUri`` — a page ends
-    when its JSON-encoded size reaches ``page_max_bytes`` or
-    ``max_rows_per_page`` rows, whichever first.  Serving token N acks
+    at ``max_rows_per_page`` rows or at the row whose JSON text brings the
+    page to ``page_max_bytes``, whichever first (a page passes the budget
+    by at most its last row).  Each page is encoded once, and that text is
+    both its size and what the response carries.  Serving token N acks
     (frees) every buffered page below N, so at most the in-flight page
     plus the just-produced one stay resident: producer backpressure is
     the client's own poll cadence.  Re-requesting the last un-acked token
-    is idempotent (HTTP retry safety).
+    is idempotent (HTTP retry safety): it is served the same bytes.
     """
 
     def __init__(
         self, rows, page_max_bytes: int, max_rows_per_page: int = 4096
     ):
-        self._src = iter(rows)
+        self._rows = rows
+        self._pos = 0  # the first row no page holds yet
         self.total_rows = len(rows)
         self._budget = max(1, int(page_max_bytes))
         self._max_rows = max(1, int(max_rows_per_page))
-        self._pages: dict[int, list] = {}
-        self._page_bytes: dict[int, int] = {}
+        self._row_bytes = 0  # the last page's bytes a row
+        self._pages: dict[int, ResultPage] = {}
         self._next = 0  # next token to produce
         self._exhausted = False
         self.pages_produced = 0
@@ -622,49 +651,68 @@ class ResultPager:
         self.peak_buffered_bytes = 0
         self._lock = threading.Lock()
 
-    def page(self, token: int) -> tuple[Optional[list], bool]:
-        """Rows for ``token`` (None when past the end) plus whether more
-        pages may follow."""
+    def page(self, token: int) -> tuple[Optional[ResultPage], bool]:
+        """The page for ``token`` (None when past the end) plus whether
+        more pages may follow."""
         with self._lock:
             self._ack_below_locked(token)
             while token >= self._next and not self._exhausted:
                 self._produce_locked()
             self._ack_below_locked(token)
-            rows = self._pages.get(token)
-            if rows is None:
+            page = self._pages.get(token)
+            if page is None:
                 return None, False
             more = (token + 1 < self._next) or not self._exhausted
-            return rows, more
+            return page, more
 
     def _ack_below_locked(self, token: int) -> None:
         for t in [t for t in self._pages if t < token]:
-            self.buffered_bytes -= self._page_bytes.pop(t)
-            del self._pages[t]
+            self.buffered_bytes -= len(self._pages.pop(t).data)
 
     def _produce_locked(self) -> None:
-        import json
-
-        rows: list = []
-        nbytes = 2  # brackets
-        for row in self._src:
-            try:
-                enc = len(json.dumps(row, default=str))
-            except (TypeError, ValueError):
-                enc = 64
-            rows.append(row)
-            nbytes += enc + 2
-            if nbytes >= self._budget or len(rows) >= self._max_rows:
-                break
-        else:
-            self._exhausted = True
-        if not rows:
+        rows, start, budget = self._rows, self._pos, self._budget
+        stop = min(start + self._max_rows, len(rows))
+        if start >= stop:
             self._exhausted = True
             return
-        self._pages[self._next] = rows
-        self._page_bytes[self._next] = nbytes
+        # runs of rows, each encoded once and joined to the page: a run is
+        # the rows left or, once a page has been cut, 7/8 of what the last
+        # page's bytes a row say the budget still holds, so the budget is
+        # rarely passed inside a run
+        text, end = "", start
+        while end < stop and len(text) < budget:
+            n = stop - end
+            if self._row_bytes:
+                room = (budget - len(text)) * 7 // (8 * self._row_bytes)
+                n = min(n, max(1, room))
+            run = encode_rows(rows[end:end + n])
+            if end == start:
+                head, text = 1, run
+            else:  # ``head``: where the run's first row starts in the page
+                head, text = len(text) + 1, f"{text[:-1]}, {run[1:]}"
+            end += n
+        # passed before the last row: without that row the text still
+        # holds the budget
+        recut = len(text) >= budget and (
+            len(text) - len(encode_rows(rows[end - 1])) - 2 >= budget
+        )
+        if recut:
+            # the budget was reached before the run's last row: size the
+            # run's rows one at a time and cut the text after that row
+            for i in range(end - n, end):
+                head += len(encode_rows(rows[i]))
+                if head + 1 >= budget:
+                    break
+                head += 2  # ", "
+            text, end = f"{text[:head]}]", i + 1
+        page = ResultPage(end - start, text.encode(), recut)
+        self._pos = end
+        self._row_bytes = -(-len(page.data) // page.rows)
+        self._exhausted = end == len(rows)
+        self._pages[self._next] = page
         self._next += 1
         self.pages_produced += 1
-        self.buffered_bytes += nbytes
+        self.buffered_bytes += len(page.data)
         self.peak_buffered_bytes = max(
             self.peak_buffered_bytes, self.buffered_bytes
         )
