@@ -173,7 +173,7 @@ def _shard_assignment(files, n, measured=None):
 #: dealt out otherwise than in the runs that passed (PR 36 met it with three
 #: more cases in ``test_dense_join.py``, PR 37 with this file in its
 #: alphabetical place), so a new file joins the queue at its end
-_COLLECTED_LAST = ("test_delivery_account.py",)
+_COLLECTED_LAST = ("test_delivery_account.py", "test_join_capacity.py")
 
 
 def pytest_collection_modifyitems(config, items):

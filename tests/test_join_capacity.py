@@ -1,0 +1,157 @@
+"""A streamed join's output capacity (``exec/fragments.py::_exec_join``,
+``exec/streaming.py::_prebuild``): where the build key is unique among the
+build side's live rows, the slab step gives the join its probe's capacity,
+else twice that; the overflow flag and the growth ladder stay the guard.
+TPC-H Q5 through the one-device slab runner against the default session, the
+``joins`` of ``stream.slab`` and the counters ``queryStats.buildRows`` and
+``joinOutSlots`` against the spans."""
+
+import pytest
+
+from trino_tpu.obs.trace import aggregate_counts, get_tracer
+from trino_tpu.testing import DistributedQueryRunner, LocalQueryRunner
+
+Q5 = """select n_name, sum(l_extendedprice * (1 - l_discount)) as revenue
+from customer, orders, lineitem, supplier, nation, region
+where c_custkey = o_custkey and l_orderkey = o_orderkey
+  and l_suppkey = s_suppkey and c_nationkey = s_nationkey
+  and s_nationkey = n_nationkey and n_regionkey = r_regionkey
+  and r_name = 'ASIA' and o_orderdate >= date '1994-01-01'
+  and o_orderdate < date '1994-01-01' + interval '1' year
+group by n_name order by revenue desc"""
+
+#: lineitem streamed a supplier's rows at a time through a memory table
+BY_SUPPLIER = """select d.label, count(*), sum(l.l_quantity)
+from tpch.tiny.lineitem l join memory.default.{table} d on l.l_suppkey = d.k
+group by d.label order by d.label"""
+
+
+class _Streamed:
+    """A sink that keeps the compiled tier's ``stream.*`` spans."""
+
+    def __init__(self):
+        self.spans = []
+
+    def record(self, span):
+        if span.name.startswith("stream."):
+            self.spans.append(span.to_json())
+
+    def named(self, name):
+        return [s["attrs"] for s in self.spans if s["name"] == name]
+
+
+@pytest.fixture()
+def streamed():
+    sink = _Streamed()
+    get_tracer().add_sink(sink)
+    try:
+        yield sink
+    finally:
+        get_tracer().remove_sink(sink)
+
+
+def _slab_runner():
+    r = DistributedQueryRunner(n_devices=1)
+    r.session.set("stream_scan_threshold_rows", 1000)
+    r.session.set("stream_device_chunk_rows", 4096)
+    return r
+
+
+@pytest.fixture(scope="module")
+def runner():
+    return _slab_runner()
+
+
+def test_q5_through_the_slab_equals_the_default_session(runner, streamed):
+    got = runner.engine.execute_statement(Q5, runner.session)
+    assert got.rows == LocalQueryRunner(engine=runner.engine).execute(Q5)[0]
+    assert [r[0] for r in got.rows][:2] == ["VIETNAM", "CHINA"]
+    (slab,) = streamed.named("stream.slab")
+    assert slab["groupBy"] == "domain"
+    (build,) = streamed.named("stream.build")
+    # one entry a join of the step, each with its key columns; the build
+    # sides' joins ran in fragments of their own
+    assert len(slab["joins"]) == build["builds"] == len(build["rowsBySite"])
+    assert max(j["keys"] for j in slab["joins"]) == 2
+    for j in slab["joins"]:
+        assert j["unique"] and j["outCap"] == j["probeCap"] == 4096
+    # a warm query answers from the stored program, at the same widths
+    del streamed.spans[:]
+    warm = runner.engine.execute_statement(Q5, runner.session)
+    assert warm.rows == got.rows and warm.trace_count == 0
+    assert streamed.named("stream.slab")[0]["joins"] == slab["joins"]
+
+
+def test_the_counters_are_those_of_the_spans(runner, streamed):
+    res = runner.engine.execute_statement(Q5, runner.session)
+    assert res.rows
+    counts = aggregate_counts(streamed.spans)
+    (slab,) = streamed.named("stream.slab")
+    (build,) = streamed.named("stream.build")
+    assert counts["joinOutSlots"] == slab["steps"] * sum(j["outCap"] for j in slab["joins"])
+    assert counts["buildRows"] == build["rows"] == sum(build["rowsBySite"].values())
+    assert 0 < build["rows"] <= sum(build["capacities"])
+
+
+def test_the_served_query_carries_the_counters():
+    from trino_tpu import client
+    from trino_tpu.server.http import TrinoTpuServer
+
+    server = TrinoTpuServer(port=0).start()
+    try:
+        conn = client.Connection(server.base_uri, client.ClientSession(
+            catalog="tpch", schema="tiny", properties={
+                "execution_mode": "distributed", "stream_scan_threshold_rows": 1000,
+                "stream_device_chunk_rows": 4096}))
+        rows, _ = conn.execute(Q5)
+        assert len(rows) == 5
+        (info,) = [q for q in conn.list_queries() if q["state"] == "FINISHED"]
+        stats = info["queryStats"]
+        assert stats["slabSteps"] >= 1
+        assert stats["joinOutSlots"] >= stats["slabSteps"] * 4096
+        assert stats["buildRows"] > 0
+    finally:
+        server.stop()
+
+
+def _supplier_table(runner, name, keys):
+    runner.execute(f"create table memory.default.{name} (k bigint, label varchar)")
+    values = ", ".join(f"({k}, 'group{k % 3}')" for k in keys)
+    runner.execute(f"insert into memory.default.{name} values {values}")
+
+
+@pytest.mark.parametrize("case", ["unique", "duplicate appended", "forced overflow"])
+def test_the_output_capacity_follows_the_build_key(case, streamed, monkeypatch):
+    """One memory table of supplier keys as the build side of lineitem's
+    streamed join. Unique keys: the join's output is its probe's width. A
+    duplicate key appended: twice that, from a program of its own, and the
+    answer stays the default session's. A build said to be unique that is not
+    (the flag forced): the step overflows, the ladder grows the capacity, and
+    the answer is still right."""
+    from trino_tpu.ops import join as J
+
+    runner = _slab_runner()
+    table = {"unique": "sup_u", "duplicate appended": "sup_d", "forced overflow": "sup_f"}[case]
+    _supplier_table(runner, table, range(1, 101))
+    sql = BY_SUPPLIER.format(table=table)
+    first = runner.engine.execute_statement(sql, runner.session)
+    (slab,) = [j for s in streamed.named("stream.slab") for j in s["joins"]]
+    assert slab["unique"] and slab["outCap"] == slab["probeCap"] == 4096
+    if case != "unique":
+        runner.execute(f"insert into memory.default.{table} values (7, 'again')")
+        if case == "forced overflow":
+            monkeypatch.setattr(J, "unique_keys", lambda keys, sel: ~sel[:0].any())
+    del streamed.spans[:]
+    got = runner.engine.execute_statement(sql, runner.session)
+    assert got.rows == LocalQueryRunner(engine=runner.engine).execute(sql)[0]
+    joins = [j for s in streamed.named("stream.slab") for j in s["joins"]]
+    if case == "unique":
+        assert got.rows == first.rows and got.trace_count == 0
+        assert joins == [slab]
+    elif case == "duplicate appended":
+        assert got.rows != first.rows and got.trace_count > 0
+        assert [(j["unique"], j["outCap"]) for j in joins] == [(False, 8192)]
+    else:
+        # the first attempt at the probe's width overflowed and was grown
+        assert [j["unique"] for j in joins] == [True] * len(joins) and len(joins) >= 2
+        assert joins[0]["outCap"] == 4096 and joins[-1]["outCap"] == 8192

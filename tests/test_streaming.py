@@ -19,9 +19,13 @@ def local():
 @pytest.fixture(scope="module")
 def streaming():
     r = DistributedQueryRunner()
-    # force tiny tables onto the streaming path with multiple small chunks
+    # force tiny tables onto the streaming path with multiple small chunks;
+    # the slab loop too, whose step is a device chunk of every shard (left at
+    # the session's 2,097,152 rows a shard, a Q5 step over eight host devices
+    # held 48 GB, and a worker of the parallel suite was killed for it)
     r.session.set("stream_scan_threshold_rows", 1000)
     r.session.set("stream_chunk_rows", 4096)
+    r.session.set("stream_device_chunk_rows", 4096)
     return r
 
 
@@ -1138,7 +1142,7 @@ def test_a_streamed_join_is_stored_and_answers_from_this_querys_build(devices, s
     assert len(stored) >= 2 and not [k for k in stored if "id(" in repr(k)]
     for k in stored:  # site, budget, rows a step, staged, mesh; capacities, builds
         assert k[1] == "agg@2#0" and k[5] == devices and len(k) == 8
-        assert all(site.split("@")[0] in ("agg", "join", "densejoin", "semi")
+        assert all(site.split("@")[0] in ("agg", "join", "ujoin", "densejoin", "semi")
                    for site, _ in k[6] if isinstance(site, str) and "@" in site)
 
 

@@ -299,9 +299,9 @@ class _Caps:
         # window — doubling can never place it (same key ⇒ same slot
         # sequence), so the site demotes to the sort strategy and the
         # retrace drops its table entirely (graceful, still compiled).
-        # Per site also the capacities (build, probe) it was last traced
-        # at: the ``joins`` attribute of the span over the site's program.
-        self.join_sites: dict[str, tuple[str, int, int]] = {}
+        # Per site also what it was last traced at: the ``joins`` attribute
+        # of the span over the site's program (``joins``).
+        self.join_sites: dict[str, dict] = {}
         self.grow_counts: dict[str, int] = {}
         self.demoted: set[str] = set()
 
@@ -350,15 +350,15 @@ class _Caps:
     def joins(self, wanted=None) -> list[dict]:
         """The join sites traced under these capacities (those whose
         runtime name ``wanted`` accepts, where given), by restart-stable
-        site: the kernel each runs and the static shapes it was chosen at."""
+        site: the kernel each runs (``strategy``), the static shapes it was
+        chosen at (``buildCap``, ``probeCap``), its key columns (``keys``),
+        the output capacity it was given (``outCap``) and whether that is
+        its probe's because the build key is unique (``unique``)."""
         by_site = {}
-        for nm, (strategy, build_cap, probe_cap) in self.join_sites.items():
+        for nm, traced in self.join_sites.items():
             if wanted is None or wanted(nm):
                 site = self.sites.get(nm, nm)
-                by_site[site] = {
-                    "site": site, "strategy": strategy,
-                    "buildCap": build_cap, "probeCap": probe_cap,
-                }
+                by_site[site] = {"site": site, **traced}
         return [by_site[site] for site in sorted(by_site)]
 
     def grow(self, name: str, factor: int = 2, need: int = 0) -> None:
@@ -773,6 +773,7 @@ class FragmentedExecutor(DistributedExecutor):
                 agg_k += 1
             elif isinstance(node, P.Join):
                 sites[f"join{id(node)}"] = f"join@{frag.id}#{join_k}"
+                sites[f"ujoin{id(node)}"] = f"ujoin@{frag.id}#{join_k}"
                 sites[f"semi{id(node)}"] = f"semi@{frag.id}#{join_k}"
                 sites[f"densejoin{id(node)}"] = f"densejoin@{frag.id}#{join_k}"
                 join_k += 1
@@ -970,8 +971,8 @@ class FragmentedExecutor(DistributedExecutor):
                     }
                     if prov.startswith("history"):
                         history_seeds += 1
-                for nm, (strat, _, _) in val.join_sites.items():
-                    join_strategy[val.sites.get(nm, nm)] = strat
+                for nm, traced in val.join_sites.items():
+                    join_strategy[val.sites.get(nm, nm)] = traced["strategy"]
         st["capacities"] = caps
         # capacity sites whose value came from the observed-history store
         # (surfaced as queryStats.historySeeds on /v1/query)
@@ -2633,6 +2634,10 @@ class _FragmentTracer(DistributedExecutor):
         # replicated hot-key tables exported for the peer build exchange
         self.aux_out: tuple = ()
         self._memo: dict[int, Result] = {}
+        # build sides made before this trace (a streamed aggregate's), by
+        # ``id`` of their plan root: True where the join's key is unique
+        # among the build's live rows (``_exec_join``)
+        self.unique_builds: dict[int, bool] = {}
         # operator telemetry: per-node traced row counts appended to the
         # shared counter channel (pulled with the overflow flags — zero
         # extra host round trips). Off -> no extra ops traced at all.
@@ -3357,15 +3362,24 @@ class _FragmentTracer(DistributedExecutor):
         for kd, kv in rkeys:
             build_keys.extend([kd, kv])
 
+        # where the build key is unique among the build's live rows a probe
+        # row matches one build row at the most, so the output fits in the
+        # probe's capacity; else twice that. Each rule has a capacity of its
+        # own, so a build that stops being unique starts from the wide one
+        # (the overflow flag and the ladder stay the guard either way)
+        unique = self.unique_builds.get(id(node.right), False)
+        cap_name = f"{'ujoin' if unique else 'join'}{id(node)}"
         probe_cap = left.batch.capacity
         default_cap = bucket_capacity(
-            max(1024, 2 * probe_cap // max(self.n, 1))
+            max(1024, (1 if unique else 2) * probe_cap // max(self.n, 1))
         )
-        cap = self.caps.get(f"join{id(node)}", default_cap)
+        cap = self.caps.get(cap_name, default_cap)
         strategy = self._join_strategy(node, lkeys)
-        self.caps.join_sites[f"densejoin{id(node)}"] = (
-            strategy, right.batch.capacity, probe_cap,
-        )
+        self.caps.join_sites[f"densejoin{id(node)}"] = {
+            "strategy": strategy, "buildCap": right.batch.capacity,
+            "probeCap": probe_cap, "keys": len(node.criteria),
+            "outCap": cap * max(self.n, 1), "unique": unique,
+        }
         table_cap = None
         if strategy != "sort":
             # table slots per shard: 4x the per-shard build rows (load
@@ -3403,7 +3417,7 @@ class _FragmentTracer(DistributedExecutor):
             # graceful overflow: the ladder doubles the table site and
             # re-hashes — never the interpreter's partitioned spill
             self.overflows.append((f"densejoin{id(node)}", table_ovf))
-        self.overflows.append((f"join{id(node)}", ovf))
+        self.overflows.append((cap_name, ovf))
         cols: list[Column] = []
         layout: dict[str, int] = {}
         i = 0

@@ -166,6 +166,9 @@ class StreamingAggregator:
         # batches (an ARGUMENT of every program built here) and layouts
         self._builds: Optional[tuple] = None
         self._build_layouts: list[dict] = []
+        # per build side, whether its join's key is unique among its live
+        # rows (``_prebuild``): static to the step's trace, so in its key
+        self._unique: tuple = ()
         self.nkeys = len(agg_node.group_keys)
         from trino_tpu.exec.fragments import agg_site
 
@@ -188,52 +191,45 @@ class StreamingAggregator:
     def _prebuild(self) -> None:
         """Materialize the build sides of probe-spine joins once a query
         (device resident for the whole stream), under the span
-        ``stream.build``. Their overflow flags join the deferred check;
-        build capacities grow through the same retry. Each batch is
-        normalized to one pytree shape (``num_rows`` its capacity, the live
-        rows in ``sel``), so a table that gained a row is the same argument
-        shape to a stored program."""
+        ``stream.build``: one program, stored like the slab program under a
+        key of content (``_run_build``), so a warm query compiles
+        nothing (run op by op, a build side that joins broadcast tables
+        compiled hundreds of small programs a query). Their overflow flags
+        join the deferred check; build capacities grow through the same
+        retry. Each batch is normalized to one pytree shape (``num_rows``
+        its capacity, the live rows in ``sel``), so a table that gained a
+        row is the same argument shape to a stored program."""
         if self._builds is not None:
             return
         if not self.build_roots:
             self._builds = ()
             return
-        from trino_tpu.exec.fragments import _FragmentTracer
-
         with get_tracer().span(
             "stream.build", attrs={"site": self.site}
         ) as span:
-            tracer = _FragmentTracer(
-                self.executor,
-                self._with_params(self.build_inputs, self.params),
-                self.build_layouts,
-                self.caps,
-            )
-            builds = []
-            for root in self.build_roots:
-                res = tracer._exec(root)
-                b = res.batch
-                if b.sel is None or b.num_rows != b.capacity:
-                    b = Batch(b.columns, b.capacity, b.selection_mask())
-                builds.append(b)
-                self._build_layouts.append(dict(res.layout))
+            (builds, flags, live), meta = self._run_build()
             self._builds = tuple(builds)
+            self._build_layouts = [dict(layout) for layout in meta["layouts"]]
             span.set("builds", len(builds))
             span.set("capacities", [b.capacity for b in builds])
             joins = self.caps.joins(self._build_join_names().__contains__)
             if joins:
                 span.set("joins", joins)
             # the one wait of the span: the build sides' live rows, which
-            # the device has once the fragments below have run
-            live = np.asarray(
-                jnp.stack([jnp.sum(b.sel, dtype=jnp.int64) for b in builds])
+            # the device has once the fragments below have run, and whether
+            # each join's key is unique among them
+            live = np.asarray(live)
+            rows, self._unique = live[: len(builds)], tuple(
+                bool(u) for u in live[len(builds):]
             )
-            span.set("rows", int(live.sum()))
-        if tracer.overflows:
-            names = [nm for nm, _ in tracer.overflows]
-            flags = jnp.stack(
-                [f.astype(jnp.int32) for _, f in tracer.overflows]
-            )
+            span.set("rows", int(rows.sum()))
+            spine = self._spine_joins()
+            sites = [f"join{id(spine.get(id(root)))}" for root in self.build_roots]
+            span.set("rowsBySite", {
+                self.caps.sites.get(nm, nm): int(n) for nm, n in zip(sites, rows)
+            })
+        names = meta["ovf_names"]
+        if names:
             dfl = getattr(self.executor, "deferred_flags", None)
             if dfl is not None:
                 dfl.append((None, names, flags, self.caps))
@@ -243,6 +239,127 @@ class StreamingAggregator:
                     raise StreamOverflow(
                         {nm: int(f) for nm, f in zip(names, fired) if f}
                     )
+
+    def _build_input_names(self) -> list:
+        """The build sides' inputs (``build_inputs``' keys) in the order
+        their plans reach them: the build program's argument order."""
+        names = []
+        for root in self.build_roots:
+            for n in P.walk_plan(root):
+                nm = (
+                    f"scan{id(n)}" if isinstance(n, P.TableScan)
+                    else f"remote{n.fragment_id}"
+                    if isinstance(n, P.RemoteSource) else None
+                )
+                if nm in self.build_inputs and nm not in names:
+                    names.append(nm)
+        return names
+
+    def _run_build(self):
+        """The program that makes the build sides from their inputs, run:
+        its outputs (the builds, the overflow flags, the live rows and
+        uniqueness of each build) and its ``meta`` (the builds' layouts, the
+        flags' names). The stored one where the key and the inputs'
+        dictionaries match, else traced and stored. The key holds the
+        capacities the trace consulted and the inputs' shapes, as the slab
+        program's does."""
+        from trino_tpu.exec.fragments import _FragmentTracer
+
+        programs = getattr(self.executor, "programs", None)
+        names = self._build_input_names()
+        inputs = tuple(self.build_inputs[nm] for nm in names)
+        dicts = tuple(c.dictionary for b in inputs for c in b.columns)
+
+        below = {
+            f"{kind}{id(n)}"
+            for root in self.build_roots for n in P.walk_plan(root)
+            for kind in ("agg", "join", "ujoin", "semi", "densejoin")
+        }
+
+        def key():
+            return ("build", self.site, self._held_caps(below), tuple(
+                (b.capacity, b.num_rows, b.sel is None, _columns_shape(b))
+                for b in inputs
+            ))
+
+        hit = self._stored(programs, key(), dicts)
+        if hit is not None:
+            program, meta, _ = hit
+            self.executor.count_program(hit=True)
+            return program(inputs, self.params), meta
+        meta: dict = {}
+        roots, layouts, executor, caps = (
+            self.build_roots, self.build_layouts, self.executor, self.caps
+        )
+        spine, unique_key = self._spine_joins(), self._unique_key
+
+        def build(inputs, params):
+            tracer = _FragmentTracer(
+                executor,
+                StreamingAggregator._with_params(dict(zip(names, inputs)), params),
+                layouts,
+                caps,
+            )
+            builds, meta["layouts"] = [], []
+            for root in roots:
+                res = tracer._exec(root)
+                b = res.batch
+                if b.sel is None or b.num_rows != b.capacity:
+                    b = Batch(b.columns, b.capacity, b.selection_mask())
+                builds.append(b)
+                meta["layouts"].append(dict(res.layout))
+            live = [jnp.sum(b.sel, dtype=jnp.int64) for b in builds] + [
+                unique_key(spine.get(id(root)), b, layout)
+                for root, b, layout in zip(roots, builds, meta["layouts"])
+            ]
+            meta["ovf_names"] = [nm for nm, _ in tracer.overflows]
+            flags = jnp.stack(
+                [f.astype(jnp.int32) for _, f in tracer.overflows]
+                or [jnp.zeros((), jnp.int32)]
+            )
+            return tuple(builds), flags, jnp.stack(live)
+
+        program = jax.jit(build)
+        t0 = time.perf_counter()
+        # trace and compile are synchronous in the first call
+        out = program(inputs, self.params)
+        self.executor.count_program(
+            hit=False,
+            compile_ms=(time.perf_counter() - t0) * 1000.0,
+            stored=programs is not None,
+        )
+        if programs is not None:
+            # (the key now holds the capacities the trace consulted)
+            programs[key()] = (program, meta, dicts)
+        return out, meta
+
+    def _spine_joins(self) -> dict:
+        """The joins of the probe spine, by ``id`` of their build side's root."""
+        joins, node = {}, self.agg.source
+        while isinstance(node, (P.Filter, P.Project, P.Join)):
+            if isinstance(node, P.Join):
+                joins[id(node.right)] = node
+                node = node.left
+            else:
+                node = node.source
+        return joins
+
+    @staticmethod
+    def _unique_key(join, build: Batch, layout: dict):
+        """On the device: True where ``join``'s key is unique among
+        ``build``'s live rows whose key has no NULL
+        (``ops/join.py::unique_keys``); a SEMI or ANTI join, which keeps no
+        build row, answers False."""
+        from trino_tpu.ops import join as J
+
+        if join is None or join.join_type not in ("INNER", "LEFT"):
+            return jnp.int64(0)
+        lanes = []
+        for _, rs in join.criteria:
+            c = build.columns[layout[rs.name]]
+            data = c.data if getattr(c.data, "ndim", 1) == 1 else c.data.T
+            lanes.extend((lane, c.valid_mask()) for lane in jnp.atleast_2d(data))
+        return J.unique_keys(lanes, build.selection_mask()).astype(jnp.int64)
 
     def _build_join_names(self) -> set:
         """The capacity names of the joins inside the build sides' plans:
@@ -259,20 +376,33 @@ class StreamingAggregator:
         budget and ``shape`` (the step's rows and what else the caller's
         program was traced at). With build sides also every capacity the
         trace consulted, under its restart-stable site name, and each build
-        batch's capacity and column types: a build that outgrew its
-        capacity is another key, never a program run on the wrong shape."""
+        batch's capacity, column types and whether its join's key is unique
+        among its live rows: a build that outgrew its capacity, or gained a
+        duplicate key, is another key, never a program run on the wrong
+        shape or at a width its rows can overflow."""
         key = (kind, self.site, self.G) + shape
         if not self.build_roots:
             return key
+        builds = tuple(
+            (b.capacity, _columns_shape(b), unique)
+            for b, unique in zip(self._builds, self._unique)
+        )
+        return key + (self._held_caps(), builds)
+
+    def _held_caps(self, wanted=None) -> tuple:
+        """The capacities the caps hold (those whose runtime name ``wanted``
+        holds, where given), under their restart-stable site names, and the
+        demoted sites (the fragment's output exchange sizes a program of its
+        own)."""
         caps = self.caps
-        sited = [(caps.sites.get(nm, nm), v) for nm, v in caps.vals.items()]
-        # (the fragment's output exchange sizes a program of its own)
-        held = tuple(sorted(
+        sited = [
+            (caps.sites.get(nm, nm), v) for nm, v in caps.vals.items()
+            if wanted is None or nm in wanted
+        ]
+        return tuple(sorted(
             sv for sv in sited
             if not sv[0].startswith(("exch@", "spill@", "hot@"))
         )) + tuple(sorted(caps.demoted))
-        builds = tuple((b.capacity, _columns_shape(b)) for b in self._builds)
-        return key + (held, builds)
 
     def _traced_dicts(self, *batches) -> tuple:
         """The dictionaries of the build sides and of ``batches``: static
@@ -808,10 +938,11 @@ class StreamingAggregator:
         # build sides of probe-spine joins: already materialized, so the
         # chunk trace reads them as its arguments instead of re-executing
         # the build per chunk
-        for root, layout, batch in zip(
-            self.build_roots, self._build_layouts, builds
+        for root, layout, batch, unique in zip(
+            self.build_roots, self._build_layouts, builds, self._unique
         ):
             tracer._memo[id(root)] = Result(batch, dict(layout))
+            tracer.unique_builds[id(root)] = unique
         return tracer
 
     def _chunk_prep(self, tracer):

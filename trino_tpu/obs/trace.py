@@ -501,29 +501,49 @@ def aggregate_counts(spans: Iterable[Dict[str, Any]]) -> Dict[str, Any]:
     client. ``slabSteps``: steps of the slab loops whose answer the query
     kept, each streamed aggregate's last ``stream.slab`` span (the passes
     before it outgrew a budget, the attempts before it a width the compiler
-    refused). ``meshDevices``: devices of the mesh the compiled session ran
-    the plan on (``execute_plan``'s attribute). The first two are absent
-    where no grouped aggregate ran, ``slabSteps`` where nothing streamed
-    through a slab program, ``meshDevices`` in the default session."""
+    refused). ``joinOutSlots``: over the same spans, each join's ``outCap``
+    of the slab step times the span's ``steps``: the width the probe spine
+    carries through the loop. ``buildRows``: live rows of the build sides
+    each streamed aggregate made last (``stream.build``'s ``rows``).
+    ``meshDevices``: devices of the mesh the compiled session ran the plan
+    on (``execute_plan``'s attribute). The first two are absent where no
+    grouped aggregate ran, ``slabSteps`` where nothing streamed through a
+    slab program, ``joinOutSlots`` where no such loop joined, ``buildRows``
+    where nothing streamed past a build side, ``meshDevices`` in the
+    default session."""
     attempts = growths = rows = devices = 0
-    last: Dict[Any, Tuple[int, int]] = {}  # site -> (start, steps), the latest
+    # site -> (start, steps, output slots of a step), the latest loop
+    last: Dict[Any, Tuple[int, int, Optional[int]]] = {}
+    built: Dict[Any, Tuple[int, int]] = {}  # site -> (start, rows), the latest
     for s in spans:
         attrs = s.get("attrs") or {}
         attempts += attrs.get("aggAttempts", 0)
         growths += attrs.get("groupBudgetGrowths", 0)
         devices = max(devices, attrs.get("meshDevices", 0))
+        start, site = s.get("startNs") or 0, attrs.get("site")
         if s["name"] == "result.pull":
             rows += attrs.get("rows", 0)
         elif s["name"] == "stream.slab" and "steps" in attrs:
-            loop = (s.get("startNs") or 0, attrs["steps"])
-            site = attrs.get("site")
-            if site not in last or loop[0] >= last[site][0]:
-                last[site] = loop
+            joins = attrs.get("joins") or ()
+            slots = (
+                sum(j["outCap"] for j in joins)
+                if joins and all("outCap" in j for j in joins) else None
+            )
+            if site not in last or start >= last[site][0]:
+                last[site] = (start, attrs["steps"], slots)
+        elif s["name"] == "stream.build" and "rows" in attrs:
+            if site not in built or start >= built[site][0]:
+                built[site] = (start, attrs["rows"])
     out: Dict[str, Any] = {"resultRows": rows}
     if attempts:
         out.update(aggAttempts=attempts, groupBudgetGrowths=growths)
     if last:
-        out["slabSteps"] = sum(steps for _, steps in last.values())
+        out["slabSteps"] = sum(steps for _, steps, _ in last.values())
+        joined = [steps * slots for _, steps, slots in last.values() if slots]
+        if joined:
+            out["joinOutSlots"] = sum(joined)
+    if built:
+        out["buildRows"] = sum(n for _, n in built.values())
     if devices:
         out["meshDevices"] = devices
     return out

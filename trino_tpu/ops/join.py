@@ -80,6 +80,21 @@ def build_side(key_hash: jnp.ndarray, valid: jnp.ndarray, sel: jnp.ndarray):
     return sorted_keys, sorted_idx, count
 
 
+def unique_keys(keys, sel: jnp.ndarray) -> jnp.ndarray:
+    """True where no two rows of ``sel`` whose key columns
+    ``[(data, valid), ...]`` hold no NULL share a key hash. The sort-merge
+    probe matches by hash, so then a probe row matches one such row at the
+    most; distinct hashes are distinct keys (a collision only says False)."""
+    key_hash, valid = hash_keys(keys)
+    use = sel & valid
+    maxv = jnp.iinfo(jnp.int64).max
+    keyed = jax.lax.sort(jnp.where(use, key_hash, maxv))
+    count = jnp.sum(use.astype(jnp.int32))
+    # the used rows sort first: their neighbours below ``count``
+    pos = jnp.arange(1, keyed.shape[0], dtype=jnp.int32)
+    return ~jnp.any((keyed[1:] == keyed[:-1]) & (pos < count))
+
+
 def merge_rank(sorted_build_keys: jnp.ndarray, keys: jnp.ndarray):
     """``searchsorted(sorted_build_keys, keys)`` for side "left" and
     "right" at once, as ``(lo, hi)`` int32, by sort-merge.
