@@ -2,15 +2,18 @@
 """The compiled tier's three join kernels against each other, on the device
 JAX finds.
 
-    python scripts/join_crossover.py [--shapes q3-slab q3-lower dim]
-        [--strategies sort dense matmul] [--calls 5] [--budget-s 1500]
+    python scripts/join_crossover.py [--shapes q3-slab q3-lower dim q5-slab]
+        [--strategies sort dense matmul lookup] [--calls 5] [--budget-s 1500]
 
 times ``parallel/distributed.py::_sharded_probe`` (the whole per-shard join:
 build, probe, ``verify_equal`` and the output gathers of two probe and two
 build payload columns, as ``exec/fragments.py::_exec_join`` calls it),
 jitted, at each strategy and shape: the first call (which compiles) and
-the median of ``--calls`` calls after it. One 64-bit key lane. The shapes are
-the static ones ``_join_strategy`` can see:
+the median of ``--calls`` calls after it. One 64-bit key lane. ``lookup`` is
+``sort`` where the build key is unique and the output is the probe's width
+(``_sharded_probe(lookup=True)``: no expansion, no probe column gathered), so
+it reads only the shapes whose output capacity is the probe's. The shapes
+are the static ones ``_join_strategy`` can see:
 
 - ``q3-slab``: a step of TPC-H Q3's slab loop at SF1: lineitem's chunk probes
   the orders that passed the two fragments below (``q3-slab-wide``: the same
@@ -19,7 +22,10 @@ the static ones ``_join_strategy`` can see:
   market segment;
 - ``dim``: a fact chunk against a 1,024-row dimension in the smallest
   capacity the tracer gives a build side (the star-join shape, and the one
-  the history-seeded ``matmul`` promotion was written for).
+  the history-seeded ``matmul`` promotion was written for);
+- ``q5-slab``: a step of TPC-H Q5's slab loop at SF1: lineitem's chunk probes
+  the orders of 1994, whose key is unique, at the probe's own width (the
+  capacity ``_exec_join`` gives a unique build).
 
     python scripts/join_crossover.py --sql sort dense [--schema sf1]
 
@@ -62,6 +68,7 @@ SHAPES = {
     "q3-slab-wide": (2_097_152, 1_130_000, 4_194_304, 147_000, 1_500_000, 4_194_304),
     "q3-lower": (2_097_152, 1_500_000, 262_144, 30_000, 150_000, 4_194_304),
     "dim": (2_097_152, 2_097_152, 1_024, 1_024, 1_024, 4_194_304),
+    "q5-slab": (2_097_152, 1_130_000, 2_097_152, 227_597, 1_500_000, 2_097_152),
 }
 STARTED = time.perf_counter()
 
@@ -95,6 +102,8 @@ def inputs(shape, seed: int, scale: int):
 
 
 def kernel(mesh, strategy: str, out_cap: int, build_cap: int):
+    lookup = strategy == "lookup"
+    strategy = "sort" if lookup else strategy
     # the table's size as ``_exec_join`` sets it: 4 slots a build row
     table_cap = None if strategy == "sort" else bucket_capacity(
         max(1024, 4 * build_cap))
@@ -106,7 +115,7 @@ def kernel(mesh, strategy: str, out_cap: int, build_cap: int):
         bh, _ = J.hash_keys([tuple(bkeys)])
         res = _sharded_probe(
             mesh, pcols, pkeys, ph, psel, bcols, bkeys, bh, bsel, out_cap,
-            "INNER", 1, strategy=strategy, table_cap=table_cap,
+            "INNER", 1, strategy=strategy, table_cap=table_cap, lookup=lookup,
         )
         outs, osel, *flags = res
         # what a consumer would read: every output lane at the live rows
@@ -131,6 +140,9 @@ def time_kernels(args) -> None:
                 made[name] = inputs(SHAPES[name], args.seed, args.scale)
             side, out_cap = made[name]
             build_cap = side["build"][2].shape[0]
+            if strategy == "lookup" and out_cap != side["probe"][2].shape[0]:
+                say(shape=name, strategy=strategy, skipped="outCap is not the probe's")
+                continue
             run = kernel(mesh, strategy, out_cap, build_cap)
             t0 = time.perf_counter()
             out = jax.block_until_ready(run(side["probe"], side["build"]))
@@ -202,7 +214,7 @@ def main() -> int:
     ap.add_argument("--shapes", nargs="*", default=["q3-slab", "q3-lower", "dim"],
                     choices=list(SHAPES))
     ap.add_argument("--strategies", nargs="*", default=["sort", "dense", "matmul"],
-                    choices=["sort", "dense", "matmul"])
+                    choices=["sort", "dense", "matmul", "lookup"])
     ap.add_argument("--calls", type=int, default=5)
     ap.add_argument("--seed", type=int, default=36)
     ap.add_argument("--scale", type=int, default=1,

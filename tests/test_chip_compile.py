@@ -22,7 +22,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as PS, SingleDeviceS
 
 from trino_tpu.ops import dense_join as DJ
 from trino_tpu.ops import keypack
-from trino_tpu.ops.join import probe_join
+from trino_tpu.ops.join import lookup_join, probe_join
 from trino_tpu.parallel.exchange import hash_repartition
 from trino_tpu.parallel.mesh import AXIS
 
@@ -120,6 +120,24 @@ def test_sort_join_probe_has_no_loop(one_chip):
         _shape(one_chip, (npr,), jnp.bool_),
     )
     assert "while" not in compiled.as_text()
+
+
+def test_sort_join_lookup_has_no_loop(one_chip):
+    """The sort tier's lookup at a step of Q5's slab loop (2,097,152 probe
+    rows against the 2^21-row build of 1994's orders, whose key is unique):
+    no ``while``, and no scatter, which only the expansion needs."""
+    nb = npr = 1 << 21
+    compiled = _compile(
+        lambda sbk, sbi, cnt, ph, pv, psel: lookup_join(sbk, sbi, cnt, ph, pv, psel, "inner"),
+        _shape(one_chip, (nb,), jnp.int64),
+        _shape(one_chip, (nb,), jnp.int32),
+        _shape(one_chip, (), jnp.int32),
+        _shape(one_chip, (npr,), jnp.int64),
+        _shape(one_chip, (npr,), jnp.bool_),
+        _shape(one_chip, (npr,), jnp.bool_),
+    )
+    text = compiled.as_text()
+    assert "while" not in text and " scatter(" not in text
 
 
 @pytest.mark.parametrize("masks", [False, True], ids=["slab-step", "whole-batch"])

@@ -4,7 +4,10 @@ build side's live rows, the slab step gives the join its probe's capacity,
 else twice that; the overflow flag and the growth ladder stay the guard.
 TPC-H Q5 through the one-device slab runner against the default session, the
 ``joins`` of ``stream.slab`` and the counters ``queryStats.buildRows`` and
-``joinOutSlots`` against the spans."""
+``joinOutSlots`` against the spans. At the probe's width a unique build is a
+lookup (``lookup`` on each join, ``queryStats.lookupJoins``): no probe column
+gathered; a duplicate build key, or a build said unique that is not, takes
+the expansion."""
 
 import pytest
 
@@ -74,7 +77,7 @@ def test_q5_through_the_slab_equals_the_default_session(runner, streamed):
     assert len(slab["joins"]) == build["builds"] == len(build["rowsBySite"])
     assert max(j["keys"] for j in slab["joins"]) == 2
     for j in slab["joins"]:
-        assert j["unique"] and j["outCap"] == j["probeCap"] == 4096
+        assert j["unique"] and j["lookup"] and j["outCap"] == j["probeCap"] == 4096
     # a warm query answers from the stored program, at the same widths
     del streamed.spans[:]
     warm = runner.engine.execute_statement(Q5, runner.session)
@@ -89,6 +92,7 @@ def test_the_counters_are_those_of_the_spans(runner, streamed):
     (slab,) = streamed.named("stream.slab")
     (build,) = streamed.named("stream.build")
     assert counts["joinOutSlots"] == slab["steps"] * sum(j["outCap"] for j in slab["joins"])
+    assert counts["lookupJoins"] == sum(j["lookup"] for j in slab["joins"]) == len(slab["joins"])
     assert counts["buildRows"] == build["rows"] == sum(build["rowsBySite"].values())
     assert 0 < build["rows"] <= sum(build["capacities"])
 
@@ -110,6 +114,7 @@ def test_the_served_query_carries_the_counters():
         assert stats["slabSteps"] >= 1
         assert stats["joinOutSlots"] >= stats["slabSteps"] * 4096
         assert stats["buildRows"] > 0
+        assert stats["lookupJoins"] >= 1
     finally:
         server.stop()
 
@@ -136,7 +141,7 @@ def test_the_output_capacity_follows_the_build_key(case, streamed, monkeypatch):
     sql = BY_SUPPLIER.format(table=table)
     first = runner.engine.execute_statement(sql, runner.session)
     (slab,) = [j for s in streamed.named("stream.slab") for j in s["joins"]]
-    assert slab["unique"] and slab["outCap"] == slab["probeCap"] == 4096
+    assert slab["unique"] and slab["lookup"] and slab["outCap"] == slab["probeCap"] == 4096
     if case != "unique":
         runner.execute(f"insert into memory.default.{table} values (7, 'again')")
         if case == "forced overflow":
@@ -150,8 +155,33 @@ def test_the_output_capacity_follows_the_build_key(case, streamed, monkeypatch):
         assert joins == [slab]
     elif case == "duplicate appended":
         assert got.rows != first.rows and got.trace_count > 0
-        assert [(j["unique"], j["outCap"]) for j in joins] == [(False, 8192)]
+        assert [(j["unique"], j["outCap"], j["lookup"]) for j in joins] == [(False, 8192, False)]
     else:
-        # the first attempt at the probe's width overflowed and was grown
+        # the first attempt, a lookup at the probe's width, met a probe row
+        # matching twice; the capacity grown past the probe's width expands
         assert [j["unique"] for j in joins] == [True] * len(joins) and len(joins) >= 2
         assert joins[0]["outCap"] == 4096 and joins[-1]["outCap"] == 8192
+        assert joins[0]["lookup"] and not joins[-1]["lookup"]
+
+
+@pytest.mark.parametrize("query,devices", [("q5", 1), ("q3", 1), ("q3", 4)])
+def test_every_spine_join_is_a_lookup(runner, streamed, query, devices):
+    """Every build key of Q5's and Q3's probe spines is unique, so each join
+    of the step runs as a lookup, on one device and on each shard of four,
+    and the answer is the default session's. (At tpch.tiny Q5's step holds
+    one join, its two-column one. Q3's sort-path group-by widens a 4,096-row
+    step to 65,536 rows after its join took 4,096 slots, which is no lookup:
+    Q3 runs at SF1's shape, a step the width of the session's chunk.)"""
+    from trino_tpu.benchmarks.tpch import queries
+
+    if query == "q3":
+        runner = DistributedQueryRunner(n_devices=devices)
+        runner.session.set("stream_scan_threshold_rows", 1)
+        runner.session.set("stream_device_chunk_rows", 65536)  # a shard's step
+    sql = Q5 if query == "q5" else queries("tpch.tiny")[3]
+    got = runner.engine.execute_statement(sql, runner.session)
+    assert got.rows and got.rows == LocalQueryRunner(engine=runner.engine).execute(sql)[0]
+    (slab,) = streamed.named("stream.slab")
+    assert slab["joins"] and all(j["lookup"] and j["unique"] for j in slab["joins"])
+    assert all(j["outCap"] == j["probeCap"] == slab["cap"] * devices for j in slab["joins"])
+    assert aggregate_counts(streamed.spans)["lookupJoins"] == len(slab["joins"]) == 1

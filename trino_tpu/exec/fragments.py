@@ -353,7 +353,8 @@ class _Caps:
         site: the kernel each runs (``strategy``), the static shapes it was
         chosen at (``buildCap``, ``probeCap``), its key columns (``keys``),
         the output capacity it was given (``outCap``) and whether that is
-        its probe's because the build key is unique (``unique``)."""
+        its probe's because the build key is unique (``unique``), and whether
+        it ran as a lookup, no probe column gathered (``lookup``)."""
         by_site = {}
         for nm, traced in self.join_sites.items():
             if wanted is None or wanted(nm):
@@ -3375,10 +3376,18 @@ class _FragmentTracer(DistributedExecutor):
         )
         cap = self.caps.get(cap_name, default_cap)
         strategy = self._join_strategy(node, lkeys)
+        # at the probe's own width a unique build is a lookup: output row i
+        # is probe row i, nothing expands and no probe column is gathered.
+        # Its flag (a probe row that matched twice) grows ``cap`` past the
+        # probe's width, and the retrace takes the expansion
+        lookup = (
+            strategy == "sort" and unique and not node.single_row
+            and cap * max(self.n, 1) == probe_cap
+        )
         self.caps.join_sites[f"densejoin{id(node)}"] = {
             "strategy": strategy, "buildCap": right.batch.capacity,
             "probeCap": probe_cap, "keys": len(node.criteria),
-            "outCap": cap * max(self.n, 1), "unique": unique,
+            "outCap": cap * max(self.n, 1), "unique": unique, "lookup": lookup,
         }
         table_cap = None
         if strategy != "sort":
@@ -3409,6 +3418,7 @@ class _FragmentTracer(DistributedExecutor):
             build_sharded=build_sharded,
             strategy=strategy,
             table_cap=table_cap,
+            lookup=lookup,
         )
         if strategy == "sort":
             out_cols, out_sel, ovf = res

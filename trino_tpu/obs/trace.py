@@ -503,17 +503,21 @@ def aggregate_counts(spans: Iterable[Dict[str, Any]]) -> Dict[str, Any]:
     before it outgrew a budget, the attempts before it a width the compiler
     refused). ``joinOutSlots``: over the same spans, each join's ``outCap``
     of the slab step times the span's ``steps``: the width the probe spine
-    carries through the loop. ``buildRows``: live rows of the build sides
+    carries through the loop. ``lookupJoins``: over the same spans, the
+    joins with ``lookup`` true, which ran as a lookup of a unique build key
+    with no probe column gathered. ``buildRows``: live rows of the build sides
     each streamed aggregate made last (``stream.build``'s ``rows``).
     ``meshDevices``: devices of the mesh the compiled session ran the plan
     on (``execute_plan``'s attribute). The first two are absent where no
     grouped aggregate ran, ``slabSteps`` where nothing streamed through a
-    slab program, ``joinOutSlots`` where no such loop joined, ``buildRows``
+    slab program, ``joinOutSlots`` and ``lookupJoins`` where no such loop
+    joined (``lookupJoins`` too where its joins carry no ``lookup``), ``buildRows``
     where nothing streamed past a build side, ``meshDevices`` in the
     default session."""
     attempts = growths = rows = devices = 0
-    # site -> (start, steps, output slots of a step), the latest loop
-    last: Dict[Any, Tuple[int, int, Optional[int]]] = {}
+    # site -> (start, steps, output slots of a step, lookup joins), the
+    # latest loop
+    last: Dict[Any, Tuple[int, int, Optional[int], Optional[int]]] = {}
     built: Dict[Any, Tuple[int, int]] = {}  # site -> (start, rows), the latest
     for s in spans:
         attrs = s.get("attrs") or {}
@@ -525,12 +529,13 @@ def aggregate_counts(spans: Iterable[Dict[str, Any]]) -> Dict[str, Any]:
             rows += attrs.get("rows", 0)
         elif s["name"] == "stream.slab" and "steps" in attrs:
             joins = attrs.get("joins") or ()
-            slots = (
-                sum(j["outCap"] for j in joins)
-                if joins and all("outCap" in j for j in joins) else None
+            slots, lookups = (
+                sum(j[key] for j in joins)
+                if joins and all(key in j for j in joins) else None
+                for key in ("outCap", "lookup")
             )
             if site not in last or start >= last[site][0]:
-                last[site] = (start, attrs["steps"], slots)
+                last[site] = (start, attrs["steps"], slots, lookups)
         elif s["name"] == "stream.build" and "rows" in attrs:
             if site not in built or start >= built[site][0]:
                 built[site] = (start, attrs["rows"])
@@ -538,10 +543,13 @@ def aggregate_counts(spans: Iterable[Dict[str, Any]]) -> Dict[str, Any]:
     if attempts:
         out.update(aggAttempts=attempts, groupBudgetGrowths=growths)
     if last:
-        out["slabSteps"] = sum(steps for _, steps, _ in last.values())
-        joined = [steps * slots for _, steps, slots in last.values() if slots]
+        out["slabSteps"] = sum(steps for _, steps, _, _ in last.values())
+        joined = [steps * slots for _, steps, slots, _ in last.values() if slots]
         if joined:
             out["joinOutSlots"] = sum(joined)
+        looked = [n for _, _, _, n in last.values() if n is not None]
+        if looked:
+            out["lookupJoins"] = sum(looked)
     if built:
         out["buildRows"] = sum(n for _, n in built.values())
     if devices:

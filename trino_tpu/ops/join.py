@@ -18,7 +18,13 @@ TPU-first design: no pointer-chasing hash table. Instead:
 4. Expand matches into a fixed output capacity via cumsum offsets: each
    emitting probe row scatters its number to its first output slot and a
    running maximum fills the slots between (``slot_owner``) — static
-   shapes, one scatter and one gather or two a row.
+   shapes, one scatter and one gather or two a row. Where the build key is
+   unique among the build's live rows, a probe row matches one build row at
+   the most and there is nothing to expand (``lookup_join``): output row
+   ``i`` is probe row ``i``, so the probe's columns pass through as they
+   are and only the build's are gathered; a probe row whose hash matches
+   two build rows raises the kernel's flag, and the caller asks again
+   through the expansion.
 5. Exactness: hashing may collide, so after expansion the caller re-checks
    the real key columns and ANDs mismatches out of the selection. This makes
    the kernel exact without needing perfect packing (Trino's 8-bit raw-hash
@@ -145,6 +151,18 @@ def slot_owner(offsets: jnp.ndarray, emit: jnp.ndarray, out_capacity: int):
     return _prefix_max(heads)
 
 
+def match_ranges(sorted_build_keys, build_count, probe_hash, use):
+    """Each probe row's range ``[lo, hi)`` of equal live build keys
+    (``merge_rank``), empty where ``use`` is false."""
+    maxv = jnp.iinfo(jnp.int64).max
+    # never matches the build's sentinel maxv
+    lo, hi = merge_rank(sorted_build_keys, jnp.where(use, probe_hash, maxv - 1))
+    # build_count is a 64-bit sum: left so, every array below is 64-bit
+    # too, and the chip gathers and scans those at half the rate
+    hi = jnp.minimum(hi, build_count.astype(jnp.int32))
+    return jnp.minimum(lo, hi), hi
+
+
 @partial(jax.jit, static_argnames=("out_capacity", "join_type"))
 def probe_join(
     sorted_build_keys: jnp.ndarray,
@@ -183,14 +201,7 @@ def probe_join(
         # statically empty build: no matches; LEFT still emits probe rows
         lo = counts = jnp.zeros(n_probe, dtype=jnp.int32)
     else:
-        maxv = jnp.iinfo(jnp.int64).max
-        # never matches the build's sentinel maxv
-        keys = jnp.where(use, probe_hash, maxv - 1)
-        lo, hi = merge_rank(sorted_build_keys, keys)
-        # build_count is a 64-bit sum: left so, every array below is 64-bit
-        # too, and the chip gathers and scans those at half the rate
-        hi = jnp.minimum(hi, build_count.astype(jnp.int32))
-        lo = jnp.minimum(lo, hi)
+        lo, hi = match_ranges(sorted_build_keys, build_count, probe_hash, use)
         counts = jnp.where(use, hi - lo, 0)
     if join_type == "left":
         emit = jnp.where(probe_sel, jnp.maximum(counts, 1), 0)
@@ -220,21 +231,61 @@ def probe_join(
     return probe_pos, build_pos, out_sel, total, overflow
 
 
+@partial(jax.jit, static_argnames=("join_type",))
+def lookup_join(
+    sorted_build_keys: jnp.ndarray,
+    sorted_build_idx: jnp.ndarray,
+    build_count: jnp.ndarray,
+    probe_hash: jnp.ndarray,
+    probe_valid: jnp.ndarray,
+    probe_sel: jnp.ndarray,
+    join_type: str = "inner",
+):
+    """``probe_join`` where no probe row matches two build rows: output row
+    ``i`` is probe row ``i``, so there are no probe positions to return.
+
+    Returns (build_pos, out_sel, dup):
+      build_pos: (n_probe,) int32 gather indices into the original build;
+        MISSING where the row's hash matched nothing.
+      out_sel: (n_probe,) bool — the matched selected rows (INNER), every
+        selected row (LEFT).
+      dup: bool — some selected probe row matched two or more build rows,
+        whose pairs this kernel cannot hold (the caller's overflow).
+    """
+    if join_type not in ("inner", "left"):
+        raise NotImplementedError(join_type)
+    n_probe, n_build = probe_hash.shape[0], sorted_build_idx.shape[0]
+    use = probe_valid & probe_sel
+    if n_probe == 0 or n_build == 0:
+        # statically empty side: no matches; LEFT still keeps probe rows
+        build_pos = jnp.full(n_probe, MISSING, dtype=jnp.int32)
+        matched, dup = jnp.zeros(n_probe, dtype=jnp.bool_), jnp.asarray(False)
+    else:
+        lo, hi = match_ranges(sorted_build_keys, build_count, probe_hash, use)
+        matched = use & (hi > lo)
+        build_pos = jnp.where(
+            matched, sorted_build_idx[jnp.minimum(lo, n_build - 1)], MISSING
+        ).astype(jnp.int32)
+        dup = jnp.any(use & (hi - lo > 1))
+    out_sel = probe_sel if join_type == "left" else matched
+    return build_pos, out_sel, dup
+
+
 def verify_equal(probe_keys, build_keys, probe_pos, build_pos, out_sel):
     """Exactness pass: re-check real key equality after hash-based expansion.
 
     probe_keys/build_keys: [(data, valid), ...] original (unsorted) columns.
+    ``probe_pos`` None: output row ``i`` is probe row ``i`` (``lookup_join``).
     Rows where build_pos == MISSING (left-outer padding) are kept.
     """
-    ok = jnp.ones(probe_pos.shape[0], dtype=jnp.bool_)
+    ok = jnp.ones(build_pos.shape[0], dtype=jnp.bool_)
     is_outer = build_pos == MISSING
     safe_build = jnp.where(is_outer, 0, build_pos)
     for (pd, pv), (bd, bv) in zip(probe_keys, build_keys):
         if pd.shape[0] == 0 or bd.shape[0] == 0:
             # statically empty side: no equality can hold
             return out_sel & is_outer
-        p_d = pd[probe_pos]
-        p_v = pv[probe_pos]
+        p_d, p_v = (pd, pv) if probe_pos is None else (pd[probe_pos], pv[probe_pos])
         b_d = bd[safe_build]
         b_v = bv[safe_build]
         ok = ok & (p_d == b_d) & p_v & b_v

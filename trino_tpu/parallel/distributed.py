@@ -870,9 +870,16 @@ def _sharded_probe(
     profiler=None,
     strategy="sort",
     table_cap=None,
+    lookup=False,
 ):
     """Per-shard join: build local table from (replicated or co-partitioned)
     build side, probe local rows, expand into fixed capacity.
+
+    ``lookup`` (``sort`` only, INNER or LEFT, ``per_shard_cap`` the shard's
+    probe rows; the caller knows the build key unique): no expansion
+    (``ops/join.py::lookup_join``). The probe's columns are returned as
+    given, output row ``i`` being probe row ``i``; the overflow flag says a
+    probe row matched two build rows, which the expansion must answer.
 
     ``strategy`` picks the join kernel: ``sort`` (ops/join.py bitonic
     build + sort-merge probe), ``dense`` (ops/dense_join.py
@@ -910,6 +917,11 @@ def _sharded_probe(
     build_cols, build_keys, bh, build_sel = pad_side(
         build_cols, build_keys, bh, build_sel
     )
+    if lookup:
+        assert strategy == "sort" and join_type in ("INNER", "LEFT")
+        assert per_shard_cap * n == ph.shape[0], (per_shard_cap, ph.shape)
+        # output row i is probe row i: the probe's columns never enter
+        passed, probe_cols = list(probe_cols), []
     n_probe = len(probe_cols)
     n_build = len(build_cols)
     build_spec = PS(AXIS) if build_sharded else PS()
@@ -950,7 +962,16 @@ def _sharded_probe(
             bv = bv & kv
         jt = "left" if join_type == "LEFT" else "inner"
         tovf = None
-        if strategy == "sort":
+        if lookup:
+            sbk, sbi, bcount = J.build_side(b_hash, bv, b_sel)
+            bpos, osel, ovf = J.lookup_join(sbk, sbi, bcount, p_hash, pv, p_sel, jt)
+            # a hash match whose keys differ is no match: INNER drops the
+            # row, LEFT keeps it as an outer row
+            hit = J.verify_equal(pk_pairs, bk_pairs, None, bpos, bpos != J.MISSING)
+            if jt == "inner":
+                osel = osel & hit
+            is_outer = ~hit
+        elif strategy == "sort":
             sbk, sbi, bcount = J.build_side(b_hash, bv, b_sel)
             ppos, bpos, osel, total, ovf = J.probe_join(
                 sbk, sbi, bcount, p_hash, pv, p_sel, per_shard_cap, jt,
@@ -978,9 +999,11 @@ def _sharded_probe(
             ppos, bpos, osel, total, ovf = DJ.probe_table(
                 table, b_hash, pbase, p_hash, pv, p_sel, per_shard_cap, jt,
             )
-        osel = J.verify_equal(pk_pairs, bk_pairs, ppos, bpos, osel)
-        is_outer = bpos == J.MISSING
-        safe_bpos = jnp.where(is_outer, 0, bpos)
+        if not lookup:
+            osel = J.verify_equal(pk_pairs, bk_pairs, ppos, bpos, osel)
+            is_outer = bpos == J.MISSING
+        # the index verify_equal gathered the build's keys through
+        safe_bpos = jnp.where(bpos == J.MISSING, 0, bpos)
         outs = []
         for k in range(0, n_probe, 2):
             outs.append(p_cols[k][ppos])
@@ -1009,6 +1032,6 @@ def _sharded_probe(
         res = go(*args)
     if strategy == "sort":
         outs, osel, ovf = res
-        return list(outs), osel, ovf
+        return (passed if lookup else []) + list(outs), osel, ovf
     outs, osel, ovf, tovf = res
     return list(outs), osel, ovf, tovf
