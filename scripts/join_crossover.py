@@ -2,7 +2,7 @@
 """The compiled tier's three join kernels against each other, on the device
 JAX finds.
 
-    python scripts/join_crossover.py [--shapes q3-slab q3-lower dim q5-slab]
+    python scripts/join_crossover.py [--shapes q3-slab q3-build dim q5-slab]
         [--strategies sort dense matmul lookup] [--calls 5] [--budget-s 1500]
 
 times ``parallel/distributed.py::_sharded_probe`` (the whole per-shard join:
@@ -10,16 +10,18 @@ build, probe, ``verify_equal`` and the output gathers of two probe and two
 build payload columns, as ``exec/fragments.py::_exec_join`` calls it),
 jitted, at each strategy and shape: the first call (which compiles) and
 the median of ``--calls`` calls after it. One 64-bit key lane. ``lookup`` is
-``sort`` where the build key is unique and the output is the probe's width
-(``_sharded_probe(lookup=True)``: no expansion, no probe column gathered), so
-it reads only the shapes whose output capacity is the probe's. The shapes
-are the static ones ``_join_strategy`` can see:
+``sort`` where the build key is unique, as every shape's is, so the output is
+the probe's width whatever the shape's (``_sharded_probe(lookup=True)``: no
+expansion, no probe column gathered). The shapes are the static ones
+``_join_strategy`` can see:
 
 - ``q3-slab``: a step of TPC-H Q3's slab loop at SF1: lineitem's chunk probes
   the orders that passed the two fragments below (``q3-slab-wide``: the same
   against the 4,194,304 slots the join below really hands up);
-- ``q3-lower``: the fragment below it: orders probes the customers of one
-  market segment;
+- ``q3-build``: the fragment program below it, which makes the slab's build
+  side: orders probes the customers of one market segment, ``sort`` expanding
+  into 4,194,304 slots against ``lookup`` at orders' 2,097,152 (the join
+  ``stream.build`` waits for, ``build_ms``);
 - ``dim``: a fact chunk against a 1,024-row dimension in the smallest
   capacity the tracer gives a build side (the star-join shape, and the one
   the history-seeded ``matmul`` promotion was written for);
@@ -66,7 +68,7 @@ SHAPES = {
     "q3-slab": (2_097_152, 1_130_000, 2_097_152, 147_000, 1_500_000, 4_194_304),
     # ... at the build capacity the tracer gives it: the join below's output
     "q3-slab-wide": (2_097_152, 1_130_000, 4_194_304, 147_000, 1_500_000, 4_194_304),
-    "q3-lower": (2_097_152, 1_500_000, 262_144, 30_000, 150_000, 4_194_304),
+    "q3-build": (2_097_152, 1_500_000, 262_144, 30_000, 150_000, 4_194_304),
     "dim": (2_097_152, 2_097_152, 1_024, 1_024, 1_024, 4_194_304),
     "q5-slab": (2_097_152, 1_130_000, 2_097_152, 227_597, 1_500_000, 2_097_152),
 }
@@ -140,10 +142,8 @@ def time_kernels(args) -> None:
                 made[name] = inputs(SHAPES[name], args.seed, args.scale)
             side, out_cap = made[name]
             build_cap = side["build"][2].shape[0]
-            if strategy == "lookup" and out_cap != side["probe"][2].shape[0]:
-                say(shape=name, strategy=strategy, skipped="outCap is not the probe's")
-                continue
-            run = kernel(mesh, strategy, out_cap, build_cap)
+            width = side["probe"][2].shape[0] if strategy == "lookup" else out_cap
+            run = kernel(mesh, strategy, width, build_cap)
             t0 = time.perf_counter()
             out = jax.block_until_ready(run(side["probe"], side["build"]))
             first = time.perf_counter() - t0
@@ -156,7 +156,7 @@ def time_kernels(args) -> None:
             answer = ([int(s) for s in sums], int(rows))
             say(shape=name, strategy=strategy,
                 probe_cap=side["probe"][2].shape[0], build_cap=build_cap,
-                build_live=int(side["build"][2].sum()), out_cap=out_cap,
+                build_live=int(side["build"][2].sum()), out_cap=width,
                 out_rows=int(rows), flags=[int(f) for f in flags],
                 first_s=round(first, 3), ms=statistics.median(ms),
                 ms_all=[round(m, 3) for m in ms],
@@ -211,7 +211,7 @@ def run_sql(args) -> None:
 
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    ap.add_argument("--shapes", nargs="*", default=["q3-slab", "q3-lower", "dim"],
+    ap.add_argument("--shapes", nargs="*", default=["q3-slab", "q3-build", "dim"],
                     choices=list(SHAPES))
     ap.add_argument("--strategies", nargs="*", default=["sort", "dense", "matmul"],
                     choices=["sort", "dense", "matmul", "lookup"])

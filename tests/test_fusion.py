@@ -322,6 +322,7 @@ class TestCapacityHalving:
             counters = ()
             exchange_static = {}
             aux_out = ()
+            joins = {}
 
         def build(meta):
             cap = caps.get("buf", 4096)

@@ -7,7 +7,9 @@ TPC-H Q5 through the one-device slab runner against the default session, the
 ``joinOutSlots`` against the spans. At the probe's width a unique build is a
 lookup (``lookup`` on each join, ``queryStats.lookupJoins``): no probe column
 gathered; a duplicate build key, or a build said unique that is not, takes
-the expansion."""
+the expansion. The same rule in a fragment program whose build side is made
+before it (``FragmentedExecutor._unique_builds``): Q3's orders ⨝ customer,
+and orders joined to a memory table below lineitem's streamed join."""
 
 import pytest
 
@@ -28,19 +30,33 @@ BY_SUPPLIER = """select d.label, count(*), sum(l.l_quantity)
 from tpch.tiny.lineitem l join memory.default.{table} d on l.l_suppkey = d.k
 group by d.label order by d.label"""
 
+#: lineitem streamed through orders, which a fragment program joins to a memory
+#: table of customer keys made by a fragment of its own (the filter keeps it so)
+BY_CUSTOMER = """select d.label, count(*), sum(l.l_quantity)
+from tpch.tiny.lineitem l join tpch.tiny.orders o on l.l_orderkey = o.o_orderkey
+join memory.default.{table} d on o.o_custkey = d.k
+where d.label like 'g%'
+group by d.label order by d.label"""
+
 
 class _Streamed:
-    """A sink that keeps the compiled tier's ``stream.*`` spans."""
+    """A sink that keeps the compiled tier's ``stream.*`` spans and the spans
+    over its fragment programs."""
 
     def __init__(self):
         self.spans = []
 
     def record(self, span):
-        if span.name.startswith("stream."):
+        if span.name.startswith("stream.") or span.name in (
+            "fragment_execute", "fused_execute"
+        ):
             self.spans.append(span.to_json())
 
     def named(self, name):
         return [s["attrs"] for s in self.spans if s["name"] == name]
+
+    def joins(self, name):
+        return [j for attrs in self.named(name) for j in attrs.get("joins", ())]
 
 
 @pytest.fixture()
@@ -53,8 +69,8 @@ def streamed():
         get_tracer().remove_sink(sink)
 
 
-def _slab_runner():
-    r = DistributedQueryRunner(n_devices=1)
+def _slab_runner(devices=1):
+    r = DistributedQueryRunner(n_devices=devices)
     r.session.set("stream_scan_threshold_rows", 1000)
     r.session.set("stream_device_chunk_rows", 4096)
     return r
@@ -119,48 +135,76 @@ def test_the_served_query_carries_the_counters():
         server.stop()
 
 
-def _supplier_table(runner, name, keys):
+def _key_table(runner, name, keys):
     runner.execute(f"create table memory.default.{name} (k bigint, label varchar)")
     values = ", ".join(f"({k}, 'group{k % 3}')" for k in keys)
     runner.execute(f"insert into memory.default.{name} values {values}")
 
 
-@pytest.mark.parametrize("case", ["unique", "duplicate appended", "forced overflow"])
-def test_the_output_capacity_follows_the_build_key(case, streamed, monkeypatch):
-    """One memory table of supplier keys as the build side of lineitem's
-    streamed join. Unique keys: the join's output is its probe's width. A
-    duplicate key appended: twice that, from a program of its own, and the
-    answer stays the default session's. A build said to be unique that is not
-    (the flag forced): the step overflows, the ladder grows the capacity, and
-    the answer is still right."""
+CASES = ("unique", "duplicate appended", "forced overflow")
+
+
+@pytest.mark.parametrize(
+    "where,devices,case",
+    [pytest.param("slab", 1, case, id=case) for case in CASES]
+    + [
+        pytest.param("fragment", n, case, id=f"fragment-{n}-{case}")
+        for n in (1, 4) for case in CASES
+    ],
+)
+def test_the_output_capacity_follows_the_build_key(
+    where, devices, case, streamed, monkeypatch
+):
+    """A memory table of keys as a join's build side: of lineitem's streamed
+    join in the slab step (supplier keys), or of orders' join in the
+    fragment program below it (customer keys), on one device and on four.
+    Unique keys: the join's output is its probe's width, and it runs as a
+    lookup. A duplicate key appended: twice that, from a program of its own,
+    and the answer stays the default session's. A build said to be unique
+    that is not (the flag forced): the lookup's flag fires, the ladder grows
+    the capacity, and the answer is still right."""
+    from trino_tpu.exec import fragments as F
     from trino_tpu.ops import join as J
 
-    runner = _slab_runner()
-    table = {"unique": "sup_u", "duplicate appended": "sup_d", "forced overflow": "sup_f"}[case]
-    _supplier_table(runner, table, range(1, 101))
-    sql = BY_SUPPLIER.format(table=table)
+    runner = _slab_runner(devices)
+    if where == "slab":
+        table = {"unique": "sup_u", "duplicate appended": "sup_d", "forced overflow": "sup_f"}[case]
+        _key_table(runner, table, range(1, 101))
+        sql, span = BY_SUPPLIER.format(table=table), "stream.slab"
+    else:
+        table = f"cus{devices}_{case[0]}"
+        _key_table(runner, table, range(1, 301))
+        sql, span = BY_CUSTOMER.format(table=table), "fragment_execute"
     first = runner.engine.execute_statement(sql, runner.session)
-    (slab,) = [j for s in streamed.named("stream.slab") for j in s["joins"]]
-    assert slab["unique"] and slab["lookup"] and slab["outCap"] == slab["probeCap"] == 4096
+    (join,) = streamed.joins(span)
+    probe = join["probeCap"]
+    # twice the probe's width a shard, in the bucket the expansion takes
+    wide = devices * F.bucket_capacity(max(1024, 2 * probe // devices))
+    assert join["unique"] and join["lookup"] and join["outCap"] == probe
+    assert where == "fragment" or probe == 4096
     if case != "unique":
-        runner.execute(f"insert into memory.default.{table} values (7, 'again')")
-        if case == "forced overflow":
+        runner.execute(f"insert into memory.default.{table} values (7, 'gagain')")
+        if case == "forced overflow" and where == "slab":
             monkeypatch.setattr(J, "unique_keys", lambda keys, sel: ~sel[:0].any())
+        elif case == "forced overflow":
+            monkeypatch.setattr(
+                F, "_unique_flags", lambda sides: F.jnp.ones(len(sides), bool)
+            )
     del streamed.spans[:]
     got = runner.engine.execute_statement(sql, runner.session)
     assert got.rows == LocalQueryRunner(engine=runner.engine).execute(sql)[0]
-    joins = [j for s in streamed.named("stream.slab") for j in s["joins"]]
+    joins = streamed.joins(span)
     if case == "unique":
         assert got.rows == first.rows and got.trace_count == 0
-        assert joins == [slab]
+        assert joins == [join]
     elif case == "duplicate appended":
         assert got.rows != first.rows and got.trace_count > 0
-        assert [(j["unique"], j["outCap"], j["lookup"]) for j in joins] == [(False, 8192, False)]
+        assert [(j["unique"], j["outCap"], j["lookup"]) for j in joins] == [(False, wide, False)]
     else:
         # the first attempt, a lookup at the probe's width, met a probe row
         # matching twice; the capacity grown past the probe's width expands
         assert [j["unique"] for j in joins] == [True] * len(joins) and len(joins) >= 2
-        assert joins[0]["outCap"] == 4096 and joins[-1]["outCap"] == 8192
+        assert joins[0]["outCap"] == probe and joins[-1]["outCap"] == wide
         assert joins[0]["lookup"] and not joins[-1]["lookup"]
 
 
@@ -171,7 +215,10 @@ def test_every_spine_join_is_a_lookup(runner, streamed, query, devices):
     and the answer is the default session's. (At tpch.tiny Q5's step holds
     one join, its two-column one. Q3's sort-path group-by widens a 4,096-row
     step to 65,536 rows after its join took 4,096 slots, which is no lookup:
-    Q3 runs at SF1's shape, a step the width of the session's chunk.)"""
+    Q3 runs at SF1's shape, a step the width of the session's chunk.) Q3's
+    orders ⨝ customer, in the fragment program below the step, is a lookup
+    too: the step's build side is the probe's width, orders', and a warm
+    query traces nothing."""
     from trino_tpu.benchmarks.tpch import queries
 
     if query == "q3":
@@ -185,3 +232,35 @@ def test_every_spine_join_is_a_lookup(runner, streamed, query, devices):
     assert slab["joins"] and all(j["lookup"] and j["unique"] for j in slab["joins"])
     assert all(j["outCap"] == j["probeCap"] == slab["cap"] * devices for j in slab["joins"])
     assert aggregate_counts(streamed.spans)["lookupJoins"] == len(slab["joins"]) == 1
+    if query == "q3":
+        (join,) = streamed.joins("fragment_execute")
+        assert join["unique"] and join["lookup"] and join["outCap"] == join["probeCap"]
+        (build,) = streamed.named("stream.build")
+        assert build["capacities"] == [join["probeCap"]]
+        del streamed.spans[:]
+        warm = runner.engine.execute_statement(sql, runner.session)
+        assert warm.rows == got.rows and warm.trace_count == 0
+        assert aggregate_counts(streamed.spans)["fragmentLookupJoins"] == 1
+
+
+def test_a_literal_that_lets_a_duplicate_through_runs_a_program_of_its_own(streamed):
+    """Literal variants of one cached plan whose fragment join's build side is
+    unique for one literal and not for the other: the flag keys the stored
+    program, so each variant runs its own (a lookup, or the expansion), warm
+    ones trace nothing, the span's ``joins`` are those of the program that
+    ran, and every answer is the default session's."""
+    runner = _slab_runner()
+    _key_table(runner, "cus_lit", range(1, 301))
+    runner.execute("insert into memory.default.cus_lit values (7, 'gagain')")
+    sql = BY_CUSTOMER.format(table="cus_lit").replace(
+        "d.label like 'g%'", "d.k <> {k}")
+    seen = []
+    for k in (7, 8, 7, 8):
+        del streamed.spans[:]
+        got = runner.engine.execute_statement(sql.format(k=k), runner.session)
+        assert got.rows == LocalQueryRunner(engine=runner.engine).execute(sql.format(k=k))[0]
+        (join,) = streamed.joins("fragment_execute")
+        seen.append((join["unique"], join["lookup"], got.trace_count > 0))
+        assert aggregate_counts(streamed.spans)["fragmentLookupJoins"] == join["lookup"]
+    assert seen[:2] == [(True, True, True), (False, False, True)]
+    assert seen[3] == (False, False, False) and seen[2][:2] == (True, True)

@@ -273,6 +273,39 @@ def fragment_fusable(frag: PlanFragment) -> bool:
     return True
 
 
+def remote_layout(node: P.RemoteSource, layout: dict) -> dict:
+    """The producer's ``layout`` under ``node``'s symbols (the same order)."""
+    producer_order = sorted(layout, key=layout.get)
+    if len(producer_order) != len(node.symbols):
+        raise FusedUnsupported("remote source arity mismatch")
+    return {s.name: layout[p] for s, p in zip(node.symbols, producer_order)}
+
+
+def sort_joins_only(session) -> bool:
+    """Every equi-join of ``session`` runs by sort-merge: ``join_strategy``
+    ``auto`` or ``sort``, or ``dense_join`` off (``_join_strategy``)."""
+    pref = str(session.get("join_strategy") or "auto").lower()
+    return pref in ("auto", "sort") or not bool(session.get("dense_join"))
+
+
+@jax.jit
+def _unique_flags(sides):
+    """``ops/join.py::unique_key_columns`` of each build side of ``sides``,
+    ``(key columns [(data, valid or None)], selection or None, rows)``,
+    stacked: one program and one pull for them all."""
+    flags = []
+    for cols, sel, rows in sides:
+        live = jnp.arange(cols[0][0].shape[0]) < rows
+        if sel is not None:
+            live = live & sel
+        flags.append(J.unique_key_columns(
+            [(d, jnp.ones(d.shape[0], jnp.bool_) if v is None else v)
+             for d, v in cols],
+            live,
+        ))
+    return jnp.stack(flags)
+
+
 class _Caps:
     """Capacity knobs, grown on overflow (shape-bucketed).
 
@@ -347,19 +380,20 @@ class _Caps:
         fl = self._seed_floor.get(name)
         return (fl[0], fl[1]) if fl is not None else None
 
-    def joins(self, wanted=None) -> list[dict]:
+    def joins(self, wanted=None, traced=None) -> list[dict]:
         """The join sites traced under these capacities (those whose
-        runtime name ``wanted`` accepts, where given), by restart-stable
+        runtime name ``wanted`` accepts, where given; those one program
+        traced, where ``traced`` is its record), by restart-stable
         site: the kernel each runs (``strategy``), the static shapes it was
         chosen at (``buildCap``, ``probeCap``), its key columns (``keys``),
         the output capacity it was given (``outCap``) and whether that is
         its probe's because the build key is unique (``unique``), and whether
         it ran as a lookup, no probe column gathered (``lookup``)."""
         by_site = {}
-        for nm, traced in self.join_sites.items():
+        for nm, info in (self.join_sites if traced is None else traced).items():
             if wanted is None or wanted(nm):
                 site = self.sites.get(nm, nm)
-                by_site[site] = {"site": site, **traced}
+                by_site[site] = {"site": site, **info}
         return [by_site[site] for site in sorted(by_site)]
 
     def grow(self, name: str, factor: int = 2, need: int = 0) -> None:
@@ -435,6 +469,8 @@ class _Meta:
     # instead of assembling one (rides the cached (jf, meta) entry, so
     # warm hits demux without retracing)
     batch_size: Optional[int] = None
+    # the join sites this program traced, as ``_Caps.join_sites`` has them
+    joins: Optional[dict] = None
 
     def capture(self, res: Result, tracer) -> None:
         self.layout = dict(res.layout)
@@ -444,6 +480,7 @@ class _Meta:
         self.overflow_names = [nm for nm, _ in tracer.overflows]
         self.counter_names = [nm for nm, _ in tracer.counters]
         self.exchange_static = dict(tracer.exchange_static)
+        self.joins = dict(tracer.joins)
         self._tracer = tracer
 
     def outputs(self, res: Result):
@@ -468,8 +505,10 @@ class _TracerSummary:
         self.counters: list = []
         self.exchange_static: dict = {}
         self.aux_out: tuple = ()
+        self.joins: dict = {}
 
     def absorb(self, tracer) -> None:
+        self.joins.update(tracer.joins)
         self.overflows.extend(tracer.overflows)
         self.counters.extend(tracer.counters)
         for k, v in tracer.exchange_static.items():
@@ -492,9 +531,11 @@ class _BatchSummary:
         self.counters: list = []
         self.exchange_static: dict = {}
         self.aux_out: tuple = ()
+        self.joins: dict = {}
         self._first = True
 
     def absorb(self, tracer) -> None:
+        self.joins.update(tracer.joins)
         if self._first:
             self.overflows = [
                 (nm, f.astype(jnp.int32)) for nm, f in tracer.overflows
@@ -668,8 +709,8 @@ class FragmentedExecutor(DistributedExecutor):
     def _store_program(self, program_key, sig, jf, meta) -> None:
         """Insert a traced program under (program_key, capacity signature).
 
-        ``("frag", id, apply_exchange, id(root))`` keys (and their
-        ``("fused", ids, apply_exchange, root_ids)`` counterparts) embed
+        ``("frag", id, apply_exchange, id(root), unique)`` keys (and their
+        ``("fused", ids, apply_exchange, root_ids, unique)`` counterparts) embed
         root-node identities because dynamic filtering rebuilds probe
         roots per execution; on a shared cross-query store those per-run
         keys would accumulate (each cached closure pins its root alive,
@@ -679,7 +720,7 @@ class FragmentedExecutor(DistributedExecutor):
         """
         if (
             isinstance(program_key, tuple)
-            and len(program_key) == 4
+            and len(program_key) == 5
             and program_key[0] in ("frag", "fused")
         ):
             prefix, rid = program_key[:3], program_key[3]
@@ -689,7 +730,7 @@ class FragmentedExecutor(DistributedExecutor):
                 if isinstance(k, tuple)
                 and len(k) == 2
                 and isinstance(k[0], tuple)
-                and len(k[0]) == 4
+                and len(k[0]) == 5
                 and k[0][:3] == prefix
                 and k[0][3] != rid
             ]
@@ -819,10 +860,16 @@ class FragmentedExecutor(DistributedExecutor):
                     continue
                 if runtime in caps.vals or runtime in caps._seed_floor:
                     continue
+                prov = str(ent.get("provenance", ""))
+                if runtime.startswith("ujoin") and not prov.endswith(
+                    ("+grown", "+halved")
+                ):
+                    # a unique build's join is its probe's width by rule,
+                    # which a bucketed floor would widen past a lookup
+                    continue
                 val = bucket_capacity(int(ent.get("value", 0)), minimum=1)
                 if val <= 0:
                     continue
-                prov = str(ent.get("provenance", ""))
                 caps.seed(
                     runtime,
                     val,
@@ -1618,9 +1665,11 @@ class FragmentedExecutor(DistributedExecutor):
     def _note_joins(self, span, *caps_key) -> None:
         """``joins`` on the span that covers a program with join sites:
         per site the kernel ``_join_strategy`` chose and the static
-        capacities it chose at (``_Caps.joins``), a stored program's too."""
+        capacities it chose at (``_Caps.joins``), as the program
+        ``_retry_traced`` ran last traced them, a stored program too."""
         caps = self.programs.get(("caps",) + caps_key)
-        joins = caps.joins() if caps is not None else []
+        traced = getattr(self, "_last_joins", None) or {}
+        joins = caps.joins(traced=traced) if caps is not None else []
         if joins:
             span.set("joins", joins)
 
@@ -1782,6 +1831,7 @@ class FragmentedExecutor(DistributedExecutor):
                 )
             self._last_aux = aux
             self._last_exchange_static = meta.exchange_static or {}
+            self._last_joins = meta.joins or {}
             if defer and getattr(self, "deferred_flags", None) is not None:
                 if flags:
                     stacked = jnp.stack([jnp.reshape(f, ()) for f in flags])
@@ -1874,6 +1924,54 @@ class FragmentedExecutor(DistributedExecutor):
         cap = cols[0].data.shape[0] if cols else int(sel.shape[0])
         return Result(Batch(cols, cap, sel), meta.layout)
 
+    def _unique_builds(self, frags, inputs, input_layouts) -> dict[int, bool]:
+        """The sort-merge INNER or LEFT joins of ``frags`` whose build side is
+        an argument of the program, made before it (a ``RemoteSource``'s
+        batch, or a scan with no operator above it): by ``id`` of that build
+        side, whether the join's key is unique among the batch's live rows.
+        Computed on the device and pulled in one transfer before the program
+        is looked up, which keys it: ``_exec_join`` then gives such a join
+        its probe's width and runs it as a lookup, the rule of the slab
+        step's build sides (``exec/streaming.py``)."""
+        if not sort_joins_only(self.session):
+            return {}
+        builds, sides = [], []
+        for frag in frags:
+            for node in P.walk_plan(frag.root):
+                if not (
+                    isinstance(node, P.Join)
+                    and node.join_type in ("INNER", "LEFT")
+                    and node.criteria
+                    and not node.single_row
+                ):
+                    continue
+                build = node.right
+                if isinstance(build, P.RemoteSource):
+                    name = f"remote{build.fragment_id}"
+                elif isinstance(build, P.TableScan):
+                    name = f"scan{id(build)}"
+                else:
+                    continue
+                batch = inputs.get(name)
+                if not isinstance(batch, Batch):
+                    continue  # made inside this very program
+                layout = input_layouts[name]
+                if isinstance(build, P.RemoteSource):
+                    layout = remote_layout(build, layout)
+                cols = [
+                    batch.columns[layout[rs.name]] for _, rs in node.criteria
+                ]
+                builds.append(id(build))
+                sides.append((
+                    tuple((c.data, c.valid) for c in cols),
+                    batch.sel,
+                    batch.num_rows,
+                ))
+        if not sides:
+            return {}
+        flags = np.asarray(_unique_flags(tuple(sides)))
+        return dict(zip(builds, (bool(f) for f in flags)))
+
     def run_fragment_program(
         self,
         frag: PlanFragment,
@@ -1899,6 +1997,7 @@ class FragmentedExecutor(DistributedExecutor):
         caps = self.programs.setdefault(("caps", frag.id), _Caps())
         self._seed_history(frag, caps)
         self._seed_caps(frag, caps)
+        unique = self._unique_builds([frag], inputs, input_layouts)
         pvec = self._param_arrays()
         if pvec is not None:
             # hoisted literals ride as device-scalar jit inputs: literal
@@ -1911,6 +2010,7 @@ class FragmentedExecutor(DistributedExecutor):
                 tracer = _FragmentTracer(
                     self, inp, input_layouts, caps, skew=skew
                 )
+                tracer.unique_builds.update(unique)
                 res = tracer._exec(frag.root)
                 if apply_exchange:
                     res = tracer.apply_output_exchange(frag, res)
@@ -1935,7 +2035,10 @@ class FragmentedExecutor(DistributedExecutor):
             # filtering rebuilds fragment nodes per attempt, and a program
             # traced against old node ids must not serve new inputs (the
             # cached closure pins the old root alive, so its id is unique)
-            program_key=("frag", frag.id, apply_exchange, id(frag.root)),
+            program_key=(
+                "frag", frag.id, apply_exchange, id(frag.root),
+                tuple(unique.values()),
+            ),
             defer=defer,
         )
 
@@ -1972,6 +2075,7 @@ class FragmentedExecutor(DistributedExecutor):
         for f in frags:
             self._seed_history(f, caps)
             self._seed_caps(f, caps)
+        unique = self._unique_builds(frags, inputs, input_layouts)
         pvec = self._param_arrays()
         if pvec is not None:
             inputs = dict(inputs)
@@ -2029,6 +2133,7 @@ class FragmentedExecutor(DistributedExecutor):
                     tracer = _FragmentTracer(
                         self, avail, layouts, caps, skew=mskew
                     )
+                    tracer.unique_builds.update(unique)
                     res = tracer._exec(frag.root)
                     if not last or apply_exchange:
                         res = tracer.apply_output_exchange(frag, res)
@@ -2067,6 +2172,7 @@ class FragmentedExecutor(DistributedExecutor):
                 fids,
                 apply_exchange,
                 tuple(id(f.root) for f in frags),
+                tuple(unique.values()),
             ),
             defer=defer,
         )
@@ -2634,6 +2740,8 @@ class _FragmentTracer(DistributedExecutor):
         self.exchange_static: dict[str, int] = {}
         # replicated hot-key tables exported for the peer build exchange
         self.aux_out: tuple = ()
+        # the join sites this trace made, as ``_Caps.join_sites`` has them
+        self.joins: dict[str, dict] = {}
         self._memo: dict[int, Result] = {}
         # build sides made before this trace (a streamed aggregate's), by
         # ``id`` of their plan root: True where the join's key is unique
@@ -2722,15 +2830,8 @@ class _FragmentTracer(DistributedExecutor):
 
     def _exec_remotesource(self, node: P.RemoteSource) -> Result:
         batch = self._inputs[f"remote{node.fragment_id}"]
-        layout = dict(self._input_layouts[f"remote{node.fragment_id}"])
-        # rename producer symbols -> this node's symbols (same order)
-        producer_order = sorted(layout, key=layout.get)
-        if len(producer_order) != len(node.symbols):
-            raise FusedUnsupported("remote source arity mismatch")
-        new_layout = {
-            s.name: layout[p] for s, p in zip(node.symbols, producer_order)
-        }
-        return Result(batch, new_layout)
+        layout = self._input_layouts[f"remote{node.fragment_id}"]
+        return Result(batch, remote_layout(node, layout))
 
     # --- output / row-preserving ---------------------------------------
 
@@ -3298,8 +3399,7 @@ class _FragmentTracer(DistributedExecutor):
         already has. ``join_strategy=dense|matmul`` still pin them, with
         their ladder: sites it demoted (duplicate chains beyond the probe
         window) stay on sort, as does every join with ``dense_join`` off."""
-        pref = str(self.session.get("join_strategy") or "auto").lower()
-        if pref in ("auto", "sort") or not bool(self.session.get("dense_join")):
+        if sort_joins_only(self.session):
             return "sort"
         # demotions are recorded under the restart-stable alias (node
         # ids churn across retraces); the alias map is registered by
@@ -3310,6 +3410,7 @@ class _FragmentTracer(DistributedExecutor):
         matmul_ok = len(lkeys) == 1 and jnp.issubdtype(
             lkeys[0][0].dtype, jnp.integer
         )
+        pref = str(self.session.get("join_strategy")).lower()
         return "matmul" if pref == "matmul" and matmul_ok else "dense"
 
     def _exec_join(self, node: P.Join) -> Result:
@@ -3365,14 +3466,15 @@ class _FragmentTracer(DistributedExecutor):
 
         # where the build key is unique among the build's live rows a probe
         # row matches one build row at the most, so the output fits in the
-        # probe's capacity; else twice that. Each rule has a capacity of its
-        # own, so a build that stops being unique starts from the wide one
-        # (the overflow flag and the ladder stay the guard either way)
+        # probe's own capacity; else twice that. Each rule has a capacity of
+        # its own, so a build that stops being unique starts from the wide
+        # one (the overflow flag and the ladder stay the guard either way)
         unique = self.unique_builds.get(id(node.right), False)
         cap_name = f"{'ujoin' if unique else 'join'}{id(node)}"
         probe_cap = left.batch.capacity
-        default_cap = bucket_capacity(
-            max(1024, (1 if unique else 2) * probe_cap // max(self.n, 1))
+        default_cap = (
+            max(1, probe_cap // max(self.n, 1)) if unique
+            else bucket_capacity(max(1024, 2 * probe_cap // max(self.n, 1)))
         )
         cap = self.caps.get(cap_name, default_cap)
         strategy = self._join_strategy(node, lkeys)
@@ -3384,7 +3486,9 @@ class _FragmentTracer(DistributedExecutor):
             strategy == "sort" and unique and not node.single_row
             and cap * max(self.n, 1) == probe_cap
         )
-        self.caps.join_sites[f"densejoin{id(node)}"] = {
+        self.joins[f"densejoin{id(node)}"] = self.caps.join_sites[
+            f"densejoin{id(node)}"
+        ] = {
             "strategy": strategy, "buildCap": right.batch.capacity,
             "probeCap": probe_cap, "keys": len(node.criteria),
             "outCap": cap * max(self.n, 1), "unique": unique, "lookup": lookup,

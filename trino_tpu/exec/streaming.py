@@ -354,12 +354,10 @@ class StreamingAggregator:
 
         if join is None or join.join_type not in ("INNER", "LEFT"):
             return jnp.int64(0)
-        lanes = []
-        for _, rs in join.criteria:
-            c = build.columns[layout[rs.name]]
-            data = c.data if getattr(c.data, "ndim", 1) == 1 else c.data.T
-            lanes.extend((lane, c.valid_mask()) for lane in jnp.atleast_2d(data))
-        return J.unique_keys(lanes, build.selection_mask()).astype(jnp.int64)
+        cols = [build.columns[layout[rs.name]] for _, rs in join.criteria]
+        return J.unique_key_columns(
+            [(c.data, c.valid_mask()) for c in cols], build.selection_mask()
+        ).astype(jnp.int64)
 
     def _build_join_names(self) -> set:
         """The capacity names of the joins inside the build sides' plans:

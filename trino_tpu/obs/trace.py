@@ -505,13 +505,18 @@ def aggregate_counts(spans: Iterable[Dict[str, Any]]) -> Dict[str, Any]:
     of the slab step times the span's ``steps``: the width the probe spine
     carries through the loop. ``lookupJoins``: over the same spans, the
     joins with ``lookup`` true, which ran as a lookup of a unique build key
-    with no probe column gathered. ``buildRows``: live rows of the build sides
-    each streamed aggregate made last (``stream.build``'s ``rows``).
+    with no probe column gathered. ``fragmentLookupJoins``: the same of the
+    joins of the fragment programs of the surviving pass, each stage's last
+    ``fragment_execute`` or ``fused_execute`` span, a build side made before
+    the program (``FragmentedExecutor._unique_builds``). ``buildRows``: live
+    rows of the build sides each streamed aggregate made last
+    (``stream.build``'s ``rows``).
     ``meshDevices``: devices of the mesh the compiled session ran the plan
     on (``execute_plan``'s attribute). The first two are absent where no
     grouped aggregate ran, ``slabSteps`` where nothing streamed through a
     slab program, ``joinOutSlots`` and ``lookupJoins`` where no such loop
-    joined (``lookupJoins`` too where its joins carry no ``lookup``), ``buildRows``
+    joined (``lookupJoins`` too where its joins carry no ``lookup``),
+    ``fragmentLookupJoins`` where no fragment program ran, ``buildRows``
     where nothing streamed past a build side, ``meshDevices`` in the
     default session."""
     attempts = growths = rows = devices = 0
@@ -519,6 +524,8 @@ def aggregate_counts(spans: Iterable[Dict[str, Any]]) -> Dict[str, Any]:
     # latest loop
     last: Dict[Any, Tuple[int, int, Optional[int], Optional[int]]] = {}
     built: Dict[Any, Tuple[int, int]] = {}  # site -> (start, rows), the latest
+    # (span, stage) -> (start, its joins), the latest
+    staged: Dict[Any, Tuple[int, Any]] = {}
     for s in spans:
         attrs = s.get("attrs") or {}
         attempts += attrs.get("aggAttempts", 0)
@@ -539,6 +546,10 @@ def aggregate_counts(spans: Iterable[Dict[str, Any]]) -> Dict[str, Any]:
         elif s["name"] == "stream.build" and "rows" in attrs:
             if site not in built or start >= built[site][0]:
                 built[site] = (start, attrs["rows"])
+        elif s["name"] in ("fragment_execute", "fused_execute"):
+            stage = (s["name"], attrs.get("stage"))
+            if stage not in staged or start >= staged[stage][0]:
+                staged[stage] = (start, attrs.get("joins"))
     out: Dict[str, Any] = {"resultRows": rows}
     if attempts:
         out.update(aggAttempts=attempts, groupBudgetGrowths=growths)
@@ -550,6 +561,10 @@ def aggregate_counts(spans: Iterable[Dict[str, Any]]) -> Dict[str, Any]:
         looked = [n for _, _, _, n in last.values() if n is not None]
         if looked:
             out["lookupJoins"] = sum(looked)
+    if staged:
+        out["fragmentLookupJoins"] = sum(
+            bool(j.get("lookup")) for _, js in staged.values() for j in js or ()
+        )
     if built:
         out["buildRows"] = sum(n for _, n in built.values())
     if devices:

@@ -101,6 +101,17 @@ def unique_keys(keys, sel: jnp.ndarray) -> jnp.ndarray:
     return ~jnp.any((keyed[1:] == keyed[:-1]) & (pos < count))
 
 
+def unique_key_columns(columns, sel: jnp.ndarray) -> jnp.ndarray:
+    """``unique_keys`` over key columns ``[(data, valid), ...]``, where a wide
+    column's ``data`` (``[rows, 2]``) gives a key lane a half, each under the
+    column's validity."""
+    lanes = []
+    for data, valid in columns:
+        data = data if data.ndim == 1 else data.T
+        lanes.extend((lane, valid) for lane in jnp.atleast_2d(data))
+    return unique_keys(lanes, sel)
+
+
 def merge_rank(sorted_build_keys: jnp.ndarray, keys: jnp.ndarray):
     """``searchsorted(sorted_build_keys, keys)`` for side "left" and
     "right" at once, as ``(lo, hi)`` int32, by sort-merge.
